@@ -9,8 +9,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 # TLR validation benches run in f64 like the paper.
 jax.config.update("jax_enable_x64", True)
+enable_compile_cache()
 
 SCALE = float(os.environ.get("BENCH_SCALE", "1.0"))
 

@@ -13,6 +13,10 @@ import numpy as np
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 from repro.core import CholOptions, TLROperator, covariance_problem  # noqa: E402
 
 

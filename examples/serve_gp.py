@@ -19,6 +19,10 @@ import numpy as np
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+
 from repro import obs  # noqa: E402
 from repro.core import (  # noqa: E402
     CholOptions, TLROperator, covariance_problem,

@@ -10,6 +10,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import init_model
 from repro.train import DecodeServer, Request
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--slots", type=int, default=3)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=True)
     print(f"initializing {cfg.name} ({cfg.param_count()/1e6:.1f}M params)")

@@ -17,6 +17,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.optim import AdamWConfig, CompressConfig
 from repro.train import TrainConfig, Trainer
@@ -34,6 +35,7 @@ def main():
                     help="enable ARA low-rank gradient compression")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset == "smoke":
         cfg = get_config(args.arch, smoke=True)

@@ -55,6 +55,7 @@ from .buckets import (_bucket_ladder, _bucket_up, _pad_axis, trace_count,
 from .tlr import TLRMatrix, tril_index, tril_pairs
 from ..kernels import ops
 from .. import obs
+from ..precision import einsum, matmul
 
 
 # -- general (nonsymmetric) tile grid -----------------------------------------
@@ -152,7 +153,8 @@ def _tiles_to_dense(D, U, V, nb: int, b: int):
     for i in range(nb):
         out = out.at[i * b:(i + 1) * b, i * b:(i + 1) * b].set(D[i])
     for t, (i, j) in enumerate(offd_pairs(nb)):
-        out = out.at[i * b:(i + 1) * b, j * b:(j + 1) * b].set(U[t] @ V[t].T)
+        out = out.at[i * b:(i + 1) * b, j * b:(j + 1) * b].set(
+            matmul(U[t], V[t].T))
     return out
 
 
@@ -161,10 +163,10 @@ def _gen_matvec(D, U, V, xb, nb: int):
     pairs = offd_pairs(nb)
     rows = jnp.asarray(pairs[:, 0], jnp.int32)
     cols = jnp.asarray(pairs[:, 1], jnp.int32)
-    yb = jnp.einsum("kbc,kc...->kb...", D, xb)
+    yb = einsum("kbc,kc...->kb...", D, xb)
     xj = jnp.take(xb, cols, axis=0)
-    y = jnp.einsum("tbr,tr...->tb...", U,
-                   jnp.einsum("tbr,tb...->tr...", V, xj))
+    y = einsum("tbr,tr...->tb...", U,
+                   einsum("tbr,tb...->tr...", V, xj))
     return yb.at[rows].add(y)
 
 
@@ -336,7 +338,7 @@ def tlr_round(A, eps, r_max_out=None, *, rel: bool = False, impl=None,
     width instead of ``r_max`` (rank-0 tiles skip the kernels entirely).
     Same truncation semantics; ``"flat"`` is the compatibility path.
     """
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     batching = resolve_batching(batching, A.ranks, A.r_max)
     b, r_in = A.b, A.r_max
     r_out = r_max_out or min(r_in, b)
@@ -382,7 +384,7 @@ def tlr_round_tiles(U, V, eps, r_out=None, *, rel: bool = False, impl=None,
     instead of one W-wide batch (``ranks[t]`` must upper-bound tile ``t``'s
     nonzero columns -- the storage invariant / axpy width convention).
     """
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     batching = resolve_batching(batching, ranks, U.shape[2])
     N, b, w_in = U.shape
     r_out = r_out or min(w_in, b)
@@ -612,7 +614,7 @@ def tlr_gemm(A, B, eps, r_max_out=None, *, rel: bool = False,
     if Ga.nb != Gb.nb or Ga.b != Gb.b:
         raise ValueError(f"tlr_gemm needs matching grids, got "
                          f"(nb={Ga.nb}, b={Ga.b}) and (nb={Gb.nb}, b={Gb.b})")
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     batching = resolve_batching(
         batching, np.concatenate([np.asarray(Ga.ranks).reshape(-1),
                                   np.asarray(Gb.ranks).reshape(-1)]),
@@ -706,7 +708,7 @@ def tlr_syrk(A: TLRMatrix, L: TLRMatrix, eps, r_max_out=None, *,
     if A.nb != L.nb or A.b != L.b:
         raise ValueError(f"tlr_syrk needs matching grids, got "
                          f"(nb={A.nb}, b={A.b}) and (nb={L.nb}, b={L.b})")
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     batching = resolve_batching(
         batching, np.concatenate([np.asarray(A.ranks).reshape(-1),
                                   np.asarray(L.ranks).reshape(-1)]),
@@ -790,38 +792,35 @@ def _syrk_column_body(accU, accV, offsets, D, Up, Vn, ranks, dk,
     Per trailing tile (i, j), i > j > k, the single rank-``r_p`` term
     ``-L(i,k) D_k L(j,k)^T = -U_i (Vn_i^T D_k Vn_j) U_j^T`` is appended as
     a factor pair at that tile's write offset ``offsets[tile]`` of the
-    accumulation buffers (the columns past the offset are zero, so a rolled
-    scatter-add lands the block exactly; duplicate padded slots add zeros).
+    accumulation buffers (the columns past the offset are zero, so a
+    windowed scatter-add lands the block exactly; duplicate padded slots
+    add zeros).
     ``offsets`` is a per-tile (nt,) vector -- uniform under flat batching,
     per-tile content widths under ranked batching, where each tile's
     concatenation stays compact instead of advancing in lockstep. Trailing
     diagonal tiles subtract their dense ``L(j,k) D_k L(j,k)^T`` product.
     """
     trace_event("algebra")
-    r_p = Up.shape[-1]
-    w_acc = accU.shape[-1]
     Ui = jnp.take(Up, aidx, axis=0)
     Vi = jnp.take(Vn, aidx, axis=0)
     Uj = jnp.take(Up, cidx, axis=0)
     Vj = jnp.take(Vn, cidx, axis=0)
     if ldl:
-        G = jnp.einsum("tbr,b,tbq->trq", Vi, dk, Vj)
+        G = einsum("tbr,b,tbq->trq", Vi, dk, Vj)
     else:
-        G = jnp.einsum("tbr,tbq->trq", Vi, Vj)
+        G = einsum("tbr,tbq->trq", Vi, Vj)
     left = -ops.batched_gemm(Ui, G, jnp.take(ranks, aidx), impl=impl)
     m = valid[:, None, None]
     left = jnp.where(m, left, jnp.zeros_like(left))
     right = jnp.where(m, Uj, jnp.zeros_like(Uj))
-    pad = ((0, 0), (0, 0), (0, w_acc - r_p))
     off = jnp.take(offsets, oidx)
-    roll = jax.vmap(lambda x, s: jnp.roll(x, s, axis=-1))
-    accU = accU.at[oidx].add(roll(jnp.pad(left, pad), off))
-    accV = accV.at[oidx].add(roll(jnp.pad(right, pad), off))
+    accU = _append_at(accU, oidx, off, left)
+    accV = _append_at(accV, oidx, off, right)
     if ldl:
-        Gd = jnp.einsum("tbr,b,tbq->trq", Vn, dk, Vn)
+        Gd = einsum("tbr,b,tbq->trq", Vn, dk, Vn)
     else:
-        Gd = jnp.einsum("tbr,tbq->trq", Vn, Vn)
-    upd = jnp.einsum("tbr,trq,tcq->tbc", Up, Gd, Up)
+        Gd = einsum("tbr,tbq->trq", Vn, Vn)
+    upd = einsum("tbr,trq,tcq->tbc", Up, Gd, Up)
     upd = jnp.where(dvalid[:, None, None], upd, jnp.zeros_like(upd))
     D = D.at[didx].add(-upd)
     return accU, accV, D
@@ -839,6 +838,21 @@ _syrk_column_core_donated = jax.jit(_syrk_column_body,
                                     donate_argnums=(0, 1, 3))
 
 
+def _append_at(acc, oidx, off, blk):
+    """``acc[oidx[s], :, off[s]:off[s] + r] += blk[s]`` for every slot
+    ``s`` of the ``(S, b, r)`` block stack: one windowed scatter-add, in
+    place on a donated ``acc``, with no full-width copy of the blocks.
+    The columns past each tile's offset are zero, so the add lands each
+    block exactly; a window that would leave the buffer is dropped (only
+    padding slots, which carry zeros, can point there)."""
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(0,),
+        scatter_dims_to_operand_dims=(0, 2))
+    idx = jnp.stack([oidx, off], axis=-1).astype(jnp.int32)
+    return jax.lax.scatter_add(acc, idx, blk, dnums,
+                               mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
 def _syrk_head_body(accU, accV, offsets, D, Up, Vn, ranks, dk,
                     oidx, valid, didx, dvalid, *, ldl: bool, impl: str):
     """The *head* of a column's trailing update: tiles ``(i, k+1)`` for
@@ -853,28 +867,24 @@ def _syrk_head_body(accU, accV, offsets, D, Up, Vn, ranks, dk,
     until after the next panel is in flight.
     """
     trace_event("algebra")
-    r_p = Up.shape[-1]
-    w_acc = accU.shape[-1]
     V0 = Vn[0]
     if ldl:
-        G = jnp.einsum("tbr,b,bq->trq", Vn, dk, V0)
+        G = einsum("tbr,b,bq->trq", Vn, dk, V0)
     else:
-        G = jnp.einsum("tbr,bq->trq", Vn, V0)
+        G = einsum("tbr,bq->trq", Vn, V0)
     left = -ops.batched_gemm(Up, G, ranks, impl=impl)
     m = valid[:, None, None]
     left = jnp.where(m, left, jnp.zeros_like(left))
     right = jnp.where(m, jnp.broadcast_to(Up[0][None], Up.shape),
                       jnp.zeros_like(Up))
-    pad = ((0, 0), (0, 0), (0, w_acc - r_p))
     off = jnp.take(offsets, oidx)
-    roll = jax.vmap(lambda x, s: jnp.roll(x, s, axis=-1))
-    accU = accU.at[oidx].add(roll(jnp.pad(left, pad), off))
-    accV = accV.at[oidx].add(roll(jnp.pad(right, pad), off))
+    accU = _append_at(accU, oidx, off, left)
+    accV = _append_at(accV, oidx, off, right)
     if ldl:
-        Gd = jnp.einsum("br,b,bq->rq", V0, dk, V0)
+        Gd = einsum("br,b,bq->rq", V0, dk, V0)
     else:
-        Gd = jnp.einsum("br,bq->rq", V0, V0)
-    upd = Up[0] @ Gd @ Up[0].T
+        Gd = einsum("br,bq->rq", V0, V0)
+    upd = matmul(matmul(Up[0], Gd), Up[0].T)
     upd = jnp.where(dvalid, upd, jnp.zeros_like(upd))
     D = D.at[didx].add(-upd)
     return accU, accV, D
@@ -937,7 +947,7 @@ def tlr_syrk_column(accU, accV, used, D, Up, Vn, ranks, dk, k: int, *,
     if T <= 0:
         return accU, accV, D
     r_p = Up.shape[-1]
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     ladder = _bucket_ladder(nb - 1)
     Tb = _bucket_up(T, ladder)
     w_acc = accU.shape[-1]

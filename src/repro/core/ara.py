@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..precision import einsum
+
 
 @dataclasses.dataclass(frozen=True)
 class ARAParams:
@@ -136,7 +138,7 @@ def _orthonormalize(Y: jax.Array, method: str, drop_tol: float) -> jax.Array:
     jit0 = 1e-12 if Y.dtype == jnp.float64 else 1e-5
 
     def one_pass(Yp):
-        G = jnp.einsum("tbs,tbc->tsc", Yp, Yp)
+        G = einsum("tbs,tbc->tsc", Yp, Yp)
         scale = jnp.maximum(jnp.trace(G, axis1=-2, axis2=-1), 1.0)
         R = jnp.linalg.cholesky(G + jit0 * scale[:, None, None] * eye)
         Yq = jax.scipy.linalg.solve_triangular(
@@ -169,8 +171,8 @@ def ara_iteration(
     # Two-pass block Gram-Schmidt against the accumulated basis. Padded
     # (zero) columns of Q contribute nothing, so no column masking needed.
     for _ in range(p.gs_passes):
-        proj = jnp.einsum("tbr,tbs->trs", state.Q, Y)
-        Y = Y - jnp.einsum("tbr,trs->tbs", state.Q, proj)
+        proj = einsum("tbr,tbs->trs", state.Q, Y)
+        Y = Y - einsum("tbr,trs->tbs", state.Q, proj)
 
     # Residual 2-norm estimate from the projected-out samples: for a shared
     # Gaussian probe, max_j ||y_j|| concentrates around the residual norm.
@@ -263,11 +265,11 @@ def dense_batch_sampler(A: jax.Array):
 
     def sample(data, Omega):
         if Omega.ndim == 2:
-            return jnp.einsum("tbm,ms->tbs", data, Omega)
-        return jnp.einsum("tbm,tms->tbs", data, Omega)
+            return einsum("tbm,ms->tbs", data, Omega)
+        return einsum("tbm,tms->tbs", data, Omega)
 
     def sample_t(data, Q):
-        return jnp.einsum("tbm,tbq->tmq", data, Q)
+        return einsum("tbm,tbq->tmq", data, Q)
 
     return sample, sample_t, A
 

@@ -11,8 +11,9 @@ bucket width, and the results scatter back into the padded storage layout.
 
 Shape discipline (the same contract as ``core/buckets.py``): both the rank
 axis and the batch-count axis of every bucket are padded up power-of-two
-ladders, so at most ``~log2(r_max) * log2(nt)`` executables compile per
-kernel family -- never one per rank distribution. The compile count is a
+ladders (the rounding dispatches step by 8x, ``ROUND_COUNTS``), so
+at most ``~log2(r_max) * log2(nt)`` executables compile per kernel family
+-- never one per rank distribution. The compile count is a
 real, process-wide counter (``batching_trace_count()``) pinned by
 ``tests/test_batching.py``, mirroring ``algebra_trace_count`` /
 ``trsm_trace_count``.
@@ -44,6 +45,9 @@ import numpy as np
 from .buckets import (_bucket_ladder, _bucket_up, _pad_axis, trace_count,
                       trace_event)
 from ..kernels import ops
+from ..launch.sharding import (set_tile_mesh,  # noqa: F401 (re-exported)
+                               tile_batch_sharding, tile_dp_size, tile_mesh,
+                               tile_mesh_mode)
 from .. import obs
 
 
@@ -206,13 +210,12 @@ class TilePlan(BatchPlan):
 
     def bucket_flops(self, b: int, r_out: int | None = None, *,
                      dtype=np.float64, impl: str | None = None) -> list[float]:
-        """Per-bucket XLA ``cost_analysis`` FLOPs of the rounding core at
-        each bucket's true dispatch shape (``kernels/ops.py::flop_estimate``;
-        lowers + compiles, nothing executes; cached process-wide by shape).
-        One entry per ``self.buckets`` element."""
-        return [_round_core_flops(bk.padded, b, bk.width,
-                                  min(r_out or b, bk.width), dtype,
-                                  ops.resolve_impl(impl))
+        """Per-bucket XLA ``cost_analysis`` FLOPs of the rounding cores at
+        each bucket's true dispatch shapes (``round_dispatches``;
+        ``kernels/ops.py::flop_estimate`` lowers + compiles, nothing
+        executes; cached process-wide by shape). One entry per
+        ``self.buckets`` element."""
+        return [_bucket_round_flops(bk, b, r_out or b, dtype, impl)
                 for bk in self.buckets]
 
     def flat_flops(self, b: int, r_out: int | None = None, *,
@@ -223,7 +226,7 @@ class TilePlan(BatchPlan):
         if self.n == 0 or self.cap == 0:
             return 0.0
         return _round_core_flops(self.n, b, self.cap, min(r_out or b, b),
-                                 dtype, ops.resolve_impl(impl))
+                                 dtype, impl)
 
 
 def _flops_cache_key(n, b, w, r_out, dtype, impl):
@@ -343,6 +346,18 @@ def choose_batching(plan: TilePlan) -> str:
     return "ranked" if plan.rank_skew >= RANK_SKEW_RANKED else "flat"
 
 
+def _flush_fit(plan: TilePlan, b: int, dtype) -> int:
+    """The most accumulated columns the right driver's two ``(nt, b,
+    max(b, cap) + flush * cap)`` accumulation buffers can hold within a
+    third of the device's memory (at least 1; no cap where the device
+    reports no limit, as on the CPU)."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    if not limit or not plan.cap or not plan.n:
+        return 8
+    col_bytes = 2 * plan.n * b * np.dtype(dtype).itemsize
+    return max(1, (limit // 3 // col_bytes - max(b, plan.cap)) // plan.cap)
+
+
 def resolve_policy(batching: str | None, plan: TilePlan, *, b: int,
                    dtype=np.float64, right_flush: int = 0) -> dict:
     """Resolve the ``batching`` / ``right_flush`` knobs against a plan and
@@ -354,7 +369,9 @@ def resolve_policy(batching: str | None, plan: TilePlan, *, b: int,
     of 2 accumulated columns between flushes, while ranked appends land at
     each tile's own bucket width (~the median width, not r_max), so the
     same accumulation window absorbs ~cap/median_width columns -- the
-    cost-model estimate below picks the flush cadence that fills it.
+    cost-model estimate below picks the flush cadence that fills it. The
+    auto cadence is capped so that the accumulation buffers take at most
+    a third of the device's memory (:func:`_flush_fit`).
     """
     requested = batching or "auto"
     if requested not in BATCHINGS:
@@ -365,10 +382,10 @@ def resolve_policy(batching: str | None, plan: TilePlan, *, b: int,
                        rank_ladder(plan.cap)) if plan.cap else 1
     if right_flush:
         flush = max(1, int(right_flush))
-    elif decision == "ranked":
-        flush = max(2, min(8, plan.cap // max(med_w, 1)))
     else:
-        flush = 2
+        flush = max(2, min(8, plan.cap // max(med_w, 1))) \
+            if decision == "ranked" else 2
+        flush = min(flush, _flush_fit(plan, b, dtype))
     from ..launch.costmodel import tile_batch_cost
 
     est = tile_batch_cost([(bk.padded, bk.width) for bk in plan.buckets],
@@ -421,8 +438,25 @@ def _pad_width(x: jax.Array, width: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
+# Tile counts a rank-bucket rounding dispatch may take. A rounding core
+# (batched QR + an XLA SVD) costs seconds to compile on a TPU for every
+# distinct (count, width), so the counts step by 8x instead of following
+# the count ladder: at most three compiled cores per width and output
+# rank, and at most 8x zero padding. A bucket goes in chunks of the
+# largest count; each chunk takes the smallest count that holds it.
+ROUND_COUNTS = (1, 8, 64)
+
+
+def round_dispatches(count: int) -> list[int]:
+    """The tile count of each rounding dispatch for a bucket of ``count``
+    tiles (zero tiles pad each dispatch up to its count)."""
+    step = ROUND_COUNTS[-1]
+    return [_bucket_up(min(step, count - lo), list(ROUND_COUNTS))
+            for lo in range(0, count, step)]
+
+
 def bucketed_round_tiles(U, V, ranks, eps, r_out=None, *, rel: bool = False,
-                         impl=None):
+                         impl=None, inplace: bool = False):
     """Rank-bucketed rounding pass: the ``batching="ranked"`` counterpart of
     ``tlr_round_tiles`` / the core of ranked ``tlr_round``.
 
@@ -435,22 +469,36 @@ def bucketed_round_tiles(U, V, ranks, eps, r_out=None, *, rel: bool = False,
     ``(N, b, r_out)`` output. Rank-0 tiles are skipped outright: their
     output is the zero factor pair at rank 0 with zero rounding error.
 
+    ``inplace=True`` donates ``U`` / ``V`` and writes each result back into
+    its own tile at the full width ``W`` (zero past the new rank), so no
+    second pair of stacks is allocated; rank-0 tiles keep their content.
+    A bucket goes in chunks of at most ``ROUND_COUNTS[-1]`` tiles, each
+    zero-padded up to the next of ``ROUND_COUNTS`` (:func:`round_dispatches`),
+    which also bounds the gathered working set.
+
     Returns ``(U, V, ranks, err)`` with identical truncation semantics to
     the flat pass -- parity is exact up to floating-point reduction order.
     """
-    impl = ops.resolve_impl(impl)
+    ops.resolve_impl(impl)  # validate; each op resolves its own default
     N, b, w_in = U.shape
     r_out = r_out or min(w_in, b)
     dtype = U.dtype
-    outU = jnp.zeros((N, b, r_out), dtype)
-    outV = jnp.zeros((N, b, r_out), dtype)
+    if inplace:
+        if r_out > w_in:
+            raise ValueError(f"inplace rounding needs r_out <= {w_in}, "
+                             f"got {r_out}")
+        outU, outV = U, V
+    else:
+        outU = jnp.zeros((N, b, r_out), dtype)
+        outV = jnp.zeros((N, b, r_out), dtype)
     out_ranks = jnp.zeros((N,), jnp.int32)
     out_err = jnp.zeros((N,), dtype)
     if N == 0:
         return outU, outV, out_ranks, out_err
     eps = jnp.asarray(eps, dtype)
     plan = tile_plan(ranks, w_in)
-    if _TILE_MESH["mesh"] is not None:
+    ranks_d = jnp.asarray(plan.ranks_host, jnp.int32)
+    if tile_mesh() is not None and not inplace:
         # End-to-end sharding: place the scatter bases so every bucket's
         # results land sharded over the mesh (the drivers' panel / flush
         # outputs inherit this placement), and each bucket's gathered
@@ -461,95 +509,86 @@ def bucketed_round_tiles(U, V, ranks, eps, r_out=None, *, rel: bool = False,
         if obs.enabled():
             attrs = bucket_span_attrs(plan, bk, b, r_out, dtype, impl)
         with obs.span("round.bucket", cat="algebra", **attrs):
-            idx = jnp.asarray(bk.idx)
-            Ug = _pad_axis(jnp.take(U, idx, axis=0)[:, :, :bk.width],
-                           bk.padded)
-            Vg = _pad_axis(jnp.take(V, idx, axis=0)[:, :, :bk.width],
-                           bk.padded)
-            if _TILE_MESH["mesh"] is not None:
-                Ug, Vg = shard_tile_batch(Ug, Vg, preserve_shape=True)
-            if bk.width <= b:
-                Ub, Vb, rb, eb = _round_bucket(
-                    Ug, Vg, eps, r_out=min(r_out, bk.width), rel=rel,
-                    impl=impl)
-            else:
-                rg = _pad_axis(jnp.take(jnp.asarray(ranks), idx), bk.padded)
-                Ub, Vb, rb, eb = _densify_round_bucket(
-                    Ug, Vg, rg, eps, r_out=min(r_out, b), rel=rel, impl=impl)
-            n = bk.count
-            outU = outU.at[idx].set(_pad_width(Ub[:n], r_out))
-            outV = outV.at[idx].set(_pad_width(Vb[:n], r_out))
-            out_ranks = out_ranks.at[idx].set(rb[:n])
-            out_err = out_err.at[idx].set(eb[:n].astype(dtype))
+            step = ROUND_COUNTS[-1]
+            for lo, cnt in zip(range(0, bk.count, step),
+                               round_dispatches(bk.count)):
+                # the chunk's indices padded up to the dispatch count; the
+                # out-of-range pad slots gather zero tiles and scatter
+                # nowhere
+                sub = bk.idx[lo:lo + step]
+                idx = np.full(cnt, N, np.int32)
+                idx[:sub.shape[0]] = sub
+                # in place, gather from the latest (donated) outputs
+                src = (outU, outV) if inplace else (U, V)
+                Ug, Vg, rg = _bucket_gather(*src, ranks_d, idx,
+                                            width=bk.width)
+                if tile_mesh() is not None:
+                    Ug, Vg = shard_tile_batch(Ug, Vg, preserve_shape=True)
+                if bk.width <= b:
+                    Ub, Vb, rb, eb = _round_bucket(
+                        Ug, Vg, eps, r_out=min(r_out, bk.width), rel=rel,
+                        impl=impl)
+                else:
+                    Ub, Vb, rb, eb = _densify_round_bucket(
+                        Ug, Vg, rg, eps, r_out=min(r_out, b), rel=rel,
+                        impl=impl)
+                outU, outV, out_ranks, out_err = _bucket_scatter(
+                    outU, outV, out_ranks, out_err, idx, Ub, Vb, rb, eb)
     return outU, outV, out_ranks, out_err
+
+
+@partial(jax.jit, static_argnames=("width",))
+def _bucket_gather(U, V, ranks, idx, *, width: int):
+    """One bucket's tiles at its ladder width; out-of-range ``idx`` slots
+    gather zero tiles of rank 0. One program per (stack, bucket) shape
+    instead of a gather, a slice and two pads per call. The slice follows
+    the gather, so no width-cut copy of the whole stack is made."""
+    def take(x):
+        return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+    return take(U)[:, :, :width], take(V)[:, :, :width], take(ranks)
+
+
+@partial(jax.jit, donate_argnums=(0, 1, 2, 3))
+def _bucket_scatter(outU, outV, out_ranks, out_err, idx, Ub, Vb, rb, eb):
+    """Scatter one bucket's results into the donated outputs (in place);
+    out-of-range ``idx`` slots are dropped."""
+    return (outU.at[idx].set(_pad_width(Ub, outU.shape[-1]), mode="drop"),
+            outV.at[idx].set(_pad_width(Vb, outV.shape[-1]), mode="drop"),
+            out_ranks.at[idx].set(rb.astype(out_ranks.dtype), mode="drop"),
+            out_err.at[idx].set(eb.astype(out_err.dtype), mode="drop"))
 
 
 def bucket_span_attrs(plan: TilePlan, bk: RankBucket, b: int, r_out: int,
                       dtype, impl) -> dict:
-    """Telemetry attributes for one rank-bucket launch (enabled mode only):
-    the dispatched (``flops_padded``, cost_analysis at the true dispatch
-    shape -- width > b uses the densify path's shape, a close proxy) vs.
-    useful (scaled by the bucket's true rank mass over its padded
-    ``count x width`` slots) FLOPs, plus the HBM traffic of the gather +
-    scatter marshaling."""
-    fl_pad = _round_core_flops(bk.padded, b, min(bk.width, b),
-                               min(r_out, bk.width), dtype,
-                               ops.resolve_impl(impl))
+    """Telemetry attributes for one rank-bucket rounding (enabled mode
+    only): the dispatched (``flops_padded``, cost_analysis at each
+    dispatch's true shape, :func:`round_dispatches` -- width > b uses the
+    densify path's shape, a close proxy) vs. useful (scaled by the
+    bucket's true rank mass over its dispatched ``count x width`` slots)
+    FLOPs, plus the HBM traffic of the gather + scatter marshaling."""
+    counts = round_dispatches(bk.count)
+    fl_pad = _bucket_round_flops(bk, b, r_out, dtype, impl)
+    slots = sum(counts)
     useful = float(plan.ranks_host[bk.idx].sum())
-    fl = fl_pad * useful / float(bk.padded * bk.width)
+    fl = fl_pad * useful / float(slots * bk.width)
     itemsize = np.dtype(dtype).itemsize
-    nbytes = 2 * (bk.padded * b * bk.width + bk.count * b * r_out) * itemsize
-    return {"width": bk.width, "count": bk.count, "padded": bk.padded,
+    nbytes = 2 * (slots * b * bk.width + bk.count * b * r_out) * itemsize
+    return {"width": bk.width, "count": bk.count, "padded": slots,
             "flops": fl, "flops_padded": fl_pad, "bytes": nbytes}
 
 
+def _bucket_round_flops(bk: RankBucket, b: int, r_out: int, dtype,
+                        impl) -> float:
+    """cost_analysis FLOPs of one bucket's rounding dispatches."""
+    return sum(_round_core_flops(cnt, b, min(bk.width, b),
+                                 min(r_out, bk.width), dtype, impl)
+               for cnt in round_dispatches(bk.count))
+
+
 # -- tile-batch sharding hook (ROADMAP: sharded tile algebra) ------------------
-
-TILE_MESH_MODES = ("pad", "error")
-
-_TILE_MESH = {"mesh": None, "on_indivisible": "pad"}
-
-
-def set_tile_mesh(mesh, *, on_indivisible: str = "pad"):
-    """Install (or clear, with ``None``) the mesh that the tile-algebra
-    batches shard their leading output-tile axis over. Returns the
-    previously installed mesh so callers can restore it.
-
-    ``on_indivisible`` decides what :func:`shard_tile_batch` does when a
-    batch axis does not divide the mesh's DP axis size -- there is no
-    silent identity fallback any more:
-
-    * ``"pad"`` (default): zero-pad the leading axis up to the next
-      multiple and shard the padded array. Zero tiles are numerically
-      inert in every accumulation path, and the index-driven gathers /
-      scatters of the tile algebra never reference the trailing pad
-      slots, so results are unchanged. Call sites that must keep the
-      caller-visible shape (``preserve_shape=True``) replicate instead.
-    * ``"error"``: raise ``ValueError`` with the offending sizes, so a
-      topology mismatch fails at the first sharded dispatch instead of
-      silently running replicated.
-    """
-    if on_indivisible not in TILE_MESH_MODES:
-        raise ValueError(f"on_indivisible must be one of {TILE_MESH_MODES}, "
-                         f"got {on_indivisible!r}")
-    prev = _TILE_MESH["mesh"]
-    _TILE_MESH["mesh"] = mesh
-    _TILE_MESH["on_indivisible"] = on_indivisible
-    return prev
-
-
-def tile_mesh():
-    return _TILE_MESH["mesh"]
-
-
-def tile_dp_size() -> int:
-    """Size of the installed mesh's data-parallel axes (1 when no mesh)."""
-    mesh = _TILE_MESH["mesh"]
-    if mesh is None:
-        return 1
-    from ..launch.mesh import dp_axes
-
-    return int(np.prod([mesh.shape[a] for a in dp_axes(mesh)], initial=1))
+# The installed mesh itself (set_tile_mesh / tile_mesh) is kept in
+# launch/sharding.py, below this module and kernels/ops.py.
 
 
 def pad_tile_batch(n: int) -> int:
@@ -579,13 +618,11 @@ def shard_tile_batch(*arrays, preserve_shape: bool = False):
     state, scatter bases): they shard when divisible and replicate
     otherwise under ``"pad"``; ``"error"`` still raises.
     """
-    mesh = _TILE_MESH["mesh"]
+    mesh = tile_mesh()
     if mesh is None:
         return arrays[0] if len(arrays) == 1 else arrays
-    from ..launch.sharding import tile_batch_sharding
-
     dp = tile_dp_size()
-    mode = _TILE_MESH["on_indivisible"]
+    mode = tile_mesh_mode()
     out = []
     for x in arrays:
         n = int(x.shape[0])

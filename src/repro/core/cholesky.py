@@ -58,6 +58,7 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
+from functools import partial
 from typing import Optional
 
 import jax
@@ -81,6 +82,7 @@ from .tlr import (TLRMatrix, num_tiles, tril_index, tril_pairs,
                   zeros_like_structure)
 from ..kernels import ops
 from .. import faults, obs
+from ..precision import einsum, matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,41 +151,59 @@ def _row_indices(i: int, k: int) -> list[int]:
     return [tril_index(i, j) for j in range(k)]
 
 
-def _gather_L_rows(L: TLRMatrix, rows: np.ndarray, k: int):
-    """L tiles (i, j) for each i in rows, j<k: (T, k, b, r) each."""
-    idx = np.array([_row_indices(int(i), k) for i in rows], np.int32)
-    idx = idx.reshape(len(rows), k)
-    return jnp.take(L.U, idx, axis=0), jnp.take(L.V, idx, axis=0)
+def _L_index(rows, k: int, Tb: int, Jb: int):
+    """Packed indices of the L tiles (i, j), i in ``rows``, j < k, padded
+    to (Tb, Jb), with the mask of the real slots."""
+    idx = np.zeros((Tb, Jb), np.int32)
+    for t, i in enumerate(rows):
+        idx[t, :k] = _row_indices(int(i), k)
+    valid = ((np.arange(Tb) < len(rows))[:, None]
+             & (np.arange(Jb) < k)[None, :])
+    return idx, valid
 
 
-def _gather_L_row(L: TLRMatrix, i: int, k: int):
-    idx = np.array(_row_indices(i, k), np.int32)
-    return jnp.take(L.U, idx, axis=0), jnp.take(L.V, idx, axis=0)
+def _A_index(rows, k: int, perm: np.ndarray, Tb: int):
+    """Packed indices of the original-A tiles of logical (i, k), i in
+    ``rows``, padded to Tb, resolving the pivot perm: a logical tile maps
+    to original (perm[i], perm[k]), and when perm[i] < perm[k] the stored
+    tile is its transpose, so the U/V roles swap (``flip``)."""
+    idx = np.zeros(Tb, np.int32)
+    flip = np.zeros(Tb, bool)
+    ok = int(perm[k])
+    for t, i in enumerate(rows):
+        oi = int(perm[i])
+        idx[t] = tril_index(max(oi, ok), min(oi, ok))
+        flip[t] = oi < ok
+    return idx, np.arange(Tb) < len(rows), flip
 
 
-def _gather_A_tiles(A: TLRMatrix, pairs: list[tuple[int, int]], perm: np.ndarray):
-    """Original-A tiles + ranks for logical (i, j) pairs, resolving the pivot
-    perm.
+@partial(jax.jit, static_argnames=("w",))
+def _gather_tiles(U, V, ranks, idx, valid, flip=None, *, w=None):
+    """Tiles ``U[idx]``, ``V[idx]`` (first ``w`` columns) and their ranks
+    (None without ``ranks``); slots where ``valid`` is False are zero, and
+    where ``flip`` is True the U/V roles swap. The host pads ``idx`` up to
+    the bucket sizes, so a factorization compiles one gather per bucket
+    shape, not a gather and its pads per column."""
+    if w is not None:
+        U, V = U[..., :w], V[..., :w]
+    Ug, Vg = jnp.take(U, idx, axis=0), jnp.take(V, idx, axis=0)
+    if flip is not None:
+        f = flip[..., None, None]
+        Ug, Vg = jnp.where(f, Vg, Ug), jnp.where(f, Ug, Vg)
+    m = valid[..., None, None]
+    zero = jnp.zeros((), U.dtype)
+    return (jnp.where(m, Ug, zero), jnp.where(m, Vg, zero),
+            None if ranks is None
+            else jnp.where(valid, jnp.take(ranks, idx), 0))
 
-    A logical tile (i, j) maps to original (perm[i], perm[j]); when
-    perm[i] < perm[j] the stored tile is its transpose, so the U/V roles swap.
-    """
-    idx, flip = [], []
-    for (i, j) in pairs:
-        oi, oj = int(perm[i]), int(perm[j])
-        if oi > oj:
-            idx.append(tril_index(oi, oj)); flip.append(False)
-        else:
-            idx.append(tril_index(oj, oi)); flip.append(True)
-    idx = np.asarray(idx, np.int32)
-    flip = np.asarray(flip)
-    U0 = jnp.take(A.U, idx, axis=0)
-    V0 = jnp.take(A.V, idx, axis=0)
-    ranks = jnp.take(A.ranks, jnp.asarray(idx))
-    f = jnp.asarray(flip)[:, None, None]
-    Ua = jnp.where(f, V0, U0)
-    Va = jnp.where(f, U0, V0)
-    return Ua, Va, ranks
+
+@jax.jit
+def _masked_rows(x, valid):
+    """The first ``len(valid)`` rows of ``x``, zero where ``valid`` is
+    False."""
+    rows = x[:valid.shape[0]]
+    return jnp.where(valid.reshape(valid.shape + (1,) * (x.ndim - 1)),
+                     rows, jnp.zeros((), x.dtype))
 
 
 # -- sampling closures (Eq. 2 / Eq. 3) ----------------------------------------
@@ -219,7 +239,7 @@ def make_column_samplers(ldl: bool, impl: str | None = None):
         shared = Omega.ndim == 2
         Om_t = jnp.broadcast_to(Omega, (T, b, s)) if shared else Omega
         # A-term: Ya[t] = Ua[t][:, :rank_t] @ (Va[t]^T Omega_t)
-        VtOm = jnp.einsum("tbr,tbs->trs", Va, Om_t)
+        VtOm = einsum("tbr,tbs->trs", Va, Om_t)
         Ya = ops.batched_gemm(Ua, VtOm, data["ranksA"], impl=impl)
         if shared:
             # Hoisted per-column intermediate, then the fused j-reduction.
@@ -249,7 +269,7 @@ def make_column_samplers(ldl: bool, impl: str | None = None):
         T, b = Ua.shape[0], Ua.shape[1]
         J, r = Uk.shape[0], Uk.shape[2]
         R = Q.shape[-1]
-        UtQ = jnp.einsum("tbr,tbq->trq", Ua, Q)
+        UtQ = einsum("tbr,tbq->trq", Ua, Q)
         Ba = ops.batched_gemm(Va, UtQ, data["ranksA"], impl=impl)
         # S2[t,j] = Vi[t,j] (Ui[t,j]^T Q[t]);  Bu[t] = sum_j Uk[j] (Vk[j]^T S2)
         Q_r = jnp.broadcast_to(Q[:, None], (T, J, b, R)).reshape(T * J, b, R)
@@ -272,19 +292,20 @@ def make_column_samplers(ldl: bool, impl: str | None = None):
 def _diag_update_sum(Uk, Vk, dk=None):
     """sum_j L(k,j) D_j L(k,j)^T as a dense (b, b) block."""
     if dk is None:
-        G = jnp.einsum("jbr,jbq->jrq", Vk, Vk)
+        G = einsum("jbr,jbq->jrq", Vk, Vk)
     else:
-        G = jnp.einsum("jbr,jb,jbq->jrq", Vk, dk, Vk)
-    M = jnp.einsum("jbr,jrq->jbq", Uk, G)
-    return jnp.einsum("jbq,jcq->bc", M, Uk)
+        G = einsum("jbr,jb,jbq->jrq", Vk, dk, Vk)
+    M = einsum("jbr,jrq->jbq", Uk, G)
+    return einsum("jbq,jcq->bc", M, Uk)
 
 
+@partial(jax.jit, static_argnames=("mode", "eps", "bs"))
 def _schur_compensate(Akk, Dsum, mode: str, eps: float, bs: int, key):
     """Section 5.1.1: subtract a *compressed* update / diagonal-compensate."""
     b = Akk.shape[0]
     p = ARAParams(bs=min(bs, b), r_max=b, eps=eps)
     Q, B, rank, _ = ara_mod.ara_compress_dense(Dsum[None], key, p)
-    Dbar = Q[0] @ B[0].T
+    Dbar = matmul(Q[0], B[0].T)
     Dbar = 0.5 * (Dbar + Dbar.T)
     if mode == "full":
         # A - Dbar  ==  A - D + (D - Dbar), the PSD-compensated update
@@ -294,6 +315,7 @@ def _schur_compensate(Akk, Dsum, mode: str, eps: float, bs: int, key):
     return Akk - Dsum + jnp.diag(comp)
 
 
+@jax.jit
 def robust_cholesky(Akk, delta):
     """Dense Cholesky with eigenvalue-clamp fallback (Algorithm 8 analogue).
 
@@ -308,7 +330,7 @@ def robust_cholesky(Akk, delta):
     def fallback(_):
         w, W = jnp.linalg.eigh(Akk)
         w = jnp.maximum(w, delta)
-        Amod = (W * w) @ W.T
+        Amod = matmul(W * w, W.T)
         Amod = 0.5 * (Amod + Amod.T)
         return jnp.linalg.cholesky(Amod)
 
@@ -316,6 +338,7 @@ def robust_cholesky(Akk, delta):
     return Lout, bad
 
 
+@jax.jit
 def dense_ldlt_tile(Akk):
     """Unpivoted dense LDL^T of one tile: returns unit-lower L and d (b,)."""
     b = Akk.shape[0]
@@ -326,7 +349,7 @@ def dense_ldlt_tile(Akk):
     def body(j, carry):
         L, d = carry
         w = jnp.where(ar < j, d * L[j, :], 0.0)
-        c = Akk[:, j] - L @ w
+        c = Akk[:, j] - matmul(L, w)
         dj = c[j]
         tiny = jnp.asarray(1e-30, dtype)
         dj = jnp.where(jnp.abs(dj) < tiny, tiny, dj)
@@ -460,22 +483,19 @@ def _build_column_data(A, Lout, rows, k, perm, dvec, ldl,
     T = len(rows)
     Tb = T if Tb is None else Tb
     Jb = max(1, k) if Jb is None else Jb
-    Ui, Vi = _gather_L_rows(Lout, rows, k)                   # (T, k, b, r)
-    Uk, Vk = _gather_L_row(Lout, k, k)                       # (k, b, r)
-    Ua, Va, ra = _gather_A_tiles(A, [(int(i), k) for i in rows], perm)
-    if wA is not None:
-        Ua, Va = Ua[:, :, :wA], Va[:, :, :wA]
-    if wL is not None:
-        Uk, Vk = Uk[:, :, :wL], Vk[:, :, :wL]
-        Ui, Vi = Ui[..., :wL], Vi[..., :wL]
+    li, lv = _L_index(rows, k, Tb, Jb)
+    Ui, Vi, _ = _gather_tiles(Lout.U, Lout.V, None, li, lv,
+                              w=wL)                          # (Tb, Jb, b, r)
+    ki, kv = _L_index([k], k, 1, Jb)
+    Uk, Vk, _ = _gather_tiles(Lout.U, Lout.V, None, ki[0], kv[0],
+                              w=wL)                          # (Jb, b, r)
+    Ua, Va, ra = _gather_tiles(A.U, A.V, A.ranks,
+                               *_A_index(rows, k, perm, Tb), w=wA)
     data = {
-        "Ua": _pad_axis(Ua, Tb), "Va": _pad_axis(Va, Tb),
-        "ranksA": _pad_axis(ra, Tb),
-        "Uk": _pad_axis(Uk, Jb), "Vk": _pad_axis(Vk, Jb),
-        "Ui": _pad_axis(_pad_axis(Ui, Jb, axis=1), Tb),
-        "Vi": _pad_axis(_pad_axis(Vi, Jb, axis=1), Tb),
+        "Ua": Ua, "Va": Va, "ranksA": ra, "Uk": Uk, "Vk": Vk,
+        "Ui": Ui, "Vi": Vi,
         "valid": jnp.arange(Tb) < T,
-        "dk": _pad_axis(dvec[:k], Jb) if ldl else None,
+        "dk": _masked_rows(dvec, kv[0]) if ldl else None,
     }
     return data
 
@@ -640,7 +660,10 @@ def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
 
 def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
                         Lkk, dk_new, key, ladder, widths=(None, None)):
-    """Algorithm 5: rank-sorted subset with converged-tile eviction/refill."""
+    """Algorithm 5: rank-sorted subset with converged-tile eviction/refill.
+
+    Returns the panel (Q, Vnew, ranks) zero-padded to the column's row
+    bucket, and ``info`` for its ``T`` real rows."""
     opts, p = pipe.opts, pipe.p
     wA, wL = widths
     T_col = len(rows)
@@ -669,29 +692,45 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
                               opts.ldl, Tb=Tb, Jb=Jb, wA=wA, wL=wL)
     state = init_state(Tb, A.b, p, A.dtype, valid=data["valid"])
 
-    done_Q = {}
-    done_rank = {}
-    done_err = {}
+    # Finished bases land in a bucket-padded buffer in row order (padding
+    # rows stay zero), ranks and errors on the host.
+    pos_of = {int(i): t for t, i in enumerate(rows)}
+    Q_all = jnp.zeros((Tb_col, A.b, p.r_max), A.dtype)
+    ranks_h = np.zeros(T_col, np.int32)
+    err_h = np.zeros(T_col)
     total_iters = 0
     safety_valve = False
     slot_live = [True] * len(slot_rows)
+
+    def finish(slots):
+        """Record the bases, ranks and errors of ``slots``; one device
+        gather + scatter and one host pull for the lot."""
+        nonlocal Q_all
+        pos = np.full(Tb, Tb_col, np.int32)      # out of range: dropped
+        sl = np.zeros(Tb, np.int32)
+        pos[:len(slots)] = [pos_of[slot_rows[s]] for s in slots]
+        sl[:len(slots)] = slots
+        Q_all = _stash_rows(Q_all, state.Q, pos, sl)
+        rk, er = np.asarray(state.rank), np.asarray(state.err)
+        for s in slots:
+            ranks_h[pos_of[slot_rows[s]]] = rk[s]
+            err_h[pos_of[slot_rows[s]]] = er[s]
 
     while any(slot_live):
         state = pipe.dyn_step(data, state, key)
         total_iters += 1
         conv = np.asarray(state.converged)
         # Evict converged tiles; refill their slots from the queue.
+        done = [s for s, live in enumerate(slot_live) if live and conv[s]]
+        if done:
+            finish(done)
         refills = []
-        for s, live in enumerate(slot_live):
-            if live and conv[s]:
-                done_Q[slot_rows[s]] = state.Q[s]
-                done_rank[slot_rows[s]] = int(state.rank[s])
-                done_err[slot_rows[s]] = float(state.err[s])
-                if queue:
-                    slot_rows[s] = queue.pop(0)
-                    refills.append(s)
-                else:
-                    slot_live[s] = False
+        for s in done:
+            if queue:
+                slot_rows[s] = queue.pop(0)
+                refills.append(s)
+            else:
+                slot_live[s] = False
         if refills:
             sr = np.asarray(refills, np.int32)
             new_rows = np.asarray([slot_rows[s] for s in refills])
@@ -710,23 +749,16 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
             # Safety valve: the iteration budget for the whole column is
             # exhausted. Flush the still-live slots with their current
             # partial bases (best basis accumulated so far) instead of
-            # dropping them -- the assembly below indexes done_Q by row, so
-            # leaving a live slot unrecorded was a guaranteed KeyError.
+            # dropping them.
             safety_valve = True
-            n_live, n_queued = sum(slot_live), len(queue)
-            for s, live in enumerate(slot_live):
-                if live:
-                    done_Q[slot_rows[s]] = state.Q[s]
-                    done_rank[slot_rows[s]] = int(state.rank[s])
-                    done_err[slot_rows[s]] = float(state.err[s])
-                    slot_live[s] = False
-            # Rows still queued never entered a slot: record them at rank 0
+            live = [s for s, lv in enumerate(slot_live) if lv]
+            n_live, n_queued = len(live), len(queue)
+            finish(live)
+            # Rows still queued never entered a slot: they keep rank 0
             # (zero basis => zero tile) with an infinite error estimate so
             # the caller can see they were never processed.
             for i in queue:
-                done_Q[i] = jnp.zeros_like(state.Q[0])
-                done_rank[i] = 0
-                done_err[i] = float("inf")
+                err_h[pos_of[i]] = float("inf")
             warnings.warn(
                 f"TLR column {k}: ARA safety valve tripped after "
                 f"{total_iters} iterations; {n_live} tile(s) kept their "
@@ -734,29 +766,31 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
                 f"recorded at rank 0 -- the factorization is degraded "
                 f"(raise max_iters/r_max or loosen eps; see "
                 f"stats['safety_valve'])", RuntimeWarning, stacklevel=4)
-            queue = []
             break
 
-    # Assemble per-row results in the original row order, then project once
-    # (batched, bucket-padded full column) into the bases.
-    Q_all = jnp.stack([done_Q[int(i)] for i in rows])
-    ranks_h = np.asarray([done_rank[int(i)] for i in rows], np.int32)
-    ranks = jnp.asarray(ranks_h)
+    # Project once (batched, bucket-padded full column) into the bases.
+    # The panel comes back padded to the column bucket, ready for the
+    # scatter into L.
     full_data = _build_column_data(A, Lout, rows, k, perm, dvec, opts.ldl,
                                    Tb=Tb_col, Jb=Jb, wA=wA, wL=wL)
     if opts.batching == "ranked":
         # Project at the rank-ladder width covering the detected ranks.
         wq = bucket_width(ranks_h, p.r_max)
-        Vnew = pipe.project(full_data,
-                            _pad_axis(Q_all[:, :, :wq], Tb_col), Lkk, dk_new)
+        Vnew = pipe.project(full_data, Q_all[:, :, :wq], Lkk, dk_new)
         Vnew = _pad_axis(Vnew, p.r_max, axis=2)
     else:
         wq = None
-        Vnew = pipe.project(full_data, _pad_axis(Q_all, Tb_col), Lkk, dk_new)
+        Vnew = pipe.project(full_data, Q_all, Lkk, dk_new)
     info = {"iters": total_iters, "T": T_col, "Tb": Tb, "Jb": Jb,
-            "err": np.asarray([done_err[int(i)] for i in rows]),
-            "safety_valve": safety_valve, "wQ": wq}
-    return Q_all, Vnew[:T_col], ranks, info
+            "err": err_h, "safety_valve": safety_valve, "wQ": wq}
+    ranks = jnp.asarray(np.pad(ranks_h, (0, Tb_col - T_col)))
+    return Q_all, Vnew, ranks, info
+
+
+@jax.jit
+def _stash_rows(buf, Q, pos, slot):
+    """``buf[pos[i]] = Q[slot[i]]``; out-of-range positions are dropped."""
+    return buf.at[pos].set(jnp.take(Q, slot, axis=0), mode="drop")
 
 
 # -- main drivers ---------------------------------------------------------------
@@ -898,12 +932,11 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             with obs.span("chol.diag", cat="factor", k=k):
                 Akk = A.D[st.perm[k]]
                 if k > 0:
-                    Uk, Vk = _gather_L_row(_Lmat(), k, k)
-                    if batching == "ranked":
-                        Uk, Vk = Uk[:, :, :st.wL], Vk[:, :, :st.wL]
-                    dk = _pad_axis(st.dvec[:k], jd) if opts.ldl else None
-                    Dsum = pipe.diag_update(_pad_axis(Uk, jd),
-                                            _pad_axis(Vk, jd), dk)
+                    ki, kv = _L_index([k], k, 1, jd)
+                    Uk, Vk, _ = _gather_tiles(st.LU, st.LV, None, ki[0],
+                                              kv[0], w=st.wL)
+                    dk = _masked_rows(st.dvec, kv[0]) if opts.ldl else None
+                    Dsum = pipe.diag_update(Uk, Vk, dk)
                     if opts.schur and not opts.ldl:
                         Akk = _schur_compensate(Akk, Dsum, opts.schur,
                                                 opts.eps, opts.bs, kkey)
@@ -1009,7 +1042,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             Q = Q.at[posj].set(Qb)
             Vnew = Vnew.at[posj].set(Vb)
             ranks = ranks.at[posj].set(rb)
-            ranks_h = np.asarray(ranks)
+            ranks_h = np.asarray(ranks)[:len(rows)]
             err_h[pos] = np.asarray(ib["err"], float)
             over[:] = False
             over[pos] = ara_mod.rank_overflow(
@@ -1024,7 +1057,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             Q = Q.at[posj].set(Qd)
             Vnew = Vnew.at[posj].set(Vd)
             ranks = ranks.at[posj].set(rd)
-            ranks_h = np.asarray(ranks)
+            ranks_h = np.asarray(ranks)[:len(rows)]
             err_h[pos] = ed
             over[:] = False
             over[pos] = ~(ed <= rp.eps_floor(opts.eps))
@@ -1058,9 +1091,9 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                         pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
                         kkey, ladder, widths=(wA, st.wL))
                 if faults.active():
-                    Q = faults.corrupt_panel(Q, k)
+                    Q = faults.corrupt_panel(Q[:T], k)
                 jax.block_until_ready((Q, Vnew, ranks))
-                ranks_h = np.asarray(ranks)
+                ranks_h = np.asarray(ranks)[:T]   # the panel may be padded
                 if obs.enabled():
                     _psp.set(T=info["T"], Tb=info["Tb"], Jb=info["Jb"],
                              iters=info["iters"],
@@ -1088,8 +1121,8 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 _pad_axis(Vnew, Tbs), _pad_axis(ranks, Tbs))
             if opts.pivot:
                 # Dsum_all[i] += L(i,k) L(i,k)^T for the remaining rows.
-                G = jnp.einsum("tbr,tbq->trq", Vnew, Vnew)
-                upd = jnp.einsum("tbr,trq,tcq->tbc", Q, G, Q)
+                G = einsum("tbr,tbq->trq", Vnew[:T], Vnew[:T])
+                upd = einsum("tbr,trq,tcq->tbc", Q[:T], G, Q[:T])
                 st.Dsum_all = st.Dsum_all.at[k + 1 :].add(upd)
 
         def fn():
@@ -1145,6 +1178,19 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
 # -- right-looking driver (DESIGN.md section 7) --------------------------------
 
 
+@partial(jax.jit, static_argnames=("rows", "width"))
+def _widen(U, V, *, rows: int, width: int):
+    """``U``, ``V`` zero-padded to ``rows`` tiles of ``width`` columns, in
+    one program: the accumulation buffers are the widest arrays of the
+    right driver, and building them as zeros plus an update would hold a
+    second copy of each."""
+    def pad(x):
+        return jnp.pad(x, ((0, rows - x.shape[0]), (0, 0),
+                           (0, width - x.shape[2])))
+
+    return pad(U), pad(V)
+
+
 class _RightPipeline:
     """Per-factorization cache of the jitted right-looking panel step.
 
@@ -1155,7 +1201,7 @@ class _RightPipeline:
     ~log2(nb) compiled variants, mirroring the left driver's contract.
     """
 
-    def __init__(self, opts: CholOptions, r_p: int, impl: str):
+    def __init__(self, opts: CholOptions, r_p: int, impl: str | None):
         self.traces = {"column": 0}
         self._column_traced = False
         self._scatter_t0 = _SCATTER_TRACES
@@ -1221,7 +1267,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     nb, b = A.nb, A.b
     nt = num_tiles(nb)
     r_p = opts.r_max_out or A.r_max
-    impl = ops.resolve_impl(opts.impl)
+    impl = opts.impl  # None: each op resolves its own default
     policy = resolve_policy(opts.batching, tile_plan(A.ranks, A.r_max),
                             b=b, dtype=A.dtype,
                             right_flush=opts.right_flush)
@@ -1230,6 +1276,12 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     dtype = A.dtype
     flush_cols = policy["right_flush"]
     w_acc = max(b, A.r_max) + flush_cols * r_p
+    # Ranked batching flushes when a trailing tile's content would pass
+    # ``flush_cols`` appends beyond the ranks a rounded tile normally has
+    # (r_max), not beyond the buffer, which keeps room for a rounded rank
+    # up to b: the rounding cores then stay at most (1 + flush_cols) * r_p
+    # wide, instead of b + r_p wide (an n = b SVD per tile) when r_p < b.
+    w_flush = min(w_acc, max(A.r_max, r_p) + flush_cols * r_p)
 
     # Accumulation buffers: every off-diagonal tile's running low-rank
     # concatenation, seeded with A's factors. Flat batching tracks one
@@ -1246,8 +1298,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     mesh = tile_mesh()
     lookahead = bool(opts.lookahead) and nb > 1
     nt_p = pad_tile_batch(nt)
-    accU = jnp.zeros((nt_p, b, w_acc), dtype).at[:nt, :, :A.r_max].set(A.U)
-    accV = jnp.zeros((nt_p, b, w_acc), dtype).at[:nt, :, :A.r_max].set(A.V)
+    accU, accV = _widen(A.U, A.V, rows=nt_p, width=w_acc)
     if ranked:
         tile_w = np.zeros(nt_p, np.int64)
         tile_w[:nt] = np.asarray(A.ranks, np.int64)
@@ -1260,7 +1311,8 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     alg0 = algebra_trace_count()
     stats = {
         "column_iters": [], "column_ranks": [], "modified_chol": 0,
-        "pivots": [], "mode": opts.mode, "impl": impl, "algo": "right",
+        "pivots": [], "mode": opts.mode, "impl": ops.resolve_impl(impl),
+        "algo": "right",
         "bucket_ladder": list(ladder), "column_events": [],
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "flushes": 0, "acc_width": w_acc,
@@ -1313,34 +1365,37 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         T = len(rows)
         Tb = _bucket_up(T, ladder)
         tidx_np = np.asarray([tril_index(int(i), k) for i in rows], np.int64)
-        tidx = jnp.asarray(tidx_np, jnp.int32)
+        # the panel's tiles, padded to the row bucket (zero tiles past T)
+        pidx = np.zeros(Tb, np.int32)
+        pidx[:T] = tidx_np
+        pvalid = np.arange(Tb) < T
         c = st.col[k]
 
         def compute():
             Lkk, dk_new = c["Lkk"], c["dk"]
             with obs.span("chol.panel", cat="factor", k=k, T=T,
                           Tb=Tb) as _psp:
+                aU, aV, _ = _gather_tiles(st.accU, st.accV, None, pidx,
+                                          pvalid)
                 if ranked:
                     # Rank-bucketed panel recompression: each panel tile
                     # rounds at the ladder width covering its tracked
-                    # content width, then one jitted TRSM (bucket-padded
-                    # row batch) scales the bases.
-                    aU = jnp.take(st.accU, tidx, axis=0)
-                    aV = jnp.take(st.accV, tidx, axis=0)
+                    # content width (the pad tiles sit at width 0), then
+                    # one jitted TRSM (bucket-padded row batch) scales the
+                    # bases.
                     Q, B, ranks, err = bucketed_round_tiles(
-                        aU, aV, st.tile_w[tidx_np], eps, r_out=r_p,
-                        impl=impl)
-                    Vn = pipe.trsm(_pad_axis(B, Tb), Lkk, dk_new)
-                    Qs, Vns = Q, Vn[:T]
+                        aU, aV, np.where(pvalid, st.tile_w[pidx], 0), eps,
+                        r_out=r_p, impl=impl)
+                    Vn = pipe.trsm(B, Lkk, dk_new)
                 else:
-                    aU = _pad_axis(jnp.take(st.accU, tidx, axis=0), Tb)
-                    aV = _pad_axis(jnp.take(st.accV, tidx, axis=0), Tb)
                     Q, Vn, ranks, err = pipe.panel_step(aU, aV, Lkk,
                                                         dk_new, eps)
-                    Qs, Vns = Q[:T], Vn[:T]
+                # the panel stays padded to the row bucket, zero past T
+                Qs, Vns = _masked_rows(Q, pvalid), _masked_rows(Vn, pvalid)
+                ranks = jnp.where(pvalid, ranks, 0)
                 if faults.active():
                     Qs = faults.corrupt_panel(Qs, k)
-                ranks_h = np.asarray(ranks[:T])
+                ranks_h = np.asarray(ranks)[:T]
                 if obs.enabled():
                     _psp.set(rank_hist=obs.rank_hist(ranks_h, r_p))
             return Qs, Vns, ranks, ranks_h, err
@@ -1349,12 +1404,9 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             # Donated scatter of the factored panel into Lout's stacks
             # (in-place on the three persistent output arrays; sharding
             # survives the aliasing).
-            idxp = np.zeros(Tb, np.int64)
-            idxp[:T] = tidx_np
             st.LU, st.LV, st.LR = pipe.scatter(
-                st.LU, st.LV, st.LR, jnp.asarray(idxp, jnp.int32),
-                jnp.asarray(np.arange(Tb) < T), _pad_axis(Qs, Tb),
-                _pad_axis(Vns, Tb), _pad_axis(ranks[:T], Tb))
+                st.LU, st.LV, st.LR, jnp.asarray(pidx), jnp.asarray(pvalid),
+                Qs, Vns, ranks)
             if ranked:
                 # A rank-0 panel column contributes an exactly-zero Schur
                 # update, so the trailing update skips it outright -- no
@@ -1461,20 +1513,22 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             if ranked:
                 if wk and part != "tail":
                     # Flush before the column's first append when the next
-                    # append would overflow: recompress the whole grid at
-                    # the per-tile rank-bucket widths. The single check
+                    # append would overflow: recompress the trailing tiles
+                    # at their rank-bucket widths. The single check
                     # covers head+tail -- they append wk to disjoint tile
                     # sets, so the max content width grows by wk once.
                     high = int(st.tile_w[trail].max()) if trail.size else 0
-                    if high + wk > w_acc:
+                    if high + wk > w_flush:
                         with obs.span("chol.flush", cat="factor", k=k):
-                            Uc, Vc, rc, _ = bucketed_round_tiles(
-                                st.accU, st.accV, st.tile_w, eps, r_out=b,
-                                impl=impl)
-                            st.accU = jnp.zeros_like(st.accU) \
-                                .at[:, :, :b].set(Uc)
-                            st.accV = jnp.zeros_like(st.accV) \
-                                .at[:, :, :b].set(Vc)
+                            # Round the trailing tiles in place (no second
+                            # pair of buffers); the tiles of factored
+                            # columns are never read again and are skipped.
+                            live = np.zeros(nt_p, bool)
+                            live[trail] = True
+                            st.accU, st.accV, rc, _ = bucketed_round_tiles(
+                                st.accU, st.accV,
+                                np.where(live, st.tile_w, 0), eps, r_out=b,
+                                impl=impl, inplace=True)
                             st.tile_w = np.asarray(rc, dtype=np.int64)
                             if mesh is not None:
                                 st.accU, st.accV = shard_tile_batch(
@@ -1485,7 +1539,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                                   T=T, part=part):
                         st.accU, st.accV, st.D = tlr_syrk_column(
                             st.accU, st.accV, st.tile_w, st.D,
-                            Qs[:, :, :wk], Vns[:, :, :wk], ranks[:T],
+                            Qs[:, :, :wk], Vns[:, :, :wk], ranks,
                             dk_new, k, impl=impl, part=part, donate=True)
                     st.tile_w[bump] += wk
                 if part != "head":
@@ -1501,10 +1555,9 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                     with obs.span("chol.flush", cat="factor", k=k):
                         Uc, Vc, _, _ = tlr_round_tiles(
                             st.accU, st.accV, eps, r_out=b, impl=impl)
-                        st.accU = jnp.zeros_like(st.accU) \
-                            .at[:, :, :b].set(Uc)
-                        st.accV = jnp.zeros_like(st.accV) \
-                            .at[:, :, :b].set(Vc)
+                        st.accU = st.accV = None
+                        st.accU, st.accV = _widen(Uc, Vc, rows=nt_p,
+                                                  width=w_acc)
                         st.used = b
                         if mesh is not None:
                             st.accU, st.accV = shard_tile_batch(
@@ -1514,7 +1567,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                               part=part):
                     st.accU, st.accV, st.D = tlr_syrk_column(
                         st.accU, st.accV, st.used, st.D, Qs, Vns,
-                        ranks[:T], dk_new, k, impl=impl, part=part,
+                        ranks, dk_new, k, impl=impl, part=part,
                         donate=True)
                 if part != "head":
                     st.used += r_p
@@ -1534,7 +1587,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                     "k": k, "T": T, "Tb": c["Tb"], "Jb": 0, "seconds": dt,
                     "traced": c["panel_traced"]
                     or batching_trace_count() > c["bt0"],
-                    "err": np.asarray(c["err"][:T]),
+                    "err": np.asarray(c["err"])[:T],
                     "wQ": wk if ranked else None,
                 })
                 c.pop("Qs", None)
@@ -1649,10 +1702,12 @@ def _power_norms(tiles, iters: int, key):
     x = jax.random.normal(key, (T, b), tiles.dtype)
     x = x / jnp.linalg.norm(x, axis=1, keepdims=True)
 
+    tiny = jnp.finfo(tiles.dtype).tiny  # a zero tile stays zero, not NaN
+
     def body(_, x):
-        y = jnp.einsum("tbc,tc->tb", tiles, x)
-        return y / jnp.maximum(jnp.linalg.norm(y, axis=1, keepdims=True), 1e-300)
+        y = einsum("tbc,tc->tb", tiles, x)
+        return y / jnp.maximum(jnp.linalg.norm(y, axis=1, keepdims=True), tiny)
 
     x = jax.lax.fori_loop(0, iters, body, x)
-    y = jnp.einsum("tbc,tc->tb", tiles, x)
+    y = einsum("tbc,tc->tb", tiles, x)
     return jnp.linalg.norm(y, axis=1)

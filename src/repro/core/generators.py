@@ -102,6 +102,48 @@ def fractional_diffusion(
 # -- assembled problems ------------------------------------------------------
 
 
+def covariance_points(n: int, d: int, tile_size: int, *,
+                      geometry: str = "grid", seed: int = 0) -> np.ndarray:
+    """The section 6.1 point cloud, KD-tree ordered into tiles."""
+    from .ordering import kd_tree_ordering
+
+    pts = grid_points(n, d) if geometry == "grid" else ball_points(n, d, seed)
+    pts = pts[:n]
+    return pts[kd_tree_ordering(pts, tile_size)]
+
+
+def exp_covariance_device(points, ell: float, nugget: float = 1e-8, *,
+                          dtype=None, rows: int = 512):
+    """:func:`exp_covariance` evaluated on the default device, ``rows``
+    rows at a time, so no host array or (n, n, d) intermediate is ever
+    formed. Distances are taken from coordinate differences (exact zeros
+    on the diagonal), not from the Gram identity, whose cancellation at
+    f32 would perturb the diagonal by ~1e-3."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = dtype or jnp.result_type(float)
+    n, d = points.shape
+    rows = min(rows, n)
+    if n % rows:
+        raise ValueError(f"n={n} must be a multiple of rows={rows}")
+
+    @jax.jit
+    def build(P):
+        def block(i):
+            Pi = jax.lax.dynamic_slice_in_dim(P, i * rows, rows)
+            diff = Pi[:, None, :] - P[None, :, :]
+            r = jnp.sqrt(jnp.sum(diff * diff, axis=-1))
+            K = jnp.exp(-r / ell)
+            eye = (jnp.arange(rows)[:, None] + i * rows
+                   == jnp.arange(n)[None, :])
+            return K + nugget * eye.astype(dtype)
+
+        return jax.lax.map(block, jnp.arange(n // rows)).reshape(n, n)
+
+    return build(jnp.asarray(points, dtype))
+
+
 def covariance_problem(
     n: int,
     d: int,
@@ -112,13 +154,8 @@ def covariance_problem(
     kernel: str = "exp",
 ):
     """Points (KD-tree ordered) + covariance matrix, paper's section 6.1 setup."""
-    from .ordering import kd_tree_ordering
-
     ell = 0.1 if d == 2 else 0.2
-    pts = grid_points(n, d) if geometry == "grid" else ball_points(n, d, seed)
-    pts = pts[:n]
-    perm = kd_tree_ordering(pts, tile_size)
-    pts = pts[perm]
+    pts = covariance_points(n, d, tile_size, geometry=geometry, seed=seed)
     if kernel == "exp":
         K = exp_covariance(pts, ell)
     elif kernel == "matern32":

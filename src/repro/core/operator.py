@@ -50,8 +50,10 @@ from . import solve as _solve
 # -- batched tile compression (construction hot path) --------------------------
 
 
+@partial(jax.jit, static_argnames=("nb", "b"))
 def _split_tiles(A: jax.Array, nb: int, b: int):
-    """One reshape-based gather of all tiles: diag (nb,b,b) + lower (nt,b,b)."""
+    """One reshape-based gather of all tiles: diag (nb,b,b) + lower (nt,b,b)
+    (jitted, so the gather reads A in place instead of a transposed copy)."""
     Ab = A.reshape(nb, b, nb, b).transpose(0, 2, 1, 3)
     diag = jnp.arange(nb)
     D = Ab[diag, diag]

@@ -38,6 +38,7 @@ from .batching import resolve_batching, tile_plan
 from .buckets import _bucket_ladder, _bucket_up, trace_count, trace_event
 from .. import obs
 from .tlr import TLRMatrix, tril_pairs, tril_index
+from ..precision import einsum, vdot
 
 
 # -- symmetric TLR matvec ------------------------------------------------------
@@ -48,12 +49,12 @@ def _sym_matvec(D, U, V, ranks, xb, nb: int):
     pairs = tril_pairs(nb)
     rows = jnp.asarray(pairs[:, 0], jnp.int32)
     cols = jnp.asarray(pairs[:, 1], jnp.int32)
-    yb = jnp.einsum("kbc,kc...->kb...", D, xb)
+    yb = einsum("kbc,kc...->kb...", D, xb)
     xj = jnp.take(xb, cols, axis=0)
     xi = jnp.take(xb, rows, axis=0)
     # lower tiles: y_i += U (V^T x_j);   mirrored upper: y_j += V (U^T x_i)
-    ylo = jnp.einsum("tbr,tr...->tb...", U, jnp.einsum("tbr,tb...->tr...", V, xj))
-    yup = jnp.einsum("tbr,tr...->tb...", V, jnp.einsum("tbr,tb...->tr...", U, xi))
+    ylo = einsum("tbr,tr...->tb...", U, einsum("tbr,tb...->tr...", V, xj))
+    yup = einsum("tbr,tr...->tb...", V, einsum("tbr,tb...->tr...", U, xi))
     yb = yb.at[rows].add(ylo)
     yb = yb.at[cols].add(yup)
     return yb
@@ -77,8 +78,8 @@ def _plan_chain(U, V, xb, yb, idx, src, dst, valid, *, w: int):
     Ut = jnp.take(U, idx, axis=0)[:, :, :w]
     Vt = jnp.take(V, idx, axis=0)[:, :, :w]
     xs = jnp.take(xb, src, axis=0)
-    y = jnp.einsum("tbr,tr...->tb...", Ut,
-                   jnp.einsum("tbr,tb...->tr...", Vt, xs))
+    y = einsum("tbr,tr...->tb...", Ut,
+                   einsum("tbr,tb...->tr...", Vt, xs))
     m = valid.reshape((-1,) + (1,) * (y.ndim - 1))
     return yb.at[dst].add(jnp.where(m, y, jnp.zeros_like(y)))
 
@@ -93,10 +94,10 @@ def _plan_chain_sym(U, V, xb, yb, idx, rows, cols, valid, *, w: int):
     Vt = jnp.take(V, idx, axis=0)[:, :, :w]
     xj = jnp.take(xb, cols, axis=0)
     xi = jnp.take(xb, rows, axis=0)
-    ylo = jnp.einsum("tbr,tr...->tb...", Ut,
-                     jnp.einsum("tbr,tb...->tr...", Vt, xj))
-    yup = jnp.einsum("tbr,tr...->tb...", Vt,
-                     jnp.einsum("tbr,tb...->tr...", Ut, xi))
+    ylo = einsum("tbr,tr...->tb...", Ut,
+                     einsum("tbr,tb...->tr...", Vt, xj))
+    yup = einsum("tbr,tr...->tb...", Vt,
+                     einsum("tbr,tb...->tr...", Ut, xi))
     m = valid.reshape((-1,) + (1,) * (ylo.ndim - 1))
     yb = yb.at[rows].add(jnp.where(m, ylo, jnp.zeros_like(ylo)))
     return yb.at[cols].add(jnp.where(m, yup, jnp.zeros_like(yup)))
@@ -149,7 +150,7 @@ def tlr_matvec(A: TLRMatrix, x: jax.Array, *,
     mode = resolve_batching(batching, A.ranks, A.r_max)
     if mode == "ranked":
         plan = tile_plan(A.ranks, A.r_max)
-        yb = jnp.einsum("kbc,kc...->kb...", A.D, xb)
+        yb = einsum("kbc,kc...->kb...", A.D, xb)
         for bk, (idx, rows, cols, valid) in zip(plan.buckets,
                                                 _plan_gathers(plan, nb)):
             attrs = {}
@@ -193,9 +194,9 @@ def tlr_tri_matvec(L: TLRMatrix, x: jax.Array, *, trans: bool = False,
     if mode == "ranked":
         plan = tile_plan(L.ranks, L.r_max)
         if not trans:
-            yb = jnp.einsum("kbc,kc...->kb...", L.D, xb)
+            yb = einsum("kbc,kc...->kb...", L.D, xb)
         else:
-            yb = jnp.einsum("kcb,kc...->kb...", L.D, xb)
+            yb = einsum("kcb,kc...->kb...", L.D, xb)
         for bk, (idx, rows, cols, valid) in zip(plan.buckets,
                                                 _plan_gathers(plan, nb)):
             attrs = {}
@@ -213,16 +214,16 @@ def tlr_tri_matvec(L: TLRMatrix, x: jax.Array, *, trans: bool = False,
     rows = jnp.asarray(pairs[:, 0], jnp.int32)
     cols = jnp.asarray(pairs[:, 1], jnp.int32)
     if not trans:
-        yb = jnp.einsum("kbc,kc...->kb...", L.D, xb)
+        yb = einsum("kbc,kc...->kb...", L.D, xb)
         xj = jnp.take(xb, cols, axis=0)
-        ylo = jnp.einsum("tbr,tr...->tb...", L.U,
-                         jnp.einsum("tbr,tb...->tr...", L.V, xj))
+        ylo = einsum("tbr,tr...->tb...", L.U,
+                         einsum("tbr,tb...->tr...", L.V, xj))
         yb = yb.at[rows].add(ylo)
     else:
-        yb = jnp.einsum("kcb,kc...->kb...", L.D, xb)
+        yb = einsum("kcb,kc...->kb...", L.D, xb)
         xi = jnp.take(xb, rows, axis=0)
-        yup = jnp.einsum("tbr,tr...->tb...", L.V,
-                         jnp.einsum("tbr,tb...->tr...", L.U, xi))
+        yup = einsum("tbr,tr...->tb...", L.V,
+                         einsum("tbr,tb...->tr...", L.U, xi))
         yb = yb.at[cols].add(yup)
     return yb.reshape(x.shape)
 
@@ -270,7 +271,7 @@ def _trsm_step(D, U, V, xb, k, tidx, ridx, valid, *, trans: bool, w: int):
         Dk = Dk.T
         Ut, Vt = Vt, Ut
     xk = jax.scipy.linalg.solve_triangular(Dk, yk, lower=not trans)
-    upd = jnp.einsum("tbr,trm->tbm", Ut, jnp.einsum("tbr,bm->trm", Vt, xk))
+    upd = einsum("tbr,trm->tbm", Ut, einsum("tbr,bm->trm", Vt, xk))
     upd = jnp.where(valid[:, None, None], upd, jnp.zeros_like(upd))
     xb = jax.lax.dynamic_update_index_in_dim(xb, xk, k, axis=0)
     return xb.at[ridx].add(-upd)
@@ -404,8 +405,8 @@ def tlr_trsv_reference(L: TLRMatrix, y: jax.Array, *,
             if idx:
                 ii = jnp.asarray(idx, jnp.int32)
                 Ut, Vt = jnp.take(L.U, ii, axis=0), jnp.take(L.V, ii, axis=0)
-                upd = jnp.einsum("tbr,tr...->tb...", Ut,
-                                 jnp.einsum("tbr,b...->tr...", Vt, xk))
+                upd = einsum("tbr,tr...->tb...", Ut,
+                                 einsum("tbr,b...->tr...", Vt, xk))
                 for t, i in enumerate(range(k + 1, nb)):
                     xb[i] = xb[i] - upd[t]
         else:
@@ -414,8 +415,8 @@ def tlr_trsv_reference(L: TLRMatrix, y: jax.Array, *,
                 ii = jnp.asarray(idx, jnp.int32)
                 Ut, Vt = jnp.take(L.U, ii, axis=0), jnp.take(L.V, ii, axis=0)
                 # (L^T)(j,k) = L(k,j)^T = V U^T
-                upd = jnp.einsum("tbr,tr...->tb...", Vt,
-                                 jnp.einsum("tbr,b...->tr...", Ut, xk))
+                upd = einsum("tbr,tr...->tb...", Vt,
+                                 einsum("tbr,b...->tr...", Ut, xk))
                 for t, j in enumerate(range(k)):
                     xb[j] = xb[j] - upd[t]
     return jnp.stack(xb).reshape(y.shape)
@@ -572,7 +573,7 @@ def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
     r = b_rhs - matvec(x)
     z = precond(r) if precond else r
     p_dir = z
-    rz = jnp.vdot(r, z)
+    rz = vdot(r, z)
     history = PCGHistory([float(jnp.linalg.norm(r)) / bnorm])
     rz_f = float(rz)
     if not np.isfinite(rz_f) or rz_f <= 0.0:
@@ -585,13 +586,13 @@ def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
         check scalars. Same op order as the classic per-iteration loop, so
         every intermediate is bitwise independent of ``check_every``."""
         Ap = matvec(p_dir)
-        pAp = jnp.vdot(p_dir, Ap)
+        pAp = vdot(p_dir, Ap)
         alpha = rz / pAp
         x_new = x + alpha * p_dir
         r_new = r - alpha * Ap
         rnorm = jnp.linalg.norm(r_new)
         z = precond(r_new) if precond else r_new
-        rz_new = jnp.vdot(r_new, z)
+        rz_new = vdot(r_new, z)
         beta = rz_new / rz
         p_new = z + beta * p_dir
         return (x_new, r_new, p_new, rz_new), (pAp, rnorm, rz_new)

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..precision import matmul
+
 
 def tril_index(i: int, j: int) -> int:
     """Flat index of strictly-lower tile (i, j), i > j."""
@@ -135,7 +137,7 @@ def tlr_to_dense(D, U, V, nb: int, b: int):
     for i in range(1, nb):
         for j in range(i):
             t = tril_index(i, j)
-            block = U[t] @ V[t].T
+            block = matmul(U[t], V[t].T)
             out = out.at[i * b : (i + 1) * b, j * b : (j + 1) * b].set(block)
             out = out.at[j * b : (j + 1) * b, i * b : (i + 1) * b].set(block.T)
     return out

@@ -7,7 +7,8 @@ the kernel instead runs right-looking modified Gram-Schmidt: when column
 ``k`` is finalized it is projected out of every later column with one
 rank-1 update (an outer product -- MXU work), so the whole factorization is
 ``r`` sequential steps of matvec + outer-product, all expressible with
-``jnp.dot`` / ``where`` / ``fori_loop`` (no scatter, no linalg primitives).
+``where`` / lane and sublane reductions / ``fori_loop`` (no scatter, no
+dynamic lane slice, no linalg primitives).
 
 Rank deficiency: a column whose residual norm falls below a relative drop
 tolerance (1e-8 f64 / 1e-4 f32, the same cut ``core/ara.py`` uses) carries
@@ -29,28 +30,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .lr_sample import HIGHEST
 
-def _mgs_body(b: int, r: int, tol, Q):
-    """One MGS sweep over the r columns of Q (b, r); returns orthonormal Q."""
+
+def _mgs_body(r: int, tol, Q):
+    """One MGS sweep over the r columns of Q (b, r); returns orthonormal Q.
+
+    Column ``k`` is read and written through an iota mask on the lane axis
+    (a masked lane reduction and a select) -- Mosaic has no dynamic lane
+    slice -- and the projection is a sublane reduction plus a broadcast
+    outer product, so every step is plain VPU work."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
 
     def body(k, Q):
-        qk = jax.lax.dynamic_slice(Q, (0, k), (b, 1))            # (b, 1)
-        nrm = jnp.sqrt(jnp.sum(qk * qk))
+        sel = col == k
+        qk = jnp.sum(jnp.where(sel, Q, 0.0), axis=1, keepdims=True)  # (b, 1)
+        nrm = jnp.sqrt(jnp.sum(qk * qk, axis=0, keepdims=True))      # (1, 1)
         keep = nrm > tol
-        qk = jnp.where(keep, qk / jnp.maximum(nrm, tol), jnp.zeros_like(qk))
+        qk = jnp.where(keep, qk / jnp.maximum(nrm, tol), 0.0)
         # project the finalized direction out of every *later* column
-        proj = jnp.dot(qk.T, Q, preferred_element_type=Q.dtype)  # (1, r)
-        later = jax.lax.broadcasted_iota(jnp.int32, (1, r), 1) > k
-        proj = jnp.where(later, proj, jnp.zeros_like(proj))
-        Q = Q - jnp.dot(qk, proj, preferred_element_type=Q.dtype)
-        return jax.lax.dynamic_update_slice(Q, qk, (0, k))
+        proj = jnp.sum(qk * Q, axis=0, keepdims=True)                # (1, r)
+        proj = jnp.where(col > k, proj, 0.0)
+        Q = Q - qk * proj
+        return jnp.where(sel, qk, Q)
 
     return jax.lax.fori_loop(0, r, body, Q)
 
 
 def _mgs_qr_kernel(y_ref, q_ref, r_ref, *, sweeps: int):
     Y = y_ref[0]                                                 # (b, r)
-    b, r = Y.shape
+    r = Y.shape[1]
     rel = 1e-8 if Y.dtype == jnp.float64 else 1e-4
     Q = Y
     for _ in range(sweeps):
@@ -58,10 +67,14 @@ def _mgs_qr_kernel(y_ref, q_ref, r_ref, *, sweeps: int):
         # surviving columns are unit vectors, so a tolerance derived from the
         # input norms (which can exceed 1/rel) would zero them all in sweep 2.
         col_norm = jnp.sqrt(jnp.sum(Q * Q, axis=0, keepdims=True))  # (1, r)
-        tol = jnp.maximum(rel * jnp.max(col_norm), jnp.finfo(Y.dtype).tiny)
-        Q = _mgs_body(b, r, tol, Q)
+        tol = jnp.maximum(rel * jnp.max(col_norm, axis=1, keepdims=True),
+                          jnp.finfo(Y.dtype).tiny)                  # (1, 1)
+        Q = _mgs_body(r, tol, Q)
     q_ref[0] = Q
-    r_ref[0] = jnp.dot(Q.T, Y, preferred_element_type=Q.dtype)
+    # R = Q^T Y, contracting the sublane (row) axis of both operands
+    r_ref[0] = jax.lax.dot_general(
+        Q, Y, (((0,), (0,)), ((), ())), precision=HIGHEST,
+        preferred_element_type=Q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("sweeps", "interpret"))

@@ -32,6 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 operands get f32-accurate MXU products (bf16 operands are unaffected)
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _lr_sample_kernel(ui_ref, vi_ref, w2_ref, y_ref, acc_ref):
     """Grid cell (t, j): acc += U[t,j] @ (V[t,j]^T @ W2[j])."""
@@ -43,9 +46,10 @@ def _lr_sample_kernel(ui_ref, vi_ref, w2_ref, y_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # (r, s) intermediate never leaves VMEM; both matmuls hit the MXU.
-    t3 = jnp.dot(vi_ref[0, 0].T, w2_ref[0],
-                 preferred_element_type=acc_ref.dtype)
-    acc_ref[...] += jnp.dot(ui_ref[0, 0], t3,
+    t3 = jax.lax.dot_general(vi_ref[0, 0], w2_ref[0],
+                             (((0,), (0,)), ((), ())), precision=HIGHEST,
+                             preferred_element_type=acc_ref.dtype)
+    acc_ref[...] += jnp.dot(ui_ref[0, 0], t3, precision=HIGHEST,
                             preferred_element_type=acc_ref.dtype)
 
     @pl.when(j == nj - 1)

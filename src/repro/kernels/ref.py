@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from ..precision import einsum
+
 
 def lr_sample_ref(Ui, Vi, W2):
     """Y[t] = sum_j U[t,j] @ (V[t,j]^T @ W2[j])."""
@@ -11,20 +13,20 @@ def lr_sample_ref(Ui, Vi, W2):
     s = W2.shape[-1]
     if k == 0:
         return jnp.zeros((T, b, s), Ui.dtype)
-    T3 = jnp.einsum("tjbr,jbs->tjrs", Vi, W2)
-    return jnp.einsum("tjbr,tjrs->tbs", Ui, T3)
+    T3 = einsum("tjbr,jbs->tjrs", Vi, W2)
+    return einsum("tjbr,tjrs->tbs", Ui, T3)
 
 
 def batched_gemm_ref(A, B, ranks):
     """C[t] = A[t][:, :ranks[t]] @ B[t][:ranks[t], :] via masking."""
     k = A.shape[-1]
     mask = (jnp.arange(k)[None, :] < ranks[:, None]).astype(A.dtype)
-    return jnp.einsum("tmk,tk,tkn->tmn", A, mask, B)
+    return einsum("tmk,tk,tkn->tmn", A, mask, B)
 
 
 def tile_chain_ref(U, V, X):
     """out[t] = U[t] @ (V[t]^T @ X[t])."""
-    return jnp.einsum("tbr,trs->tbs", U, jnp.einsum("tbr,tbs->trs", V, X))
+    return einsum("tbr,trs->tbs", U, einsum("tbr,tbs->trs", V, X))
 
 
 def batched_qr_ref(Y):

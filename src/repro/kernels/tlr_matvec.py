@@ -19,16 +19,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .lr_sample import HIGHEST
+
 
 def _tile_chain_kernel(u_ref, v_ref, x_ref, out_ref):
     acc_dtype = (
         jnp.float32 if u_ref.dtype in (jnp.bfloat16, jnp.float16)
         else u_ref.dtype
     )
-    t1 = jnp.dot(v_ref[0].T, x_ref[0], preferred_element_type=acc_dtype)
-    out_ref[0] = jnp.dot(u_ref[0], t1, preferred_element_type=acc_dtype).astype(
-        out_ref.dtype
-    )
+    t1 = jax.lax.dot_general(v_ref[0], x_ref[0], (((0,), (0,)), ((), ())),
+                             precision=HIGHEST,
+                             preferred_element_type=acc_dtype)
+    out_ref[0] = jnp.dot(u_ref[0], t1, precision=HIGHEST,
+                         preferred_element_type=acc_dtype).astype(
+        out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "width"))

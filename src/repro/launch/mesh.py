@@ -10,16 +10,10 @@ the TPU slice topology.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; absent in 0.4.x
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

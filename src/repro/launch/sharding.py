@@ -164,6 +164,58 @@ def tile_batch_sharding(mesh: Mesh, n: int, ndim: int) -> NamedSharding:
     return NamedSharding(mesh, tile_batch_spec(n, ndim, mesh))
 
 
+# The installed tile mesh. It lives here, below both the tile algebra
+# (core/batching.py, which places its batches with it) and the kernel
+# dispatch (kernels/ops.py, which splits Pallas calls over it).
+
+TILE_MESH_MODES = ("pad", "error")
+
+_TILE_MESH = {"mesh": None, "on_indivisible": "pad"}
+
+
+def set_tile_mesh(mesh, *, on_indivisible: str = "pad"):
+    """Install (or clear, with ``None``) the mesh that the tile-algebra
+    batches shard their leading output-tile axis over. Returns the
+    previously installed mesh so callers can restore it.
+
+    ``on_indivisible`` decides what ``core.batching.shard_tile_batch`` does
+    when a batch axis does not divide the mesh's DP axis size -- there is
+    no silent identity fallback:
+
+    * ``"pad"`` (default): zero-pad the leading axis up to the next
+      multiple and shard the padded array. Zero tiles are numerically
+      inert in every accumulation path, and the index-driven gathers /
+      scatters of the tile algebra never reference the trailing pad
+      slots, so results are unchanged. Call sites that must keep the
+      caller-visible shape (``preserve_shape=True``) replicate instead.
+    * ``"error"``: raise ``ValueError`` with the offending sizes, so a
+      topology mismatch fails at the first sharded dispatch instead of
+      silently running replicated.
+    """
+    if on_indivisible not in TILE_MESH_MODES:
+        raise ValueError(f"on_indivisible must be one of {TILE_MESH_MODES}, "
+                         f"got {on_indivisible!r}")
+    prev = _TILE_MESH["mesh"]
+    _TILE_MESH["mesh"] = mesh
+    _TILE_MESH["on_indivisible"] = on_indivisible
+    return prev
+
+
+def tile_mesh():
+    return _TILE_MESH["mesh"]
+
+
+def tile_mesh_mode() -> str:
+    """The installed ``on_indivisible`` mode (see :func:`set_tile_mesh`)."""
+    return _TILE_MESH["on_indivisible"]
+
+
+def tile_dp_size() -> int:
+    """Size of the installed mesh's data-parallel axes (1 when no mesh)."""
+    mesh = _TILE_MESH["mesh"]
+    return 1 if mesh is None else _axis_size(mesh, dp_axes(mesh))
+
+
 # -- inputs ---------------------------------------------------------------------
 
 

@@ -111,17 +111,12 @@ NOOP_SPAN = _NoopSpan()
 
 
 def _device_annotations(name: str):
-    """Best-effort profiler alignment contexts for one span: a
-    ``TraceAnnotation`` (host region in device profiles) and a
-    ``named_scope`` (names any tracing that happens inside the span)."""
+    """Profiler alignment contexts for one span: a ``TraceAnnotation``
+    (host region in device profiles) and a ``named_scope`` (names any
+    tracing that happens inside the span)."""
     import jax
 
-    ctxs = []
-    ta = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
-    if ta is not None:
-        ctxs.append(ta(name))
-    ctxs.append(jax.named_scope(name))
-    return ctxs
+    return [jax.profiler.TraceAnnotation(name), jax.named_scope(name)]
 
 
 class Telemetry:

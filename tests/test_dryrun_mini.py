@@ -76,8 +76,6 @@ lowered = jax.jit(step, in_shardings=in_sh).lower(*args)
 compiled = lowered.compile()
 ma = compiled.memory_analysis()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):  # jax 0.4.x: one dict per computation
-    ca = ca[0] if ca else {}
 coll = parse_collectives(compiled.as_text())
 print(json.dumps({
     "flops": ca.get("flops", 0.0),

@@ -245,3 +245,17 @@ def test_pivoted_cholesky(pivot):
     y = K @ x_true
     x = np.asarray(fact.solve(jnp.asarray(y)))
     assert np.linalg.norm(x - x_true) / np.linalg.norm(x_true) < 1e-2
+
+
+def test_power_norms_zero_tile_f32():
+    """The power-iteration normalizer clamps at the dtype's smallest
+    normal number: a zero f32 tile estimates 0, not NaN (a 1e-300 clamp
+    is 0 in f32)."""
+    from repro.core.cholesky import _power_norms
+
+    tiles = jnp.zeros((2, 16, 16), jnp.float32)
+    tiles = tiles.at[1].set(3.0 * jnp.eye(16, dtype=jnp.float32))
+    norms = _power_norms(tiles, 5, jax.random.PRNGKey(0))
+    assert norms.dtype == jnp.float32
+    assert float(norms[0]) == 0.0
+    np.testing.assert_allclose(float(norms[1]), 3.0, rtol=1e-6)

@@ -225,6 +225,33 @@ def test_resolve_impl_rejects_pallas_off_tpu():
     assert ops.resolve_impl("interpret") == "interpret"
 
 
+def test_batched_gemm_empty_batch():
+    """A zero-tile batch (the pair grid of a one-row bucket in the
+    right-looking trailing update) returns an empty result instead of
+    tracing the kernel over an empty ranks array."""
+    out = ops.batched_gemm(jnp.zeros((0, 8, 4)), jnp.zeros((0, 4, 3)),
+                           jnp.zeros((0,), jnp.int32), impl="interpret")
+    assert out.shape == (0, 8, 3)
+
+
+@pytest.mark.parametrize("on_tpu", [False, True])
+def test_default_impl_per_op(monkeypatch, on_tpu):
+    """The backend default is resolved per op and never "interpret": on a
+    TPU every op takes its kernel except those TPU_XLA_DEFAULT names (each
+    with its reason); elsewhere every op takes the XLA path."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    for op in ("lr_sample", "batched_gemm", "tile_chain", "batched_qr",
+               "small_svd"):
+        want = "pallas" if on_tpu and op not in ops.TPU_XLA_DEFAULT \
+            else "ref"
+        assert ops.resolve_impl(None, op) == want
+    assert ops.resolve_impl(None) == ("pallas" if on_tpu else "ref")
+    assert set(ops.TPU_XLA_DEFAULT) == {"small_svd"}
+    assert all(ops.TPU_XLA_DEFAULT.values())
+    if on_tpu:  # an explicit request still runs the kernel
+        assert ops.resolve_impl("pallas", "small_svd") == "pallas"
+
+
 def test_lr_sample_matches_factorization_sampling():
     """Kernel output == the einsum used inside the factorization samplers."""
     rng = np.random.default_rng(0)

@@ -146,6 +146,103 @@ def test_resolve_policy_record():
         resolve_policy("bogus", plan, b=L.b)
 
 
+@pytest.mark.parametrize("limit,want", [(None, 2), (17 * 2**30, 1),
+                                        (2**50, 2)])
+def test_auto_flush_fits_device_memory(monkeypatch, limit, want):
+    """The auto flush cadence keeps the right driver's two accumulation
+    buffers within a third of the device's memory: a one-chip v5e at
+    N=32768, b=512, r_max=128 (2016 tiles) gets one column between
+    flushes; a device that reports no limit (the CPU) is not capped, and
+    an explicit ``right_flush`` is never overridden."""
+    from repro.core import batching
+
+    class Dev:
+        def memory_stats(self):
+            return None if limit is None else {"bytes_limit": limit}
+
+    monkeypatch.setattr(batching.jax, "devices", lambda: [Dev()])
+    plan = tile_plan(np.full(2016, 100, np.int32), 128)
+    pol = resolve_policy("flat", plan, b=512, dtype=np.float32)
+    assert pol["right_flush"] == want
+    pol = resolve_policy("flat", plan, b=512, dtype=np.float32,
+                         right_flush=3)
+    assert pol["right_flush"] == 3
+
+
+@pytest.mark.parametrize("count", [64, 3])
+def test_bucketed_round_inplace_chunked_matches(monkeypatch, count):
+    """In-place rounding (results written back into the donated stacks at
+    their full width, zero past the new rank) in dispatches of ``count``
+    tiles (one-tile dispatches for remainders of one) gives the
+    out-of-place pass's factors; rank-0 tiles keep their content."""
+    from repro.core import batching
+    from repro.core.batching import bucketed_round_tiles
+
+    monkeypatch.setattr(batching, "ROUND_COUNTS", (1, count))
+    rng = np.random.default_rng(0)
+    n, b, w = 20, 16, 24
+    ranks = rng.integers(0, w + 1, n)
+    ranks[:4] = 0
+    U = rng.standard_normal((n, b, w)).astype(np.float32)
+    V = rng.standard_normal((n, b, w)).astype(np.float32)
+    cols = np.arange(w)[None, None, :]
+    U = np.where(cols < ranks[:, None, None], U, 0)
+    V = np.where(cols < ranks[:, None, None], V, 0)
+    U[0, 0, 0] = 7.0   # rank-0 content an in-place pass must not touch
+    Uo, Vo, ro, eo = bucketed_round_tiles(jnp.asarray(U), jnp.asarray(V),
+                                          ranks, 1e-3, r_out=b)
+    Ui, Vi, ri, ei = bucketed_round_tiles(
+        jnp.asarray(U), jnp.asarray(V), ranks, 1e-3, r_out=b, inplace=True)
+    assert Ui.shape == (n, b, w) and Uo.shape == (n, b, b)
+    np.testing.assert_array_equal(np.asarray(ri), np.asarray(ro))
+    np.testing.assert_allclose(np.asarray(ei), np.asarray(eo), atol=1e-5)
+    live = ranks > 0
+    for got, want in ((Ui, Uo), (Vi, Vo)):
+        got = np.asarray(got)
+        np.testing.assert_allclose(got[live, :, :b], np.asarray(want)[live],
+                                   atol=1e-5)
+        assert not got[live, :, b:].any()
+    assert float(Ui[0, 0, 0]) == 7.0
+    with pytest.raises(ValueError, match="r_out"):
+        bucketed_round_tiles(jnp.asarray(U), jnp.asarray(V), ranks, 1e-3,
+                             r_out=w + 1, inplace=True)
+
+
+@pytest.mark.parametrize("count,want", [
+    (1, [1]), (5, [8]), (8, [8]), (9, [64]), (64, [64]), (70, [64, 8]),
+    (129, [64, 64, 1])])
+def test_round_dispatches(count, want):
+    """Rounding dispatch counts step by 8x: a bucket goes in chunks of 64
+    tiles, each padded to the smallest of 1, 8, 64 that holds it."""
+    from repro.core.batching import round_dispatches
+
+    assert round_dispatches(count) == want
+
+
+def test_round_bucket_flops_count_dispatches():
+    """The round.bucket spans and TilePlan.bucket_flops count the FLOPs of
+    the dispatches the rounding makes (a 3-tile bucket pays an 8-tile
+    core, not the count ladder's 4 or a 64-tile chunk)."""
+    from repro import obs
+    from repro.core.batching import (_round_core_flops, bucketed_round_tiles,
+                                     round_dispatches)
+
+    n, b, w = 3, 8, 4
+    U = jnp.asarray(np.random.default_rng(0).standard_normal((n, b, w)))
+    ranks = np.full(n, w)
+    plan = tile_plan(ranks, w)
+    (bk,) = plan.buckets
+    want = _round_core_flops(8, b, w, w, U.dtype, None)
+    assert round_dispatches(bk.count) == [8]
+    assert plan.bucket_flops(b, w, dtype=U.dtype) == [want]
+    obs.enable()
+    bucketed_round_tiles(U, U, ranks, 1e-10, r_out=w)
+    tel = obs.disable()
+    (span,) = [s for s in tel.spans if s.name == "round.bucket"]
+    assert span.args["flops_padded"] == want
+    assert span.args["padded"] == 8
+
+
 def test_factorization_stats_record_policy():
     _, K = covariance_problem(256, 2, 32)
     K = np.asarray(K) + 1e-2 * np.eye(256)
