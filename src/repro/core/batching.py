@@ -302,7 +302,7 @@ def _ranks_fingerprint(ranks) -> tuple | None:
     return None
 
 
-def tile_plan(ranks, cap: int) -> TilePlan:
+def tile_plan(ranks, cap: int, read=np.asarray) -> TilePlan:
     """The memoized :class:`TilePlan` for this ranks array at this cap.
 
     Keyed on the *identity* of the ranks array (plus a content checksum for
@@ -313,6 +313,7 @@ def tile_plan(ranks, cap: int) -> TilePlan:
     multi-solve) reuse the plan without re-pulling ranks to the host. The
     cache holds strong references to the last ``_PLAN_CACHE_SIZE`` rank
     arrays, so an entry's ``id`` can never be recycled while it is live.
+    A miss reads the ranks to the host through ``read``.
     """
     key = (id(ranks), int(cap))
     hit = _PLAN_CACHE.get(key)
@@ -322,7 +323,7 @@ def tile_plan(ranks, cap: int) -> TilePlan:
             _PLAN_CACHE.move_to_end(key)
             return plan
         del _PLAN_CACHE[key]
-    plan = plan_rank_buckets(ranks, cap)
+    plan = plan_rank_buckets(read(ranks), cap)
     _PLAN_CACHE[key] = (ranks, _ranks_fingerprint(ranks), plan)
     while len(_PLAN_CACHE) > _PLAN_CACHE_SIZE:
         _PLAN_CACHE.popitem(last=False)

@@ -286,6 +286,50 @@ def make_column_samplers(ldl: bool, impl: str | None = None):
     return sample, sample_t
 
 
+# -- host reads ----------------------------------------------------------------
+
+
+def _read(x, convert=None):
+    """A device-to-host read, uncounted (the right driver's reads)."""
+    return (convert or np.asarray)(x)
+
+
+class _HostPulls:
+    """The left driver's device-to-host reads, counted where they happen.
+
+    Every read of a device value by the left driver goes through
+    ``pull(x, convert)``, which returns ``convert(x)`` (``np.asarray`` by
+    default; ``int``, or a function that blocks on a pytree and reads one
+    leaf). It always counts the read, one integer add, and under
+    telemetry wraps it in a ``chol.pull`` span, so a device trace names
+    the host's wait. It adds no read of its own: a host array passes
+    through uncounted. The stages take their share of ``total``: each
+    panel into ``column_events[k]["syncs"]``, the diagonals into
+    ``stats["diag_syncs"]``.
+    """
+
+    __slots__ = ("total",)
+
+    def __init__(self):
+        self.total = 0
+
+    def __call__(self, x, convert=None):
+        convert = convert or np.asarray
+        if isinstance(x, np.ndarray):
+            return convert(x)
+        self.total += 1
+        if not obs.enabled():
+            return convert(x)
+        with obs.span("chol.pull", cat="factor"):
+            return convert(x)
+
+
+def _ready_last(xs):
+    """Block on a pytree of device arrays and read its last one."""
+    jax.block_until_ready(xs)
+    return np.asarray(xs[-1])
+
+
 # -- diagonal machinery --------------------------------------------------------
 
 
@@ -363,19 +407,20 @@ def dense_ldlt_tile(Akk):
     return jax.lax.fori_loop(0, b, body, (L0, d0))
 
 
-def _factor_diag_tile(Akk, opts: CholOptions, stats: dict):
+def _factor_diag_tile(Akk, opts: CholOptions, stats: dict, pull=_read):
     """Dense-factor one (fully updated) diagonal tile per the options.
 
     Shared by both drivers: LDL^T tile factor, or Cholesky with the
     eigenvalue-clamp fallback (``modified_chol`` accounting lands in
-    ``stats``). Returns ``(Lkk, dk)`` with ``dk`` None for Cholesky.
+    ``stats``; its flag is read through ``pull``). Returns ``(Lkk, dk)``
+    with ``dk`` None for Cholesky.
     """
     if opts.ldl:
         return dense_ldlt_tile(Akk)
     delta = opts.eps * jnp.maximum(jnp.max(jnp.abs(jnp.diag(Akk))), 1.0)
     if opts.modified_chol:
         Lkk, bad = robust_cholesky(Akk, delta)
-        stats["modified_chol"] += int(bad)
+        stats["modified_chol"] += pull(bad, int)
     else:
         Lkk = jnp.linalg.cholesky(Akk)
     return Lkk, None
@@ -391,34 +436,36 @@ def _jittered(Akk, shift: float):
     return Akk + shift * scale * jnp.eye(b, dtype=Akk.dtype)
 
 
-def _spd_shift(Akk, rp, attempt: int) -> float:
+def _spd_shift(Akk, rp, attempt: int, pull=_read) -> float:
     """Relative jitter for retry ``attempt``: enough to clear the tile's
     most negative eigenvalue (one b x b eigvalsh, failure path only), plus
     the policy's base shift, escalated by ``growth``. A non-finite tile
     gets the bare policy schedule -- no shift fixes a NaN, and the bounded
     ladder is what turns that into a structured breakdown."""
-    finite = bool(jnp.all(jnp.isfinite(Akk)))
+    finite = pull(jnp.all(jnp.isfinite(Akk)), bool)
     base = 0.0
     if finite:
-        scale = float(jnp.maximum(jnp.max(jnp.abs(jnp.diag(Akk))), 1.0))
-        lam = float(jnp.min(jnp.linalg.eigvalsh(Akk)))
+        scale = pull(jnp.maximum(jnp.max(jnp.abs(jnp.diag(Akk))), 1.0),
+                     float)
+        lam = pull(jnp.min(jnp.linalg.eigvalsh(Akk)), float)
         base = max(0.0, -lam) / scale
     return (base + rp.shift(0)) * rp.growth ** attempt
 
 
-def _diag_check_hook(k, st, opts, stats, health):
+def _diag_check_hook(k, st, opts, stats, health, pull=_read):
     """Check hook for a diag stage with no panel after it (the last
     column in either driver): the panel-boundary hook elsewhere owns the
     jitter ladder, so the trailing diagonal gets its own. Retries
     re-factor the stashed updated tile ``st.col[k]["Akk"]``; exhaustion
-    raises with the column's full remedy history."""
+    raises with the column's full remedy history. Device values are read
+    through ``pull``."""
 
     def check():
         c = st.col[k]
         rp = health.policy
         for attempt in range(rp.max_retries + 1):
             pivots = c["dk"] if opts.ldl else jnp.diag(c["Lkk"])
-            flags = column_flags(pivots)
+            flags = column_flags(pivots, read=pull)
             bad = flags[1] > 0 or (not opts.ldl and flags[2] <= 0.0)
             if not bad:
                 break
@@ -427,11 +474,11 @@ def _diag_check_hook(k, st, opts, stats, health):
                             pivot_index=int(flags[3]),
                             min_pivot=float(flags[2]),
                             nonfinite_pivots=int(flags[1]))
-            shift = _spd_shift(c["Akk"], rp, attempt)
+            shift = _spd_shift(c["Akk"], rp, attempt, pull)
             health.record("spd_breakdown", k, "diag", remedy="jitter",
                           attempt=attempt + 1, shift=shift)
             Lkk, dk_new = _factor_diag_tile(_jittered(c["Akk"], shift),
-                                            opts, stats)
+                                            opts, stats, pull)
             if opts.ldl:
                 st.dvec = st.dvec.at[k].set(dk_new)
             st.LD = st.LD.at[k].set(Lkk)
@@ -441,7 +488,7 @@ def _diag_check_hook(k, st, opts, stats, health):
     return check
 
 
-def _final_gate(st, opts, health, b):
+def _final_gate(st, opts, health, b, pull=_read):
     """The returned-factors guarantee: one fused scan over every factor
     array and every pivot before the driver returns. Nothing that reaches
     the caller is non-finite (or non-positive, for Cholesky) -- a failure
@@ -452,7 +499,7 @@ def _final_gate(st, opts, health, b):
     else:
         pivots = jnp.diagonal(st.LD, axis1=1, axis2=2).reshape(-1)
         arrays = (st.LU, st.LV)
-    flags = column_flags(pivots, arrays)
+    flags = column_flags(pivots, arrays, read=pull)
     if flags[0] > 0 or flags[1] > 0:
         health.fail(-1, "final", "nonfinite_factor",
                     nonfinite=int(flags[0]),
@@ -636,7 +683,8 @@ class _ColumnPipeline:
 
 
 def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
-                      Lkk, dk_new, key, ladder, widths=(None, None)):
+                      Lkk, dk_new, key, ladder, widths=(None, None),
+                      pull=_read):
     T = len(rows)
     Tb, Jb = _column_buckets(A.nb, k, ladder)
     wA, wL = widths
@@ -647,23 +695,29 @@ def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
         # width covering the detected ranks, not at r_max (exact -- columns
         # of Q past each tile's rank are zero).
         Q, ranks, it, err = pipe.fused_sample(data, key)
-        wq = bucket_width(np.asarray(ranks[:T]), pipe.p.r_max)
-        Vnew = pipe.project(data, Q[:, :, :wq], Lkk, dk_new)
-        Vnew = _pad_axis(Vnew, pipe.p.r_max, axis=2)
+        wq = bucket_width(pull(ranks[:T]), pipe.p.r_max)
+        with obs.span("chol.project", cat="factor", k=k):
+            Vnew = pipe.project(data, Q[:, :, :wq], Lkk, dk_new)
+            Vnew = _pad_axis(Vnew, pipe.p.r_max, axis=2)
     else:
         wq = None
         Q, Vnew, ranks, it, err = pipe.fused_col(data, Lkk, dk_new, key)
-    info = {"iters": int(it), "err": np.asarray(err[:T]), "T": T,
+    info = {"iters": pull(it, int), "err": pull(err[:T]), "T": T,
             "Tb": Tb, "Jb": Jb, "safety_valve": False, "wQ": wq}
     return Q[:T], Vnew[:T], ranks[:T], info
 
 
 def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
-                        Lkk, dk_new, key, ladder, widths=(None, None)):
+                        Lkk, dk_new, key, ladder, widths=(None, None),
+                        pull=_read):
     """Algorithm 5: rank-sorted subset with converged-tile eviction/refill.
 
     Returns the panel (Q, Vnew, ranks) zero-padded to the column's row
-    bucket, and ``info`` for its ``T`` real rows."""
+    bucket, and ``info`` for its ``T`` real rows. ``info`` also records
+    the batch's occupancy from the convergence flags the loop reads
+    anyway: ``tile_iters[t]``, the iterations in which row ``t`` held a
+    slot unconverged, and ``slots``, the slot width each step was
+    dispatched at, summed over the column's steps."""
     opts, p = pipe.opts, pipe.p
     wA, wL = widths
     T_col = len(rows)
@@ -675,7 +729,7 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
 
     # Sort rows by the rank of the original A tile, descending (section 4.2):
     # big tiles stay in the batch longest, so they enter first.
-    a_ranks = np.asarray(A.ranks)
+    a_ranks = pull(A.ranks)
     key_rank = np.array(
         [a_ranks[tril_index(max(int(perm[i]), int(perm[k])),
                             min(int(perm[i]), int(perm[k])))]
@@ -698,28 +752,33 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
     Q_all = jnp.zeros((Tb_col, A.b, p.r_max), A.dtype)
     ranks_h = np.zeros(T_col, np.int32)
     err_h = np.zeros(T_col)
+    tile_iters = np.zeros(T_col, np.int64)
     total_iters = 0
     safety_valve = False
     slot_live = [True] * len(slot_rows)
 
     def finish(slots):
         """Record the bases, ranks and errors of ``slots``; one device
-        gather + scatter and one host pull for the lot."""
+        gather + scatter and two host pulls for the lot."""
         nonlocal Q_all
         pos = np.full(Tb, Tb_col, np.int32)      # out of range: dropped
         sl = np.zeros(Tb, np.int32)
         pos[:len(slots)] = [pos_of[slot_rows[s]] for s in slots]
         sl[:len(slots)] = slots
         Q_all = _stash_rows(Q_all, state.Q, pos, sl)
-        rk, er = np.asarray(state.rank), np.asarray(state.err)
+        rk, er = pull(state.rank), pull(state.err)
         for s in slots:
             ranks_h[pos_of[slot_rows[s]]] = rk[s]
             err_h[pos_of[slot_rows[s]]] = er[s]
 
     while any(slot_live):
-        state = pipe.dyn_step(data, state, key)
-        total_iters += 1
-        conv = np.asarray(state.converged)
+        # Every live slot enters the step unconverged (a refill resets it).
+        tile_iters[[pos_of[slot_rows[s]]
+                    for s, live in enumerate(slot_live) if live]] += 1
+        with obs.span("chol.ara_iter", cat="factor", k=k):
+            state = pipe.dyn_step(data, state, key)
+            total_iters += 1
+            conv = pull(state.converged)
         # Evict converged tiles; refill their slots from the queue.
         done = [s for s, live in enumerate(slot_live) if live and conv[s]]
         if done:
@@ -773,16 +832,18 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
     # scatter into L.
     full_data = _build_column_data(A, Lout, rows, k, perm, dvec, opts.ldl,
                                    Tb=Tb_col, Jb=Jb, wA=wA, wL=wL)
-    if opts.batching == "ranked":
-        # Project at the rank-ladder width covering the detected ranks.
-        wq = bucket_width(ranks_h, p.r_max)
-        Vnew = pipe.project(full_data, Q_all[:, :, :wq], Lkk, dk_new)
-        Vnew = _pad_axis(Vnew, p.r_max, axis=2)
-    else:
-        wq = None
-        Vnew = pipe.project(full_data, Q_all, Lkk, dk_new)
+    with obs.span("chol.project", cat="factor", k=k):
+        if opts.batching == "ranked":
+            # Project at the rank-ladder width covering the detected ranks.
+            wq = bucket_width(ranks_h, p.r_max)
+            Vnew = pipe.project(full_data, Q_all[:, :, :wq], Lkk, dk_new)
+            Vnew = _pad_axis(Vnew, p.r_max, axis=2)
+        else:
+            wq = None
+            Vnew = pipe.project(full_data, Q_all, Lkk, dk_new)
     info = {"iters": total_iters, "T": T_col, "Tb": Tb, "Jb": Jb,
-            "err": err_h, "safety_valve": safety_valve, "wQ": wq}
+            "err": err_h, "safety_valve": safety_valve, "wQ": wq,
+            "tile_iters": tile_iters, "slots": Tb * total_iters}
     ranks = jnp.asarray(np.pad(ranks_h, (0, Tb_col - T_col)))
     return Q_all, Vnew, ranks, info
 
@@ -806,10 +867,11 @@ def _dispatch(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     if not obs.enabled():
         return driver(A, opts)
     # Telemetry: one root span per factorization; its subtree becomes the
-    # ``stats["telemetry"]`` metrics snapshot (per-phase FLOP/s,
-    # padded-vs-useful ratios), with the plan-level analytic ratio from
-    # ``stats["policy"]`` copied alongside for parity checks, and the
-    # compile-count registry folded in as a counter sample.
+    # ``stats["telemetry"]`` metrics snapshot (per-phase seconds and FLOPs,
+    # padded-vs-useful ratios, the JIT work of the subtree), with the
+    # plan-level analytic ratio from ``stats["policy"]`` copied alongside
+    # for parity checks, and the compile-count registry folded in as a
+    # counter sample.
     mesh = tile_mesh()
     sched = "lookahead" if (opts.lookahead and opts.algo == "right") \
         else "sequential"
@@ -845,7 +907,9 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     r_out = opts.r_max_out or A.r_max
     p = opts.ara_params(r_out)
     impl = ops.resolve_impl(opts.impl)  # validate the knob up front
-    policy = resolve_policy(opts.batching, tile_plan(A.ranks, A.r_max),
+    pull = _HostPulls()
+    policy = resolve_policy(opts.batching,
+                            tile_plan(A.ranks, A.r_max, read=pull),
                             b=b, dtype=A.dtype,
                             right_flush=opts.right_flush)
     batching = policy["batching"]
@@ -862,7 +926,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     # ranks (monotone up the ladder, so it changes at most ~log2(r_max)
     # times over the whole factorization -- the compile count stays
     # O(log nb + log r_max) instead of multiplying).
-    wA = bucket_width(np.asarray(A.ranks), A.r_max) if batching == "ranked" \
+    wA = bucket_width(pull(A.ranks), A.r_max) if batching == "ranked" \
         else None
     wL = 1 if batching == "ranked" else None
     stats = {
@@ -871,6 +935,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         "bucket_ladder": list(ladder), "column_events": [],
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "batching": batching, "policy": policy,
+        "syncs": 0, "diag_syncs": 0,
     }
     health = HealthMonitor(opts.retry, "left", nb) if opts.check else None
     # Rank-overflow remedies re-run the failing rows' ARA pass at a
@@ -909,6 +974,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         kkey = jax.random.fold_in(key, k)
 
         def fn():
+            n0 = pull.total
             # ---- pivot selection & swap (Algorithm 9 lines 11-14) ----------
             if opts.pivot:
                 diag_orig = jnp.take(A.D, jnp.asarray(st.perm[k:], np.int32),
@@ -920,7 +986,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                     norms = _power_norms(cand, iters=10, key=kkey)
                 else:
                     raise ValueError(opts.pivot)
-                pidx = k + int(jnp.argmax(norms))
+                pidx = k + pull(jnp.argmax(norms), int)
                 stats["pivots"].append(pidx)
                 if pidx != k:
                     st.perm[[k, pidx]] = st.perm[[pidx, k]]
@@ -945,7 +1011,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 if faults.active():
                     Akk = faults.corrupt_diag(Akk, k)
                 mc0 = stats["modified_chol"]
-                Lkk, dk_new = _factor_diag_tile(Akk, opts, stats)
+                Lkk, dk_new = _factor_diag_tile(Akk, opts, stats, pull)
                 if opts.ldl:
                     st.dvec = st.dvec.at[k].set(dk_new)
                 st.LD = st.LD.at[k].set(Lkk)
@@ -957,6 +1023,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                     if stats["modified_chol"] > mc0:
                         health.record("spd_breakdown", k, "diag",
                                       remedy="clamp")
+            stats["diag_syncs"] += pull.total - n0
 
         return fn
 
@@ -980,7 +1047,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         Qd = jnp.where(mask, Qd, 0.0)
         Bd = jnp.where(mask, Bd, 0.0)
         Vd = _trsm(Lkk, dk_new, Bd, opts.ldl)
-        ed = np.asarray(S[:, keep], float) if keep < b \
+        ed = pull(S[:, keep]).astype(float) if keep < b \
             else np.zeros(len(rows_bad))
         return (_pad_axis(Qd, r_out, axis=2), _pad_axis(Vd, r_out, axis=2),
                 rd, ed)
@@ -999,7 +1066,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             # Bucket-pad the scanned panel (padding is zero => finite and
             # inert) so the flags reduction compiles on the ladder.
             flags = column_flags(pivots, (_pad_axis(Q, Tbs),
-                                          _pad_axis(Vnew, Tbs)))
+                                          _pad_axis(Vnew, Tbs)), read=pull)
             bad_piv = flags[1] > 0 or (not opts.ldl and flags[2] <= 0.0)
             if not bad_piv:
                 break
@@ -1008,11 +1075,11 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                             pivot_index=int(flags[3]),
                             min_pivot=float(flags[2]),
                             nonfinite_pivots=int(flags[1]))
-            shift = _spd_shift(c["Akk"], rp, attempt)
+            shift = _spd_shift(c["Akk"], rp, attempt, pull)
             health.record("spd_breakdown", k, "panel", remedy="jitter",
                           attempt=attempt + 1, shift=shift)
             Lkk, dk_new = _factor_diag_tile(_jittered(c["Akk"], shift),
-                                            opts, stats)
+                                            opts, stats, pull)
             if opts.ldl:
                 st.dvec = st.dvec.at[k].set(dk_new)
             st.LD = st.LD.at[k].set(Lkk)
@@ -1037,12 +1104,12 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 _retry_pipe(attempt), A, _Lmat(), rows[pos], k, st.perm,
                 st.dvec, c["Lkk"], c["dk"],
                 jax.random.fold_in(kkey, 7000 + attempt), ladder,
-                widths=(wA, st.wL))
+                widths=(wA, st.wL), pull=pull)
             posj = jnp.asarray(pos)
             Q = Q.at[posj].set(Qb)
             Vnew = Vnew.at[posj].set(Vb)
             ranks = ranks.at[posj].set(rb)
-            ranks_h = np.asarray(ranks)[:len(rows)]
+            ranks_h = pull(ranks)[:len(rows)]
             err_h[pos] = np.asarray(ib["err"], float)
             over[:] = False
             over[pos] = ara_mod.rank_overflow(
@@ -1057,7 +1124,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             Q = Q.at[posj].set(Qd)
             Vnew = Vnew.at[posj].set(Vd)
             ranks = ranks.at[posj].set(rd)
-            ranks_h = np.asarray(ranks)[:len(rows)]
+            ranks_h = pull(ranks)[:len(rows)]
             err_h[pos] = ed
             over[:] = False
             over[pos] = ~(ed <= rp.eps_floor(opts.eps))
@@ -1082,25 +1149,22 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             pipe.begin_column()
             with obs.span("chol.panel", cat="factor", k=k) as _psp:
                 L = _Lmat()
-                if opts.mode == "fused":
-                    Q, Vnew, ranks, info = _column_ara_fused(
-                        pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
-                        kkey, ladder, widths=(wA, st.wL))
-                else:
-                    Q, Vnew, ranks, info = _column_ara_dynamic(
-                        pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
-                        kkey, ladder, widths=(wA, st.wL))
+                column = _column_ara_fused if opts.mode == "fused" \
+                    else _column_ara_dynamic
+                Q, Vnew, ranks, info = column(
+                    pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
+                    kkey, ladder, widths=(wA, st.wL), pull=pull)
                 if faults.active():
                     Q = faults.corrupt_panel(Q[:T], k)
-                jax.block_until_ready((Q, Vnew, ranks))
-                ranks_h = np.asarray(ranks)[:T]   # the panel may be padded
+                # one pull: wait for the panel, read its (padded) ranks
+                ranks_h = pull((Q, Vnew, ranks), _ready_last)[:T]
                 if obs.enabled():
                     _psp.set(T=info["T"], Tb=info["Tb"], Jb=info["Jb"],
                              iters=info["iters"],
                              rank_hist=obs.rank_hist(ranks_h, r_out))
             return Q, Vnew, ranks, ranks_h, info
 
-        def commit(Q, Vnew, ranks, ranks_h, info, t0):
+        def commit(Q, Vnew, ranks, ranks_h, info, t0, n0):
             dt = time.perf_counter() - t0
             if batching == "ranked":
                 st.wL = max(st.wL, bucket_width(ranks_h, r_out))
@@ -1111,35 +1175,39 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 "k": k, "T": info["T"], "Tb": info["Tb"], "Jb": info["Jb"],
                 "seconds": dt, "traced": pipe.column_traced,
                 "err": np.asarray(info["err"]), "wQ": info.get("wQ"),
+                "syncs": pull.total - n0,
+                "tile_iters": info.get("tile_iters"),
+                "slots": info.get("slots"),
             })
 
-            idxp = np.zeros(Tbs, np.int64)
-            idxp[:T] = [tril_index(int(i), k) for i in rows]
-            st.LU, st.LV, st.LR = pipe.scatter(
-                st.LU, st.LV, st.LR, jnp.asarray(idxp, jnp.int32),
-                jnp.asarray(np.arange(Tbs) < T), _pad_axis(Q, Tbs),
-                _pad_axis(Vnew, Tbs), _pad_axis(ranks, Tbs))
-            if opts.pivot:
-                # Dsum_all[i] += L(i,k) L(i,k)^T for the remaining rows.
-                G = einsum("tbr,tbq->trq", Vnew[:T], Vnew[:T])
-                upd = einsum("tbr,trq,tcq->tbc", Q[:T], G, Q[:T])
-                st.Dsum_all = st.Dsum_all.at[k + 1 :].add(upd)
+            with obs.span("chol.commit", cat="factor", k=k):
+                idxp = np.zeros(Tbs, np.int64)
+                idxp[:T] = [tril_index(int(i), k) for i in rows]
+                st.LU, st.LV, st.LR = pipe.scatter(
+                    st.LU, st.LV, st.LR, jnp.asarray(idxp, jnp.int32),
+                    jnp.asarray(np.arange(Tbs) < T), _pad_axis(Q, Tbs),
+                    _pad_axis(Vnew, Tbs), _pad_axis(ranks, Tbs))
+                if opts.pivot:
+                    # Dsum_all[i] += L(i,k) L(i,k)^T for the remaining rows.
+                    G = einsum("tbr,tbq->trq", Vnew[:T], Vnew[:T])
+                    upd = einsum("tbr,trq,tcq->tbc", Q[:T], G, Q[:T])
+                    st.Dsum_all = st.Dsum_all.at[k + 1 :].add(upd)
 
         def fn():
-            t0 = time.perf_counter()
+            t0, n0 = time.perf_counter(), pull.total
             out = compute()
             if health is None:
-                commit(*out, t0)
+                commit(*out, t0, n0)
             else:
                 # Defer the commit to the stage's check hook: the scatter
                 # is a donated *add*, so it must happen exactly once --
                 # after validation has settled the panel's final content.
-                st.col[k]["pending"] = (out, t0)
+                st.col[k]["pending"] = (out, t0, n0)
 
         def check():
-            out, t0 = st.col[k].pop("pending")
+            out, t0, n0 = st.col[k].pop("pending")
             out = _repair_column(k, rows, compute, kkey, *out)
-            commit(*out, t0)
+            commit(*out, t0, n0)
             health.columns_checked += 1
 
         return fn, (check if health is not None else None)
@@ -1149,7 +1217,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         # The last column has no panel stage, so its pivots get their own
         # boundary check; every other diag is validated by the following
         # panel's hook (which owns the jitter + recompute ladder).
-        dcheck = _diag_check_hook(k, st, opts, stats, health) \
+        dcheck = _diag_check_hook(k, st, opts, stats, health, pull) \
             if health is not None and k + 1 >= nb else None
         stages.append(Stage(
             name=f"diag:{k}", kind="diag", k=k, fn=_diag_stage(k),
@@ -1170,8 +1238,9 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     stats["diag_traces"] = pipe.traces["diag"]
     stats["scatter_traces"] = pipe.scatter_traces
     if health is not None:
-        _final_gate(st, opts, health, b)
+        _final_gate(st, opts, health, b, pull)
         stats["health"] = health.summary()
+    stats["syncs"] = pull.total
     return TLRFactorization(L=_Lmat(), d=st.dvec, perm=st.perm, stats=stats)
 
 
@@ -1317,6 +1386,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "flushes": 0, "acc_width": w_acc,
         "batching": batching, "policy": policy, "append_widths": [],
+        "syncs": None, "diag_syncs": None,   # host reads: left driver only
     }
     eps = jnp.asarray(opts.eps, dtype)
     health = HealthMonitor(opts.retry, "right", nb) if opts.check else None

@@ -160,8 +160,10 @@ _flags_jit = jax.jit(_flags_body, static_argnames=())
 
 
 def column_flags(pivots, arrays=(), *, ranks=None, err=None,
-                 r_cap: int = 0, eps: float = 0.0) -> np.ndarray:
-    """One fused health reduction, pulled as a single (5,) host transfer.
+                 r_cap: int = 0, eps: float = 0.0,
+                 read=np.asarray) -> np.ndarray:
+    """One fused health reduction, pulled as a single (5,) host transfer
+    (through ``read``, which a driver that counts its reads passes).
 
     ``pivots`` is the diagonal of the column's dense factor (Cholesky) or
     its LDL d-vector; ``arrays`` is a pytree of panel outputs to scan for
@@ -177,7 +179,7 @@ def column_flags(pivots, arrays=(), *, ranks=None, err=None,
         flags = _flags_jit(pivots, tuple(jax.tree.leaves(arrays)),
                            ranks, err, jnp.asarray(r_cap),
                            jnp.asarray(eps, pivots.dtype))
-    return np.asarray(flags)
+    return read(flags)
 
 
 # -- the monitor ---------------------------------------------------------------

@@ -9,6 +9,10 @@ Kernels (each: <name>.py kernel + ref.py oracle + ops.py dispatch):
   batched_qr   MGS economy QR of stacked low-rank factors (the rounding
                pass of the tile algebra, core/algebra.py)
   small_svd    one-sided-Jacobi SVD of the r x r rounding cores
+
+Each ``pallas_call`` carries ``name=`` equal to its jitted wrapper's name
+(``lr_sample_pallas``, ...): the custom call, and so the op in a device
+trace, keeps that name whatever function holds the kernel.
 """
 
 from .ops import (  # noqa: F401

@@ -69,4 +69,5 @@ def batched_gemm_pallas(A, B, ranks, *, bm: int = 0, bn: int = 0,
         ),
         out_shape=jax.ShapeDtypeStruct((T, m, n), A.dtype),
         interpret=interpret,
+        name="batched_gemm_pallas",
     )(ranks.astype(jnp.int32), A, B)

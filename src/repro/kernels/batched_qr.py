@@ -98,4 +98,5 @@ def batched_qr_pallas(Y, *, sweeps: int = 2, interpret: bool = True):
             jax.ShapeDtypeStruct((T, r, r), Y.dtype),
         ],
         interpret=interpret,
+        name="batched_qr_pallas",
     )(Y)

@@ -94,4 +94,5 @@ def lr_sample_pallas(Ui, Vi, W2, *, interpret: bool = True,
         out_shape=jax.ShapeDtypeStruct((T, b, s), Ui.dtype),
         scratch_shapes=[pltpu.VMEM((b, s), acc_dtype)],
         interpret=interpret,
+        name="lr_sample_pallas",
     )(Ui, Vi, W2)

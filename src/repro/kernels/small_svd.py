@@ -143,5 +143,6 @@ def small_svd_pallas(M, *, sweeps: int = 8, interpret: bool = True):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=min(max(vmem, 32 << 20), 100 << 20)),
         interpret=interpret,
+        name="small_svd_pallas",
     )(M)
     return U[:, :m, :n], s[:, 0, :n], V[:, :n, :n]

@@ -62,4 +62,5 @@ def tile_chain_pallas(U, V, X, *, interpret: bool = True,
         out_specs=pl.BlockSpec((1, b, s), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, b, s), U.dtype),
         interpret=interpret,
+        name="tile_chain_pallas",
     )(U, V, X)

@@ -2,9 +2,9 @@
 
 The observability layer of DESIGN.md section 11. One process-wide
 recording context collects nested spans (wall time + FLOP attribution +
-rank histograms) at every layer's natural boundaries and exports them
-as Perfetto-loadable Chrome-trace JSON, a flat metrics snapshot, or
-counter timelines of the compile-count registry.
+rank histograms + JIT work) at every layer's natural boundaries and
+exports them as Perfetto-loadable Chrome-trace JSON, a flat metrics
+snapshot, or counter timelines of the compile-count registry.
 
 Typical use::
 
@@ -13,7 +13,7 @@ Typical use::
     obs.enable()
     fact = op.cholesky(eps=1e-6)          # spans recorded as a side effect
     obs.export_chrome_trace("trace.json")  # -> load in ui.perfetto.dev
-    print(fact.stats["telemetry"])         # per-phase FLOP/s snapshot
+    print(fact.stats["telemetry"])         # per-phase seconds, FLOPs, JIT
     obs.disable()
 
 Everything is a no-op while disabled: ``obs.span(...)`` returns a shared

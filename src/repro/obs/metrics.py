@@ -1,22 +1,26 @@
 """Flat metrics snapshot of a telemetry recording.
 
-``metrics_snapshot()`` collapses the span tree into the per-phase
-numbers the paper's profiling tables report (arXiv:2108.11932 fig. 10:
-wall time and achieved FLOP/s attributed to batched-GEMM vs.
-compression phases): for every span *name*, the call count, total
-seconds, useful and padded FLOPs, achieved FLOP/s, and padded-vs-useful
-ratio. The snapshot is plain JSON-able data; the drivers merge it into
+``metrics_snapshot()`` collapses the span tree into per-phase numbers:
+for every span *name*, the call count, total host seconds, useful and
+padded FLOPs, and the padded-vs-useful ratio (the paper's profiling
+split of arXiv:2108.11932 fig. 10 between batched-GEMM and compression
+phases, as counts), plus the JIT work the spans held. The snapshot is
+plain JSON-able data; the drivers merge it into
 ``fact.stats["telemetry"]``, the server into ``ServerStats``-backed
-summaries, and every bench into its ``BENCH_<suite>.json`` -- which is
-what ``benchmarks/compare.py`` diffs for regressions.
+summaries, and every bench into its ``BENCH_<suite>.json``.
+
+It carries no FLOP rate: span durations are host time without a device
+sync, so FLOPs over them measure the host, not the chip. Device rates
+come from a device trace.
 
 FLOP attribution convention (matching ``TilePlan.bucket_flops``):
 instrumentation sites attach ``flops`` (useful work, true ranks) and
 ``flops_padded`` (dispatched work, bucket-padded shapes) to *leaf*
 spans only. Aggregation here sums attrs per span name without walking
-the tree, so interior spans must not repeat their children's FLOPs --
-their own row then reports seconds but no FLOP/s, and the top-level
-totals stay double-count free.
+the tree, so interior spans must not repeat their children's FLOPs and
+the top-level totals stay double-count free. JIT work is counted on the
+innermost open span only, so its sum over a selection is double-count
+free too.
 """
 
 from __future__ import annotations
@@ -36,13 +40,15 @@ def metrics_snapshot(tel: Optional["_tel.Telemetry"] = None,
 
     ``phases``
         per span-name rows ``{count, seconds, flops, flops_padded,
-        flops_per_s, padded_flop_ratio}`` (the FLOP-derived fields only
-        where FLOPs were attached);
-    ``wall_s`` / ``flops`` / ``flops_padded`` / ``padded_flop_ratio`` /
-    ``flops_per_s``
+        padded_flop_ratio}`` (the ratio only where FLOPs were attached);
+    ``wall_s`` / ``flops`` / ``flops_padded`` / ``padded_flop_ratio``
         totals -- ``wall_s`` is the summed duration of *top-level* spans
         in the selection (nested spans overlap their parents and must
         not be double counted);
+    ``jit``
+        the JIT work of the selection, ``{trace_s, lower_s, compile_s,
+        traces, programs}``: jaxpr tracing, lowering to MLIR, backend
+        compiles or persistent-cache loads (``telemetry.JIT_EVENTS``);
     ``retraces``
         the compile-count registry snapshot at call time;
     ``spans``
@@ -65,8 +71,12 @@ def metrics_snapshot(tel: Optional["_tel.Telemetry"] = None,
     ids = {sp.id for sp in spans}
 
     phases: dict[str, dict] = {}
+    jit = dict.fromkeys(_tel.JIT_KEYS, 0)
     wall = 0.0
     for sp in spans:
+        if sp.jit:
+            for key, v in sp.jit.items():
+                jit[key] += v
         row = phases.setdefault(sp.name, _phase_row())
         row["count"] += 1
         row["seconds"] += sp.dur
@@ -83,8 +93,6 @@ def metrics_snapshot(tel: Optional["_tel.Telemetry"] = None,
         if row["flops"] > 0.0:
             tot_fl += row["flops"]
             tot_pad += row["flops_padded"]
-            if row["seconds"] > 0.0:
-                row["flops_per_s"] = row["flops"] / row["seconds"]
             row["padded_flop_ratio"] = row["flops_padded"] / row["flops"]
 
     from ..core.buckets import trace_counts
@@ -95,12 +103,11 @@ def metrics_snapshot(tel: Optional["_tel.Telemetry"] = None,
         "flops": tot_fl,
         "flops_padded": tot_pad,
         "phases": phases,
+        "jit": jit,
         "retraces": trace_counts(),
     }
     if tot_fl > 0.0:
         out["padded_flop_ratio"] = tot_pad / tot_fl
-        if wall > 0.0:
-            out["flops_per_s"] = tot_fl / wall
     # Last sample per counter series (counters are cumulative: the drivers
     # emit running totals, e.g. the "health" jitter/retry counts, so the
     # final sample IS the aggregate). Counters are recording-global --

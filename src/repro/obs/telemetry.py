@@ -8,7 +8,7 @@ per-tick stages of the ``TLRServer`` loop. Spans carry free-form numeric
 attributes; the instrumentation sites attach ``flops`` (useful) /
 ``flops_padded`` (dispatched, padding included) estimates, bucket widths,
 and rank-histogram snapshots, which ``obs.metrics_snapshot`` aggregates
-into per-phase FLOP/s and padded-vs-useful ratios and
+into per-phase FLOP counts and padded-vs-useful ratios and
 ``obs.export_chrome_trace`` turns into a Perfetto-loadable trace.
 
 Design constraints, in order:
@@ -30,6 +30,12 @@ Design constraints, in order:
   (``named_scope`` only renames HLO metadata while tracing; the jit cache
   key is unchanged), so enabling telemetry never changes the compiled
   executable set.
+
+The JIT work a span holds is counted too: one ``jax.monitoring`` duration
+listener, registered the first time :func:`enable` runs, adds each
+tracing, lowering and backend compile (a persistent-cache load included)
+to the innermost open span of the calling thread (``Span.jit``). While
+telemetry is off it returns after one global check.
 """
 
 from __future__ import annotations
@@ -60,13 +66,14 @@ class Span:
     parent: int
     depth: int
     args: Dict[str, Any]
+    jit: Optional[Dict[str, float]] = None
 
 
 class _SpanHandle:
     """Open-span context manager returned by :meth:`Telemetry.start_span`."""
 
     __slots__ = ("_tel", "id", "name", "cat", "parent", "depth", "t0",
-                 "args", "_ctxs")
+                 "args", "jit", "_ctxs")
 
     def __init__(self, tel: "Telemetry", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -74,6 +81,7 @@ class _SpanHandle:
         self.name = name
         self.cat = cat
         self.args = args
+        self.jit = None
         self._ctxs = ()
 
     def set(self, **attrs) -> "_SpanHandle":
@@ -174,7 +182,7 @@ class Telemetry:
             st.pop()
         sp = Span(id=h.id, name=h.name, cat=h.cat, ts=h.t0 - self.epoch,
                   dur=t1 - h.t0, parent=h.parent, depth=h.depth,
-                  args=h.args)
+                  args=h.args, jit=h.jit)
         with self._lock:
             self.spans.append(sp)
 
@@ -225,6 +233,35 @@ class Telemetry:
 
 _STATE: Optional[Telemetry] = None
 
+# The ``jax.monitoring`` duration events of JIT work (all fire in the thread
+# that called the jitted function): event -> (seconds key, count key).
+# ``backend_compile_duration`` fires for a persistent-cache load too.
+JIT_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("trace_s", "traces"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower_s", None),
+    "/jax/core/compile/backend_compile_duration": ("compile_s", "programs"),
+}
+JIT_KEYS = ("trace_s", "lower_s", "compile_s", "traces", "programs")
+_jit_listening = False
+
+
+def _on_jit_duration(event: str, secs: float, **_) -> None:
+    """Add one JIT event to the innermost open span of this thread."""
+    tel = _STATE
+    if tel is None:
+        return
+    keys = JIT_EVENTS.get(event)
+    stack = getattr(tel._local, "stack", None)
+    if keys is None or not stack:
+        return
+    h = stack[-1]
+    if h.jit is None:
+        h.jit = dict.fromkeys(JIT_KEYS, 0)
+    seconds, count = keys
+    h.jit[seconds] += secs
+    if count is not None:
+        h.jit[count] += 1
+
 
 def enabled() -> bool:
     """Is telemetry recording? The one check every instrumentation site
@@ -239,8 +276,16 @@ def current() -> Optional[Telemetry]:
 
 def enable(*, device_annotations: bool = True) -> Telemetry:
     """Start (or restart) recording; returns the fresh context. Any
-    previous context is dropped -- export it first if you need it."""
-    global _STATE
+    previous context is dropped -- export it first if you need it. The
+    first call registers the JIT listener (``jax.monitoring`` keeps it for
+    the life of the process)."""
+    global _STATE, _jit_listening
+    if not _jit_listening:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jit_duration)
+        _jit_listening = True
     _STATE = Telemetry(device_annotations=device_annotations)
     return _STATE
 

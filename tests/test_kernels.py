@@ -268,3 +268,37 @@ def test_lr_sample_matches_factorization_sampling():
     want = jnp.einsum("tjbr,tjrs->tbs", Ui, T3)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-10,
                                atol=1e-10)
+
+
+def _f32(*shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+KERNEL_LOWERINGS = {
+    "lr_sample_pallas": (lambda u, v, w: lr_sample_pallas(
+        u, v, w, interpret=False),
+        (_f32(4, 2, 128, 16), _f32(4, 2, 128, 16), _f32(2, 128, 16))),
+    "tile_chain_pallas": (lambda u, v, x: tile_chain_pallas(
+        u, v, x, interpret=False),
+        (_f32(4, 128, 16), _f32(4, 128, 16), _f32(4, 128, 8))),
+    "batched_gemm_pallas": (lambda a, b, k: batched_gemm_pallas(
+        a, b, k, interpret=False),
+        (_f32(4, 128, 16), _f32(4, 16, 16), _f32(4, dtype=jnp.int32))),
+    "batched_qr_pallas": (lambda y: batched_qr_pallas(y, interpret=False),
+                          (_f32(4, 128, 16),)),
+    "small_svd_pallas": (lambda m: small_svd_pallas(m, interpret=False),
+                         (_f32(4, 16, 16),)),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_LOWERINGS))
+def test_kernel_carries_its_name(name):
+    """Each Mosaic custom call is named after its jitted wrapper, so the
+    op in a device trace keeps that name whatever function holds the
+    kernel (lowered for the TPU on the CPU; nothing is compiled)."""
+    fn, shapes = KERNEL_LOWERINGS[name]
+    with jax.enable_x64(False):
+        text = jax.jit(fn).trace(*shapes).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    assert f'kernel_name = "{name}"' in text

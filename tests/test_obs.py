@@ -169,6 +169,69 @@ def test_span_tree_nesting_in_trace():
         assert s.depth == r.depth + 1
 
 
+# -- JIT work ------------------------------------------------------------------
+
+
+def _fresh_jit():
+    """A jitted function no earlier call has compiled."""
+    import jax
+
+    return jax.jit(lambda x: x * 3.0 + 1.0)
+
+
+def test_jit_listener_counts_a_fresh_program():
+    f = _fresh_jit()
+    x = jnp.ones((7, 5))
+    tel = obs.enable()
+    with obs.span("outer", cat="factor"):
+        with obs.span("first", cat="factor"):
+            f(x)
+        with obs.span("second", cat="factor"):
+            f(x)                       # cached: nothing to trace or lower
+    obs.disable()
+    by_name = {s.name: s for s in tel.spans}
+    jit = by_name["first"].jit
+    assert jit["traces"] >= 1 and jit["programs"] == 1
+    assert jit["lower_s"] > 0 and jit["trace_s"] > 0
+    assert jit["compile_s"] > 0
+    # only the innermost open span holds it
+    assert by_name["second"].jit is None and by_name["outer"].jit is None
+    snap = obs.metrics_snapshot(tel)
+    assert snap["jit"] == pytest.approx(jit)
+
+
+def test_jit_listener_silent_while_disabled():
+    """With telemetry off the listener records nothing, even into a span
+    left open by the recording that was switched off."""
+    f = _fresh_jit()
+    tel = obs.enable()
+    h = obs.span("left_open", cat="factor")
+    h.__enter__()
+    obs.disable()
+    f(jnp.ones((3, 11)))
+    h.__exit__(None, None, None)
+    assert [s.jit for s in tel.spans] == [None]
+    assert obs.metrics_snapshot(tel)["jit"] == dict.fromkeys(
+        obs.telemetry.JIT_KEYS, 0)
+
+
+def test_factorization_snapshot_carries_its_jit_work():
+    """A factorization's snapshot counts the programs its own subtree
+    compiled: the left driver jits its column steps per factorization."""
+    op = _problem(n=128, b=32, seed=5)
+    obs.enable()
+    fact = op.cholesky(CholOptions(eps=1e-6))
+    obs.disable()
+    jit = fact.stats["telemetry"]["jit"]
+    assert jit["programs"] >= fact.stats["column_traces"] >= 1
+    assert jit["traces"] >= jit["programs"]
+    assert jit["lower_s"] > 0 and jit["compile_s"] > 0
+    # no FLOP rate from host span time
+    assert "flops_per_s" not in fact.stats["telemetry"]
+    assert all("flops_per_s" not in row
+               for row in fact.stats["telemetry"]["phases"].values())
+
+
 # -- metrics parity with existing stats ----------------------------------------
 
 
