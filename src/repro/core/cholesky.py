@@ -58,8 +58,8 @@ from __future__ import annotations
 import dataclasses
 import time
 import warnings
-from functools import partial
-from typing import Optional
+from functools import lru_cache, partial
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,7 +81,7 @@ from .stages import (LookaheadSchedule, SequentialSchedule, Stage, run_graph)
 from .tlr import (TLRMatrix, num_tiles, tril_index, tril_pairs,
                   zeros_like_structure)
 from ..kernels import ops
-from .. import faults, obs
+from .. import faults, obs, precision
 from ..precision import einsum, matmul
 
 
@@ -598,77 +598,119 @@ def scatter_trace_count() -> int:
     return _SCATTER_TRACES
 
 
+# Process-wide trace counts of the left driver's column steps, one per role;
+# each bumps when jax traces a fresh variant of a step (once per compiled
+# executable, since the jitted bodies below run only while tracing).
+_STEP_TRACES = {"column": 0, "project": 0, "diag": 0}
+
+# Bound on the distinct static configurations whose compiled column steps
+# stay cached: a factorization uses one, plus one per rank-overflow retry
+# level it escalates to.
+_COLUMN_STEP_CONFIGS = 32
+
+
+class _ColumnSteps(NamedTuple):
+    sample: Callable
+    fused_col: Callable
+    fused_sample: Callable
+    dyn_step: Callable
+    project: Callable
+    diag_update: Callable
+
+
+@lru_cache(maxsize=_COLUMN_STEP_CONFIGS)
+def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
+                  mesh, matmul_precision) -> _ColumnSteps:
+    """The left driver's jitted column steps for one static configuration.
+
+    Built once per ``(ldl, impl, share_omega, ARAParams, tile mesh, matmul
+    precision)`` and shared by every factorization in the process, like
+    ``_panel_scatter``: per-factorization jits would trace, lower and load
+    every step variant again on every call. The key is everything the
+    traced bodies read besides their arguments (``impl`` as
+    ``ops.resolve_impl`` gives it; the tile mesh decides at trace time
+    whether a kernel runs under ``shard_map``; ``precision.MATMUL_PRECISION``
+    is read when a contraction is traced). The bodies capture nothing else:
+    arrays, keys and per-factorization state arrive as arguments.
+    """
+    sample, sample_t = make_column_samplers(ldl, impl)
+
+    def fused_col(data, Lkk, dk_new, key):
+        _STEP_TRACES["column"] += 1
+        Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
+        Q, B, ranks, state = run_ara_fused(
+            sample, sample_t, data, key, T=Tb, b=b, m=b,
+            p=p, dtype=data["Ua"].dtype, share_omega=share,
+            valid=data["valid"],
+        )
+        return Q, _trsm(Lkk, dk_new, B, ldl), ranks, state.it, state.err
+
+    def fused_sample(data, key):
+        # Ranked batching: sampling only -- the projection runs after
+        # the detected ranks reach the host, against Q sliced to the
+        # rank-ladder width that covers them (see run_ara_fused).
+        _STEP_TRACES["column"] += 1
+        Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
+        Q, _, ranks, state = run_ara_fused(
+            sample, sample_t, data, key, T=Tb, b=b, m=b,
+            p=p, dtype=data["Ua"].dtype, share_omega=share,
+            valid=data["valid"], project=False,
+        )
+        return Q, ranks, state.it, state.err
+
+    def dyn_step(data, state, key):
+        _STEP_TRACES["column"] += 1
+        Tb, b = state.Q.shape[0], state.Q.shape[1]
+        return ara_iteration(sample, data, state, key, p,
+                             share_omega=share, T=Tb, b=b)
+
+    def project(data, Q, Lkk, dk_new):
+        _STEP_TRACES["project"] += 1
+        return _trsm(Lkk, dk_new, sample_t(data, Q), ldl)
+
+    def diag_update(Uk, Vk, dk):
+        _STEP_TRACES["diag"] += 1
+        return _diag_update_sum(Uk, Vk, dk)
+
+    return _ColumnSteps(sample, jax.jit(fused_col),
+                        jax.jit(fused_sample), jax.jit(dyn_step),
+                        jax.jit(project), jax.jit(diag_update))
+
+
 class _ColumnPipeline:
-    """Per-factorization cache of the shape-stable jitted column steps.
+    """Per-factorization handle on the shape-stable jitted column steps.
 
     One jitted callable per role (fused column, dynamic ARA step, projection,
-    diagonal update); jax's shape-keyed jit cache plus the bucket ladder keeps
-    the number of compiled variants at ~log2(nb). The python body of each
-    callable runs exactly once per compiled variant, so the ``traces``
-    counters report real compile counts (surfaced in ``stats``).
+    diagonal update), taken from the process-wide ``_column_steps`` cache;
+    jax's shape-keyed jit cache plus the bucket ladder keeps the number of
+    compiled variants at ~log2(nb), and a later factorization with the same
+    statics traces and lowers none of them again. The handle keeps the
+    options, the ARA parameters and the trace-count baselines, so
+    ``traces`` and ``scatter_traces`` report the fresh traces made since
+    it was built (a rank-overflow retry's included): ~log2(nb) in the
+    first factorization of a configuration, 0 in a warm one.
     """
 
     def __init__(self, opts: CholOptions, p: ARAParams):
         self.opts = opts
         self.p = p
-        self.sample, self.sample_t = make_column_samplers(opts.ldl, opts.impl)
-        self.traces = {"column": 0, "project": 0, "diag": 0}
-        self._column_traced = False
-        self._scatter_t0 = _SCATTER_TRACES
-        ldl = opts.ldl
-        share = opts.share_omega
-
-        def fused_col(data, Lkk, dk_new, key):
-            self._mark("column")
-            Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
-            Q, B, ranks, state = run_ara_fused(
-                self.sample, self.sample_t, data, key, T=Tb, b=b, m=b,
-                p=p, dtype=data["Ua"].dtype, share_omega=share,
-                valid=data["valid"],
-            )
-            return Q, _trsm(Lkk, dk_new, B, ldl), ranks, state.it, state.err
-
-        def fused_sample(data, key):
-            # Ranked batching: sampling only -- the projection runs after
-            # the detected ranks reach the host, against Q sliced to the
-            # rank-ladder width that covers them (see run_ara_fused).
-            self._mark("column")
-            Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
-            Q, _, ranks, state = run_ara_fused(
-                self.sample, self.sample_t, data, key, T=Tb, b=b, m=b,
-                p=p, dtype=data["Ua"].dtype, share_omega=share,
-                valid=data["valid"], project=False,
-            )
-            return Q, ranks, state.it, state.err
-
-        def dyn_step(data, state, key):
-            self._mark("column")
-            Tb, b = state.Q.shape[0], state.Q.shape[1]
-            return ara_iteration(self.sample, data, state, key, p,
-                                 share_omega=share, T=Tb, b=b)
-
-        def project(data, Q, Lkk, dk_new):
-            self._mark("project")
-            return _trsm(Lkk, dk_new, self.sample_t(data, Q), ldl)
-
-        def diag_update(Uk, Vk, dk):
-            self._mark("diag")
-            return _diag_update_sum(Uk, Vk, dk)
-
-        self.fused_col = jax.jit(fused_col)
-        self.fused_sample = jax.jit(fused_sample)
-        self.dyn_step = jax.jit(dyn_step)
-        self.project = jax.jit(project)
-        self.diag_update = jax.jit(diag_update)
+        (self.sample, self.fused_col, self.fused_sample, self.dyn_step,
+         self.project, self.diag_update) = _column_steps(
+            opts.ldl, ops.resolve_impl(opts.impl), opts.share_omega, p,
+            tile_mesh(), precision.MATMUL_PRECISION)
         self.scatter = _panel_scatter
+        self._traces_t0 = dict(_STEP_TRACES)
+        self._column_t0 = _STEP_TRACES["column"]
+        self._scatter_t0 = _SCATTER_TRACES
 
-    def _mark(self, kind: str) -> None:
-        self.traces[kind] += 1
-        if kind == "column":
-            self._column_traced = True
+    @property
+    def traces(self) -> dict[str, int]:
+        """Fresh traces of each step role since this pipeline was built."""
+        return {kind: n - self._traces_t0[kind]
+                for kind, n in _STEP_TRACES.items()}
 
     def begin_column(self) -> None:
-        self._column_traced = False
+        self._column_t0 = _STEP_TRACES["column"]
 
     @property
     def scatter_traces(self) -> int:
@@ -679,7 +721,7 @@ class _ColumnPipeline:
     @property
     def column_traced(self) -> bool:
         """Did the current column trigger a fresh trace of the ARA step?"""
-        return self._column_traced
+        return _STEP_TRACES["column"] > self._column_t0
 
 
 def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,

@@ -12,10 +12,20 @@ def _drop_compiled_executables_between_modules():
 
     The CPU XLA backend in this toolchain segfaults once a single process
     accumulates enough compiled executables (the full suite compiles a few
-    thousand: per-factorization pipelines retrace by design). No single
+    thousand: every module warms its own shapes and options). No single
     module comes anywhere near the limit, so dropping the caches at module
     boundaries keeps the whole run bounded; tests that pin compile counts
     warm up and measure within one module, so they are unaffected.
     """
     yield
     jax.clear_caches()
+
+
+@pytest.fixture
+def fresh_column_steps():
+    """Start from an empty process-wide cache of the left driver's jitted
+    column steps, so a test that pins which columns trace sees the first
+    factorization of its configuration whatever ran before it."""
+    from repro.core.cholesky import _column_steps
+
+    _column_steps.cache_clear()

@@ -13,9 +13,11 @@ variants serve all nb columns. These tests pin that contract:
 import math
 
 import jax.numpy as jnp
+from jax import lax
 import numpy as np
 import pytest
 
+from repro import obs, precision
 from repro.core import (
     CholOptions, covariance_problem, from_dense, tlr_cholesky, tlr_ldlt,
     tlr_to_dense,
@@ -64,7 +66,7 @@ def test_column_buckets_cover_and_bound(nb):
 
 
 @pytest.mark.parametrize("mode", ["dynamic", "fused"])
-def test_column_step_compile_count(mode):
+def test_column_step_compile_count(fresh_column_steps, mode):
     """nb=8, b=64: the ARA column step compiles <= log2(nb)+1 variants."""
     _, A = _problem(n=512, b=64)
     assert A.nb == 8
@@ -81,6 +83,69 @@ def test_column_step_compile_count(mode):
         key = (ev["Tb"], ev["Jb"])
         assert ev["traced"] == (key not in seen)
         seen.add(key)
+
+
+_FACTOR_ARRAYS = ("D", "U", "V", "ranks")
+
+
+def _same_factor(f1, f2) -> bool:
+    return all(np.array_equal(np.asarray(getattr(f1.L, a)),
+                              np.asarray(getattr(f2.L, a)))
+               for a in _FACTOR_ARRAYS)
+
+
+def _traces(fact):
+    return [fact.stats[k] for k in
+            ("column_traces", "project_traces", "diag_traces")]
+
+
+@pytest.mark.parametrize("mode", ["dynamic", "fused"])
+@pytest.mark.parametrize("factor", [tlr_cholesky, tlr_ldlt],
+                         ids=["cholesky", "ldlt"])
+def test_warm_factorization_traces_nothing(factor, mode):
+    """The column steps are cached across factorizations: a second one
+    with the same options traces, lowers and compiles nothing (its JIT
+    work read under telemetry, which the first ran without) and returns
+    the first one's factor bit for bit."""
+    _, A = _problem(n=512, b=64)
+    opts = CholOptions(eps=1e-6, bs=8, mode=mode)
+    first = factor(A, opts)
+    obs.enable()
+    try:
+        warm = factor(A, opts)
+    finally:
+        obs.disable()
+    assert _traces(warm) == [0, 0, 0]
+    assert not any(ev["traced"] for ev in warm.stats["column_events"])
+    jit = warm.stats["telemetry"]["jit"]
+    assert jit["programs"] == 0 and jit["lower_s"] == 0, jit
+    assert _same_factor(first, warm)
+
+
+def _other_eps(monkeypatch):
+    return CholOptions(eps=1e-5, bs=8)
+
+
+def _other_precision(monkeypatch):
+    monkeypatch.setattr(precision, "MATMUL_PRECISION", lax.Precision.HIGH)
+    return CholOptions(eps=1e-6, bs=8)
+
+
+@pytest.mark.parametrize("other", [_other_eps, _other_precision],
+                         ids=["eps", "matmul_precision"])
+def test_column_steps_keyed_on_statics(fresh_column_steps, other):
+    """eps (static in the traced ARA step) and the library's matmul
+    precision (read while tracing) key the cached steps: another value
+    traces its own steps, and going back to the first traces nothing and
+    reproduces its factor."""
+    _, A = _problem(n=512, b=64)
+    fa = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8))
+    with pytest.MonkeyPatch.context() as mp:
+        fb = tlr_cholesky(A, other(mp))
+    fa2 = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8))
+    assert fb.stats["column_traces"] >= 1
+    assert _traces(fa2) == [0, 0, 0]
+    assert _same_factor(fa, fa2)
 
 
 def test_explicit_bucket_still_bounded():

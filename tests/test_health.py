@@ -15,7 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro import faults
+from repro import faults, obs
 from repro.core import (
     CholOptions, FactorizationBreakdown, RetryPolicy, SequentialSchedule,
     Stage, TLROperator, column_flags, covariance_problem, from_dense,
@@ -116,11 +116,23 @@ def test_rank_spike_recovers_left(prob1):
     eps-loosen / densify ladder (left driver); the factors stay finite and
     every remedy is on the record."""
     As = faults.spike_rank(prob1, 4, 1, seed=3, scale=1e-4)
-    fact = tlr_cholesky(As, CholOptions(eps=1e-6, bs=8, r_max_out=16,
-                                        check=True))
+    opts = CholOptions(eps=1e-6, bs=8, r_max_out=16, check=True)
+    fact = tlr_cholesky(As, opts)
     assert _finite(fact)
     over = [e for e in _events(fact) if e["kind"] == "rank_overflow"]
     assert over and {"eps_loosen"} <= {e["remedy"] for e in over}
+    # The retry's loosened-eps steps are cached with the rest: the same
+    # retry in a second factorization compiles nothing new.
+    obs.enable()
+    try:
+        again = tlr_cholesky(As, opts)
+    finally:
+        obs.disable()
+    assert _events(again) == _events(fact)
+    assert [again.stats[k] for k in ("column_traces", "project_traces",
+                                     "diag_traces", "scatter_traces")] \
+        == [0, 0, 0, 0]
+    assert again.stats["telemetry"]["jit"]["programs"] == 0
 
 
 def test_rank_spike_accepts_right(prob1):

@@ -215,9 +215,10 @@ def test_jit_listener_silent_while_disabled():
         obs.telemetry.JIT_KEYS, 0)
 
 
-def test_factorization_snapshot_carries_its_jit_work():
+def test_factorization_snapshot_carries_its_jit_work(fresh_column_steps):
     """A factorization's snapshot counts the programs its own subtree
-    compiled: the left driver jits its column steps per factorization."""
+    compiled: the first left-driver factorization of a configuration jits
+    its column steps (the cache is emptied first)."""
     op = _problem(n=128, b=32, seed=5)
     obs.enable()
     fact = op.cholesky(CholOptions(eps=1e-6))
