@@ -10,6 +10,12 @@ whose ``.matvec`` plugs into the same ``pcg`` slot.
 
 Run:  PYTHONPATH=src python examples/fractional_diffusion_pcg.py [--n 2048]
       ... --suite ns --check     # Newton-Schulz only + CI assertion
+      ... --device --n 32768 --tile 512 --suite cholesky   # chip size
+
+``--device`` builds the operator with ``fractional_diffusion_device`` in
+f32 with x64 off (no host matrix, so it runs at chip sizes), compresses it
+with ARA at 1e-4 and factors it at eps 1e-2 as PCG's preconditioner, as
+the benchmark's ``fracdiff3d-pcg`` cell does.
 """
 
 import argparse
@@ -19,14 +25,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_enable_x64", True)
-
 from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
 from repro.core import (  # noqa: E402
-    CholOptions, TLROperator, fractional_diffusion_problem, pcg,
+    CholOptions, TLROperator, fractional_diffusion_device,
+    fractional_diffusion_problem, grid_points, kd_tree_ordering, pcg,
     tlr_newton_schulz,
 )
 
@@ -43,6 +48,35 @@ def run_cholesky(op, Kfd, rhs, args):
         t_fact = time.perf_counter() - t0
         x, iters, hist = pcg(op, rhs, precond=fact, tol=1e-6, maxiter=300)
         print(f"{eps:>8g} {t_fact:>9.2f} {iters:>8d} {hist[-1]:>10.2e}")
+
+
+def build_on_device(args):
+    """The operator in f32 on the device (KD-ordered grid, normalized to a
+    unit largest diagonal), compressed with ARA at rank cap tile / 4; the
+    dense matrix is dropped."""
+    pts = grid_points(args.n, 3)
+    pts = pts[kd_tree_ordering(pts, args.tile)]
+    K = fractional_diffusion_device(pts, dtype=jnp.float32, rows=args.tile,
+                                    normalize=True)
+    op = TLROperator.compress(K, args.tile, args.tile // 4, 1e-4,
+                              method="ara", bs=16)
+    jax.block_until_ready(op.A.U)
+    return op
+
+
+def run_device_cholesky(op, rhs):
+    print(f"{'eps':>8} {'factor_s':>9} {'cg_iters':>8} {'solve_s':>8} "
+          f"{'residual':>10}")
+    for eps in (1e-1, 1e-2):
+        t0 = time.perf_counter()
+        fact = op.cholesky(CholOptions(eps=eps, bs=16))
+        jax.block_until_ready(fact.L.U)
+        t_fact = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        x, iters, hist = pcg(op, rhs, precond=fact, tol=1e-6, maxiter=300)
+        jax.block_until_ready(x)
+        print(f"{eps:>8g} {t_fact:>9.2f} {iters:>8d} "
+              f"{time.perf_counter() - t0:>8.2f} {hist[-1]:>10.2e}")
 
 
 def run_newton_schulz(op, rhs, it_plain, args):
@@ -78,17 +112,29 @@ def main():
     ap.add_argument("--check", action="store_true",
                     help="assert the Newton-Schulz preconditioner reduces "
                          "PCG iterations (CI examples-smoke)")
+    ap.add_argument("--device", action="store_true",
+                    help="build the operator on the device in f32 "
+                         "(fractional_diffusion_device), x64 off")
     args = ap.parse_args()
+    jax.config.update("jax_enable_x64", not args.device)
 
-    print(f"building 3D fractional-diffusion matrix, N={args.n}")
-    _, Kfd = fractional_diffusion_problem(args.n, args.tile)
-    cond = np.linalg.cond(Kfd) if args.n <= 4096 else float("nan")
-    print(f"condition number ~ {cond:.2e}")
-    op = TLROperator.compress(jnp.asarray(Kfd), args.tile, eps=1e-10)
-    rhs = jnp.asarray(np.random.default_rng(0).standard_normal(args.n))
-
-    if args.suite in ("all", "cholesky"):
-        run_cholesky(op, Kfd, rhs, args)
+    if args.device:
+        print(f"building 3D fractional-diffusion operator on "
+              f"{jax.devices()[0].platform}, N={args.n}, f32")
+        op = build_on_device(args)
+        rhs = jax.random.normal(jax.random.PRNGKey(0), (args.n,),
+                                jnp.float32)
+        if args.suite in ("all", "cholesky"):
+            run_device_cholesky(op, rhs)
+    else:
+        print(f"building 3D fractional-diffusion matrix, N={args.n}")
+        _, Kfd = fractional_diffusion_problem(args.n, args.tile)
+        cond = np.linalg.cond(Kfd) if args.n <= 4096 else float("nan")
+        print(f"condition number ~ {cond:.2e}")
+        op = TLROperator.compress(jnp.asarray(Kfd), args.tile, eps=1e-10)
+        rhs = jnp.asarray(np.random.default_rng(0).standard_normal(args.n))
+        if args.suite in ("all", "cholesky"):
+            run_cholesky(op, Kfd, rhs, args)
 
     _, it_plain, hist = pcg(op, rhs, tol=1e-6, maxiter=300)
     print(f"unpreconditioned CG: {it_plain} iters, residual {hist[-1]:.2e}")

@@ -46,6 +46,8 @@ Public API (operator-first since PR 2; DESIGN.md section 5):
   covariance_problem, fractional_diffusion_problem   paper's test matrices
   covariance_points, exp_covariance_device   the same points / covariance
                                              built on the device
+  fractional_diffusion_device                the section 6.2 operator built
+                                             on the device, SPD in f32
 
 Deprecated shims (kept for one release; each warns and delegates):
   from_dense          -> TLROperator.compress
@@ -72,7 +74,7 @@ from .solve import (  # noqa: F401
 from .generators import (  # noqa: F401
     grid_points, ball_points, exp_covariance, matern32_covariance,
     fractional_diffusion, covariance_problem, fractional_diffusion_problem,
-    covariance_points, exp_covariance_device,
+    covariance_points, exp_covariance_device, fractional_diffusion_device,
 )
 from .algebra import (  # noqa: F401
     TLRTiles, algebra_trace_count, generalize, offd_index, offd_pairs,

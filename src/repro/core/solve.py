@@ -517,11 +517,21 @@ class PCGHistory(list):
 
     On breakdown PCG returns the last finite iterate instead of silently
     flooding x and the history with NaNs for the remaining iterations.
+
+    Counters of a single-vector solve, kept with telemetry off too:
+    ``iterations`` (accepted CG steps, the returned count) and
+    ``host_reads`` (device-to-host reads of the convergence scalars, each
+    one wait for the device). Under telemetry ``telemetry`` holds the
+    metrics snapshot of the solve's ``algebra.pcg`` span (its
+    ``algebra.pcg.check`` reads, ``trsm.*`` and ``matvec.*`` children).
     """
 
     def __init__(self, *args):
         super().__init__(*args)
         self.breakdown: str | None = None
+        self.iterations = 0
+        self.host_reads = 0
+        self.telemetry: dict | None = None
 
 
 def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
@@ -558,28 +568,58 @@ def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
     stopping semantics (at most one extra partial window of recompute, only
     on the final window). The window is always clamped to the iterations
     remaining, so ``maxiter`` need not be a multiple of ``check_every``.
+
+    A single-vector solve counts its iterations and host reads on the
+    returned history; under telemetry it runs in an ``algebra.pcg`` span
+    (ending at its last read) with an ``algebra.pcg.check`` span around
+    each read, and the history carries the span's metrics snapshot.
     """
     if jnp.ndim(b_rhs) >= 2:
         return _pcg_batched(A, jnp.asarray(b_rhs), precond=precond, tol=tol,
                             maxiter=maxiter, check_every=check_every)
-    tol = float(tol)
+    history = PCGHistory()
+    args = (A, b_rhs, precond, float(tol), maxiter, max(1, int(check_every)),
+            history)
+    if not obs.enabled():
+        return _pcg_single(*args), history.iterations, history
+    with obs.span("algebra.pcg", cat="solve", n=int(b_rhs.shape[0]),
+                  maxiter=maxiter, check_every=check_every) as root:
+        x = _pcg_single(*args)
+        root.set(iterations=history.iterations,
+                 host_reads=history.host_reads)
+    history.telemetry = obs.metrics_snapshot(root=root)
+    return x, history.iterations, history
+
+
+def _pull(history: PCGHistory, value) -> np.ndarray:
+    """Read PCG's convergence scalars to the host: counted on
+    ``history``, in an ``algebra.pcg.check`` span under telemetry."""
+    history.host_reads += 1
+    with obs.span("algebra.pcg.check", cat="solve"):
+        return np.asarray(value)
+
+
+def _pcg_single(A, b_rhs, precond, tol: float, maxiter: int,
+                check_every: int, history: PCGHistory) -> jax.Array:
+    """The single-vector PCG loop of :func:`pcg`: fills ``history`` (its
+    residuals, breakdown and counters) and returns x."""
     matvec = _as_matvec(A)
     precond = _as_matvec(precond)
-    check_every = max(1, int(check_every))
-    bnorm = float(jnp.linalg.norm(b_rhs))
+    bnorm = float(_pull(history, jnp.linalg.norm(b_rhs)))
     if bnorm == 0.0:
-        return jnp.zeros_like(b_rhs), 0, PCGHistory()
+        return jnp.zeros_like(b_rhs)
     x = jnp.zeros_like(b_rhs)
     r = b_rhs - matvec(x)
     z = precond(r) if precond else r
     p_dir = z
     rz = vdot(r, z)
-    history = PCGHistory([float(jnp.linalg.norm(r)) / bnorm])
-    rz_f = float(rz)
+    rnorm0, rz_f = (float(v) for v in
+                    _pull(history, jnp.stack([jnp.linalg.norm(r), rz])))
+    history.append(rnorm0 / bnorm)
     if not np.isfinite(rz_f) or rz_f <= 0.0:
         history.breakdown = ("nonfinite" if not np.isfinite(rz_f)
                              else "indefinite_preconditioner")
-        return x, 0, history
+        return x
 
     def step(x, r, p_dir, rz):
         """One CG iteration; returns the new state and the (lazy, device)
@@ -609,7 +649,7 @@ def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
             st, sc = step(*st)
             scalars.append(sc)
         # One host sync for the whole window.
-        vals = np.asarray(jnp.stack([jnp.stack(sc) for sc in scalars]))
+        vals = _pull(history, jnp.stack([jnp.stack(sc) for sc in scalars]))
         accepted = 0
         for s in range(steps):
             pAp, rnorm_raw, rz_new = (float(v) for v in vals[s])
@@ -643,7 +683,8 @@ def pcg(A, b_rhs: jax.Array, *, precond=None, tol=1e-6,
             for _ in range(accepted):
                 st, _ = step(*st)
             state = st
-    return state[0], it, history
+    history.iterations = it
+    return state[0]
 
 
 # -- batched-RHS PCG with per-column convergence masks --------------------------
