@@ -18,9 +18,11 @@ zero columns are zero), so no masking is needed in the orthogonalization.
 Convergence is tracked per tile; the two execution modes differ in who drives
 the loop:
 
-* host mode  ("dynamic")  -- python loop + jitted step, convergence pulled to
-  host each block-iteration; enables Algorithm 5's converged-tile eviction /
-  refill at stable shapes.
+* dynamic mode ("dynamic") -- Algorithm 5's converged-tile eviction / refill
+  at stable shapes: a python loop + jitted step, convergence pulled to the
+  host each block-iteration, where the column has more tiles than slots;
+  one ``lax.while_loop`` over the same step, read once, where one batch
+  holds the column (``core/cholesky.py``).
 * fused mode ("fused")    -- a single ``lax.while_loop`` that runs until every
   tile in the batch converges; one jit for the whole column.
 """
@@ -236,24 +238,6 @@ def run_ara_fused(
     if not project:
         return state.Q, None, state.rank, state
     B = samplet_fn(data, state.Q)  # (T, m, r_max); cols past rank are zero
-    return state.Q, B, state.rank, state
-
-
-def run_ara_host(
-    step_fn, sample_fn, samplet_fn, data, key, *, T: int, b: int,
-    p: ARAParams, dtype, share_omega: bool = True,
-):
-    """Host-driven ARA: python loop, convergence pulled each iteration.
-
-    ``step_fn`` must be (a jitted wrapper of) ``ara_iteration`` partial'd on
-    ``sample_fn`` with ``data``/``state``/``key`` as traced args.
-    """
-    state = init_state(T, b, p, dtype)
-    for _ in range(p.iters):
-        state = step_fn(data, state, key)
-        if bool(jnp.all(state.converged)):
-            break
-    B = samplet_fn(data, state.Q)
     return state.Q, B, state.rank, state
 
 

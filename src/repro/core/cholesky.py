@@ -614,6 +614,7 @@ class _ColumnSteps(NamedTuple):
     fused_col: Callable
     fused_sample: Callable
     dyn_step: Callable
+    dyn_loop: Callable
     project: Callable
     diag_update: Callable
 
@@ -664,6 +665,36 @@ def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
         return ara_iteration(sample, data, state, key, p,
                              share_omega=share, T=Tb, b=b)
 
+    def dyn_loop(data, key):
+        # The dynamic column's ARA run as one device loop, for a column
+        # whose rows all hold a slot (nothing to evict or refill): the
+        # same ``ara_iteration`` as ``dyn_step``, stopped where the host
+        # loop stops -- every tile converged, or the column's budget of
+        # ``p.iters`` steps per row tile passed (the safety valve).
+        # ``tile_iters[t]`` counts the steps slot ``t`` entered
+        # unconverged; padding slots start converged and count none.
+        _STEP_TRACES["column"] += 1
+        Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
+        valid = data["valid"]
+        budget = p.iters * jnp.maximum(1, jnp.sum(valid))
+
+        def cond(carry):
+            state, _ = carry
+            return ~jnp.all(state.converged) & (state.it <= budget)
+
+        def body(carry):
+            state, tile_iters = carry
+            tile_iters = tile_iters + (~state.converged).astype(jnp.int32)
+            return (ara_iteration(sample, data, state, key, p,
+                                  share_omega=share, T=Tb, b=b),
+                    tile_iters)
+
+        # The state takes the dtype of L, A's working dtype (A's U and V
+        # may be stored narrower), as the host loop's state does.
+        state0 = init_state(Tb, b, p, data["Uk"].dtype, valid=valid)
+        return jax.lax.while_loop(
+            cond, body, (state0, jnp.zeros((Tb,), jnp.int32)))
+
     def project(data, Q, Lkk, dk_new):
         _STEP_TRACES["project"] += 1
         return _trsm(Lkk, dk_new, sample_t(data, Q), ldl)
@@ -674,17 +705,19 @@ def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
 
     return _ColumnSteps(sample, jax.jit(fused_col),
                         jax.jit(fused_sample), jax.jit(dyn_step),
-                        jax.jit(project), jax.jit(diag_update))
+                        jax.jit(dyn_loop), jax.jit(project),
+                        jax.jit(diag_update))
 
 
 class _ColumnPipeline:
     """Per-factorization handle on the shape-stable jitted column steps.
 
-    One jitted callable per role (fused column, dynamic ARA step, projection,
-    diagonal update), taken from the process-wide ``_column_steps`` cache;
-    jax's shape-keyed jit cache plus the bucket ladder keeps the number of
-    compiled variants at ~log2(nb), and a later factorization with the same
-    statics traces and lowers none of them again. The handle keeps the
+    One jitted callable per role (fused column, dynamic ARA step and loop,
+    projection, diagonal update), taken from the process-wide
+    ``_column_steps`` cache; jax's shape-keyed jit cache plus the bucket
+    ladder keeps the number of compiled variants at ~log2(nb), and a later
+    factorization with the same statics traces and lowers none of them
+    again. The handle keeps the
     options, the ARA parameters and the trace-count baselines, so
     ``traces`` and ``scatter_traces`` report the fresh traces made since
     it was built (a rank-overflow retry's included): ~log2(nb) in the
@@ -695,7 +728,7 @@ class _ColumnPipeline:
         self.opts = opts
         self.p = p
         (self.sample, self.fused_col, self.fused_sample, self.dyn_step,
-         self.project, self.diag_update) = _column_steps(
+         self.dyn_loop, self.project, self.diag_update) = _column_steps(
             opts.ldl, ops.resolve_impl(opts.impl), opts.share_omega, p,
             tile_mesh(), precision.MATMUL_PRECISION)
         self.scatter = _panel_scatter
@@ -745,33 +778,109 @@ def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
         wq = None
         Q, Vnew, ranks, it, err = pipe.fused_col(data, Lkk, dk_new, key)
     info = {"iters": pull(it, int), "err": pull(err[:T]), "T": T,
-            "Tb": Tb, "Jb": Jb, "safety_valve": False, "wQ": wq}
+            "Tb": Tb, "Jb": Jb, "safety_valve": False, "wQ": wq,
+            "ara_loop": "device"}
     return Q[:T], Vnew[:T], ranks[:T], info
 
 
 def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
                         Lkk, dk_new, key, ladder, widths=(None, None),
-                        pull=_read):
+                        pull=_read, *, a_ranks):
     """Algorithm 5: rank-sorted subset with converged-tile eviction/refill.
+
+    Where one batch holds every row of the column (``bucket=0``, and the
+    short columns under ``bucket > 0``), nothing is ever evicted or
+    refilled: the ARA loop then runs on the device (``_ara_device_loop``)
+    and is read once. Otherwise the host drives the slot queue
+    (``_ara_host_loop``), sorted by ``a_ranks``, the host copy of
+    ``A.ranks``.
 
     Returns the panel (Q, Vnew, ranks) zero-padded to the column's row
     bucket, and ``info`` for its ``T`` real rows. ``info`` also records
-    the batch's occupancy from the convergence flags the loop reads
-    anyway: ``tile_iters[t]``, the iterations in which row ``t`` held a
-    slot unconverged, and ``slots``, the slot width each step was
-    dispatched at, summed over the column's steps."""
+    the batch's occupancy: ``tile_iters[t]``, the iterations in which row
+    ``t`` held a slot unconverged, and ``slots``, the slot width each step
+    was dispatched at, summed over the column's steps; ``ara_loop`` says
+    which loop ran, ``"device"`` or ``"host"``."""
+    opts, p = pipe.opts, pipe.p
+    T_col = len(rows)
+    Tb_col, Jb = _column_buckets(A.nb, k, ladder)
+    requested = min(opts.bucket if opts.bucket > 0 else T_col, T_col)
+    Tb = _bucket_up(requested, ladder)
+    # The whole column in row order: the device loop samples it, and
+    # every column projects against it.
+    wA, wL = widths
+    data = _build_column_data(A, Lout, rows, k, perm, dvec, opts.ldl,
+                              Tb=Tb_col, Jb=Jb, wA=wA, wL=wL)
+    if Tb >= T_col:
+        Q_all, ranks, ranks_h, info = _ara_device_loop(pipe, data, key, k,
+                                                       T_col, pull)
+    else:
+        Q_all, ranks_h, info = _ara_host_loop(
+            pipe, A, Lout, rows, k, perm, dvec, key, Tb, Tb_col, Jb,
+            widths, pull, a_ranks)
+        ranks = jnp.asarray(np.pad(ranks_h, (0, Tb_col - T_col)))
+
+    # Project once (batched, bucket-padded full column) into the bases.
+    # The panel comes back padded to the column bucket, ready for the
+    # scatter into L.
+    with obs.span("chol.project", cat="factor", k=k):
+        if opts.batching == "ranked":
+            # Project at the rank-ladder width covering the detected ranks.
+            wq = bucket_width(ranks_h, p.r_max)
+            Vnew = pipe.project(data, Q_all[:, :, :wq], Lkk, dk_new)
+            Vnew = _pad_axis(Vnew, p.r_max, axis=2)
+        else:
+            wq = None
+            Vnew = pipe.project(data, Q_all, Lkk, dk_new)
+    info.update(T=T_col, Tb=Tb, Jb=Jb, wQ=wq, slots=Tb * info["iters"])
+    return Q_all, Vnew, ranks, info
+
+
+def _warn_safety_valve(k, iters, n_live, n_queued):
+    warnings.warn(
+        f"TLR column {k}: ARA safety valve tripped after {iters} "
+        f"iterations; {n_live} tile(s) kept their partial bases and "
+        f"{n_queued} queued tile(s) were recorded at rank 0 -- the "
+        f"factorization is degraded (raise max_iters/r_max or loosen eps; "
+        f"see stats['safety_valve'])", RuntimeWarning, stacklevel=6)
+
+
+def _ara_device_loop(pipe: _ColumnPipeline, data, key, k, T_col, pull):
+    """The column's ARA run as one device loop over ``data`` (every row
+    in one batch, in row order), and one host read of what it found.
+
+    The loop stops at the host loop's step and each tile keeps the state
+    it converged with, so ranks, errors, iterations and occupancy are
+    what the host loop records; with a shared Omega every slot draws the
+    same probes, so the slot order does not change them either. Returns
+    the bases and ranks (device, bucket-padded), the ranks on the host
+    and ``info``."""
+    state, tile_iters = pipe.dyn_loop(data, key)
+    rank, err, conv, it, tile_iters = pull(
+        (state.rank, state.err, state.converged, state.it, tile_iters),
+        jax.device_get)
+    iters, valve = int(it), not conv.all()
+    if valve:
+        _warn_safety_valve(k, iters, int(np.sum(~conv)), 0)
+    info = {"iters": iters, "err": np.asarray(err[:T_col], float),
+            "tile_iters": tile_iters[:T_col].astype(np.int64),
+            "safety_valve": valve, "ara_loop": "device"}
+    return state.Q, state.rank, rank[:T_col], info
+
+
+def _ara_host_loop(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec, key,
+                   Tb, Tb_col, Jb, widths, pull, a_ranks):
+    """Algorithm 5's slot queue, driven from the host: ``Tb`` slots, the
+    convergence flags read after every step, converged tiles evicted and
+    their slots refilled from the queue. Returns the bases in row order
+    (bucket-padded to ``Tb_col``), the ranks on the host and ``info``."""
     opts, p = pipe.opts, pipe.p
     wA, wL = widths
     T_col = len(rows)
-    requested = opts.bucket if opts.bucket > 0 else T_col
-    requested = min(requested, T_col)
-    Tb_col, Jb = _column_buckets(A.nb, k, ladder)
-    Tb = _bucket_up(requested, ladder)
     n_slots = min(Tb, T_col)
 
     # Sort rows by the rank of the original A tile, descending (section 4.2):
     # big tiles stay in the batch longest, so they enter first.
-    a_ranks = pull(A.ranks)
     key_rank = np.array(
         [a_ranks[tril_index(max(int(perm[i]), int(perm[k])),
                             min(int(perm[i]), int(perm[k])))]
@@ -853,41 +962,18 @@ def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
             # dropping them.
             safety_valve = True
             live = [s for s, lv in enumerate(slot_live) if lv]
-            n_live, n_queued = len(live), len(queue)
+            _warn_safety_valve(k, total_iters, len(live), len(queue))
             finish(live)
             # Rows still queued never entered a slot: they keep rank 0
             # (zero basis => zero tile) with an infinite error estimate so
             # the caller can see they were never processed.
             for i in queue:
                 err_h[pos_of[i]] = float("inf")
-            warnings.warn(
-                f"TLR column {k}: ARA safety valve tripped after "
-                f"{total_iters} iterations; {n_live} tile(s) kept their "
-                f"partial bases and {n_queued} queued tile(s) were "
-                f"recorded at rank 0 -- the factorization is degraded "
-                f"(raise max_iters/r_max or loosen eps; see "
-                f"stats['safety_valve'])", RuntimeWarning, stacklevel=4)
             break
 
-    # Project once (batched, bucket-padded full column) into the bases.
-    # The panel comes back padded to the column bucket, ready for the
-    # scatter into L.
-    full_data = _build_column_data(A, Lout, rows, k, perm, dvec, opts.ldl,
-                                   Tb=Tb_col, Jb=Jb, wA=wA, wL=wL)
-    with obs.span("chol.project", cat="factor", k=k):
-        if opts.batching == "ranked":
-            # Project at the rank-ladder width covering the detected ranks.
-            wq = bucket_width(ranks_h, p.r_max)
-            Vnew = pipe.project(full_data, Q_all[:, :, :wq], Lkk, dk_new)
-            Vnew = _pad_axis(Vnew, p.r_max, axis=2)
-        else:
-            wq = None
-            Vnew = pipe.project(full_data, Q_all, Lkk, dk_new)
-    info = {"iters": total_iters, "T": T_col, "Tb": Tb, "Jb": Jb,
-            "err": err_h, "safety_valve": safety_valve, "wQ": wq,
-            "tile_iters": tile_iters, "slots": Tb * total_iters}
-    ranks = jnp.asarray(np.pad(ranks_h, (0, Tb_col - T_col)))
-    return Q_all, Vnew, ranks, info
+    info = {"iters": total_iters, "err": err_h, "tile_iters": tile_iters,
+            "safety_valve": safety_valve, "ara_loop": "host"}
+    return Q_all, ranks_h, info
 
 
 @jax.jit
@@ -950,8 +1036,12 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     p = opts.ara_params(r_out)
     impl = ops.resolve_impl(opts.impl)  # validate the knob up front
     pull = _HostPulls()
+    # A's ranks on the host, read once: the batching plan, the A-tile
+    # gather width and the host ARA loop's queue order.
+    a_ranks = pull(A.ranks)
     policy = resolve_policy(opts.batching,
-                            tile_plan(A.ranks, A.r_max, read=pull),
+                            tile_plan(A.ranks, A.r_max,
+                                      read=lambda _: a_ranks),
                             b=b, dtype=A.dtype,
                             right_flush=opts.right_flush)
     batching = policy["batching"]
@@ -968,8 +1058,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     # ranks (monotone up the ladder, so it changes at most ~log2(r_max)
     # times over the whole factorization -- the compile count stays
     # O(log nb + log r_max) instead of multiplying).
-    wA = bucket_width(pull(A.ranks), A.r_max) if batching == "ranked" \
-        else None
+    wA = bucket_width(a_ranks, A.r_max) if batching == "ranked" else None
     wL = 1 if batching == "ranked" else None
     stats = {
         "column_iters": [], "column_ranks": [], "modified_chol": 0,
@@ -977,7 +1066,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         "bucket_ladder": list(ladder), "column_events": [],
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "batching": batching, "policy": policy,
-        "syncs": 0, "diag_syncs": 0,
+        "syncs": 0, "diag_syncs": 0, "ara_device_columns": 0,
     }
     health = HealthMonitor(opts.retry, "left", nb) if opts.check else None
     # Rank-overflow remedies re-run the failing rows' ARA pass at a
@@ -1192,7 +1281,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             with obs.span("chol.panel", cat="factor", k=k) as _psp:
                 L = _Lmat()
                 column = _column_ara_fused if opts.mode == "fused" \
-                    else _column_ara_dynamic
+                    else partial(_column_ara_dynamic, a_ranks=a_ranks)
                 Q, Vnew, ranks, info = column(
                     pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
                     kkey, ladder, widths=(wA, st.wL), pull=pull)
@@ -1202,7 +1291,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 ranks_h = pull((Q, Vnew, ranks), _ready_last)[:T]
                 if obs.enabled():
                     _psp.set(T=info["T"], Tb=info["Tb"], Jb=info["Jb"],
-                             iters=info["iters"],
+                             iters=info["iters"], ara_loop=info["ara_loop"],
                              rank_hist=obs.rank_hist(ranks_h, r_out))
             return Q, Vnew, ranks, ranks_h, info
 
@@ -1213,13 +1302,14 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             stats["column_iters"].append(info["iters"])
             stats["column_ranks"].append(ranks_h)
             stats["safety_valve"] |= info["safety_valve"]
+            stats["ara_device_columns"] += info["ara_loop"] == "device"
             stats["column_events"].append({
                 "k": k, "T": info["T"], "Tb": info["Tb"], "Jb": info["Jb"],
                 "seconds": dt, "traced": pipe.column_traced,
                 "err": np.asarray(info["err"]), "wQ": info.get("wQ"),
                 "syncs": pull.total - n0,
                 "tile_iters": info.get("tile_iters"),
-                "slots": info.get("slots"),
+                "slots": info.get("slots"), "ara_loop": info["ara_loop"],
             })
 
             with obs.span("chol.commit", cat="factor", k=k):
@@ -1428,7 +1518,8 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "flushes": 0, "acc_width": w_acc,
         "batching": batching, "policy": policy, "append_widths": [],
-        "syncs": None, "diag_syncs": None,   # host reads: left driver only
+        # host reads and the ARA loop: left driver only
+        "syncs": None, "diag_syncs": None, "ara_device_columns": None,
     }
     eps = jnp.asarray(opts.eps, dtype)
     health = HealthMonitor(opts.retry, "right", nb) if opts.check else None

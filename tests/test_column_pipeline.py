@@ -12,6 +12,7 @@ variants serve all nb columns. These tests pin that contract:
 
 import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 import numpy as np
@@ -254,4 +255,119 @@ def test_share_omega_false_through_ops_layer():
     f = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, share_omega=False,
                                     impl="ref"))
     Ld = _dense_L(f)
+    assert np.linalg.norm(K - Ld @ Ld.T, 2) < 1e-4
+
+
+# -- the dynamic ARA loop on the device against the host loop ------------------
+
+
+def _column_both_ways(factor, opts, r_max, k):
+    """Column ``k`` of an f32 factorization run through the device ARA loop
+    and through the host loop with one batch for the whole column (the
+    queue empty after the first fill), on the same operands and key."""
+    from repro.core.batching import bucket_width
+    from repro.core.cholesky import (_ColumnPipeline, _HostPulls,
+                                     _ara_device_loop, _ara_host_loop,
+                                     _build_column_data)
+
+    _, K = covariance_problem(512, 3, 64)
+    A = from_dense(jnp.asarray(K, jnp.float32), 64, r_max, 1e-7)
+    fact = factor(A, opts)
+    L, d = fact.L, fact.d
+    p = opts.ara_params(A.r_max)
+    pipe = _ColumnPipeline(opts, p)
+    ladder = _bucket_ladder(A.nb - 1)
+    rows, perm = np.arange(k + 1, A.nb), np.arange(A.nb)
+    Tb, Jb = _column_buckets(A.nb, k, ladder)
+    widths = (None, None)
+    if opts.batching == "ranked":
+        widths = (bucket_width(np.asarray(A.ranks), A.r_max),
+                  bucket_width(np.asarray(L.ranks), A.r_max))
+    data = _build_column_data(A, L, rows, k, perm, d, opts.ldl, Tb=Tb,
+                              Jb=Jb, wA=widths[0], wL=widths[1])
+    key = jax.random.PRNGKey(11)
+    dk = d[k] if opts.ldl else None
+    reads = _HostPulls()
+    Qd, ranks_d, ranks_dh, info_d = _ara_device_loop(pipe, data, key, k,
+                                                     len(rows), reads)
+    assert reads.total == 1
+    Qh, ranks_hh, info_h = _ara_host_loop(
+        pipe, A, L, rows, k, perm, d, key, Tb, Tb, Jb, widths, _HostPulls(),
+        np.asarray(A.ranks))
+    np.testing.assert_array_equal(np.asarray(ranks_d)[:len(rows)], ranks_dh)
+    return dict(
+        device=(Qd, ranks_dh, info_d, pipe.project(data, Qd, L.D[k], dk)),
+        host=(Qh, ranks_hh, info_h, pipe.project(data, Qh, L.D[k], dk)),
+        p=p, Tb=Tb)
+
+
+_PARITY = {
+    "cholesky-flat": (tlr_cholesky, dict(batching="flat"), 64, 1e-4),
+    "cholesky-ranked": (tlr_cholesky, dict(batching="ranked"), 64, 1e-4),
+    "ldlt-flat": (tlr_ldlt, dict(batching="flat"), 64, 1e-4),
+    "ldlt-ranked": (tlr_ldlt, dict(batching="ranked"), 64, 1e-4),
+    # eps out of reach at r_max 16: every tile fills its buffer and takes
+    # one more step, the full-rank step that records its error
+    "cholesky-full-rank": (tlr_cholesky, dict(batching="flat"), 16, 1e-9),
+}
+
+
+@pytest.mark.parametrize("case", list(_PARITY))
+def test_device_ara_loop_matches_the_host_loop(case):
+    factor, extra, r_max, eps = _PARITY[case]
+    opts = CholOptions(eps=eps, bs=8, **extra)
+    with jax.enable_x64(False):
+        run = _column_both_ways(factor, opts, r_max, k=2)
+        (Qd, rd, info_d, Vd), (Qh, rh, info_h, Vh) = run["device"], run["host"]
+        np.testing.assert_array_equal(rd, rh)
+        np.testing.assert_array_equal(info_d["err"], info_h["err"])
+        np.testing.assert_array_equal(info_d["tile_iters"], info_h["tile_iters"])
+        assert info_d["iters"] == info_h["iters"]
+        assert info_d["safety_valve"] is info_h["safety_valve"] is False
+        assert (info_d["ara_loop"], info_h["ara_loop"]) == ("device", "host")
+        np.testing.assert_allclose(np.asarray(Qd), np.asarray(Qh),
+                                   rtol=0, atol=1e-6)
+        scale = float(np.abs(np.asarray(Vh)).max())
+        np.testing.assert_allclose(np.asarray(Vd), np.asarray(Vh),
+                                   rtol=0, atol=1e-6 * scale)
+    if case == "cholesky-full-rank":
+        assert (rd == r_max).all()
+        assert info_d["iters"] == run["p"].iters + 1
+    else:
+        assert rd.max() < r_max
+
+
+def test_device_ara_loop_stops_at_the_host_loops_safety_valve():
+    """The column budget (``p.iters`` steps per row tile) ends both loops
+    at the same step, one past the budget, and both flush the partial
+    bases with the same warning."""
+    opts = CholOptions(eps=1e-13, bs=4, max_iters=1, batching="flat")
+    with jax.enable_x64(False), pytest.warns(RuntimeWarning,
+                                             match="safety valve"):
+        run = _column_both_ways(tlr_cholesky, opts, 64, k=5)
+    (_, rd, info_d, _), (_, rh, info_h, _) = run["device"], run["host"]
+    T = 2
+    assert info_d["iters"] == info_h["iters"] == T + 1
+    assert info_d["safety_valve"] is info_h["safety_valve"] is True
+    np.testing.assert_array_equal(rd, rh)
+    np.testing.assert_array_equal(info_d["tile_iters"], info_h["tile_iters"])
+
+
+def test_refilling_columns_keep_the_host_loop():
+    """Under ``bucket > 0`` a column with more rows than slots evicts and
+    refills on the host; a column that fits its slots runs on the device."""
+    _, A = _problem(n=512, b=64)
+    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, bucket=2))
+    events = fact.stats["column_events"]
+    host = [e for e in events if e["T"] > e["Tb"]]
+    assert len(host) == 5
+    for e in events:
+        assert e["ara_loop"] == ("host" if e in host else "device")
+    for e in host:
+        # every row held a slot, though fewer slots than rows: refilled
+        assert len(e["tile_iters"]) == e["T"] > e["Tb"]
+        assert e["tile_iters"].min() >= 1
+    assert fact.stats["ara_device_columns"] == len(events) - len(host)
+    Ld = _dense_L(fact)
+    K, _ = _problem(n=512, b=64)
     assert np.linalg.norm(K - Ld @ Ld.T, 2) < 1e-4

@@ -37,7 +37,8 @@ def _host_reads(monkeypatch):
     ``np.asarray`` takes the buffer protocol and never calls
     ``ArrayImpl.__array__``, and ``jax.transfer_guard`` does not fire, so
     each entry point is wrapped: the NumPy constructors, the scalar
-    conversions and the list/item reads. Nested entries count once."""
+    conversions, the list/item reads and ``jax.device_get``, which reads
+    a whole pytree in one go. Nested entries count once."""
     count = [0]
     depth = [0]
 
@@ -60,12 +61,16 @@ def _host_reads(monkeypatch):
     for name in ("__array__", "__int__", "__float__", "__bool__",
                  "__index__", "__complex__", "item", "tolist"):
         wrap(ArrayImpl, name, lambda a: a[0])
+    wrap(jax, "device_get",
+         lambda a: next((x for x in jax.tree_util.tree_leaves(a[0])
+                         if isinstance(x, jax.Array)), None))
     yield count
 
 
 OPTIONS = {
     "dynamic": CholOptions(eps=1e-6),
     "dynamic-ranked": CholOptions(eps=1e-6, batching="ranked"),
+    "bucketed": CholOptions(eps=1e-6, bucket=2),
     "fused": CholOptions(eps=1e-6, mode="fused"),
     "fused-ranked": CholOptions(eps=1e-6, mode="fused", batching="ranked"),
     "checked": CholOptions(eps=1e-6, check=True),
@@ -87,9 +92,14 @@ def test_pull_count_equals_an_independent_count(monkeypatch, case):
     assert sum(per_col) + stats["diag_syncs"] <= stats["syncs"]
     # one pull per diagonal: the modified-Cholesky flag (and the pivot)
     assert stats["diag_syncs"] == op.nb * (1 + (opts.pivot is not None))
+    if opts.mode == "dynamic" and opts.bucket == 0 and not opts.check:
+        # per column: the diagonal's reads, the ARA loop's one read and
+        # the panel's end; once per factorization: A's ranks
+        assert stats["syncs"] <= op.nb * (3 + (opts.pivot is not None))
+        assert per_col == [2] * (op.nb - 1)
 
 
-@pytest.mark.parametrize("case", ["dynamic", "dynamic-ranked"])
+@pytest.mark.parametrize("case", ["dynamic", "dynamic-ranked", "bucketed"])
 def test_slot_occupancy_bounds(case):
     fact = _problem(seed=1).cholesky(OPTIONS[case])
     events = fact.stats["column_events"]
@@ -103,13 +113,20 @@ def test_slot_occupancy_bounds(case):
     assert 0 < occ <= 1
 
 
+def test_default_options_run_every_ara_loop_on_the_device():
+    fact = _problem(seed=1).cholesky(OPTIONS["dynamic"])
+    events = fact.stats["column_events"]
+    assert fact.stats["ara_device_columns"] == len(events) == 7
+    assert {e["ara_loop"] for e in events} == {"device"}
+
+
 def test_fused_mode_records_no_occupancy():
     fact = _problem(seed=1).cholesky(OPTIONS["fused"])
     assert all(e["tile_iters"] is None and e["slots"] is None
                for e in fact.stats["column_events"])
 
 
-@pytest.mark.parametrize("case", ["dynamic", "fused-ranked"])
+@pytest.mark.parametrize("case", ["dynamic", "fused-ranked", "bucketed"])
 def test_telemetry_leaves_the_factor_bit_identical(case):
     op = _problem(seed=2)
     opts = OPTIONS[case]
@@ -127,9 +144,11 @@ def test_telemetry_leaves_the_factor_bit_identical(case):
     assert names.count("chol.pull") == on.stats["syncs"]
     assert names.count("chol.commit") == op.nb - 1
     assert names.count("chol.project") == op.nb - 1
-    ara_iters = sum(on.stats["column_iters"]) if opts.mode == "dynamic" \
-        else 0
-    assert names.count("chol.ara_iter") == ara_iters
+    # one per step of the host ARA loop; the device loop opens none
+    host_iters = sum(it for it, e in zip(on.stats["column_iters"],
+                                         on.stats["column_events"])
+                     if e["ara_loop"] == "host")
+    assert names.count("chol.ara_iter") == host_iters
     ph = on.stats["telemetry"]["phases"]
     assert ph["chol.pull"]["count"] == on.stats["syncs"]
     # a pull is inside the span it serves: the ARA step's, the panel's,
