@@ -372,18 +372,6 @@ def bench_lookahead():
         emit(f"lookahead/{'on' if lookahead else 'seq'}", dt * 1e6, extra)
 
 
-def bench_batching_modes():
-    """Section 4.2: dynamic batched ARA vs fused whole-column batching."""
-    n, b = scaled(1024), 128
-    K, op = _build(n, 3, b)
-    for mode, bucket in (("fused", 0), ("dynamic", 0), ("dynamic", 4)):
-        dt, fact = timeit(
-            lambda: op.cholesky(CholOptions(eps=1e-6, bs=8, mode=mode,
-                                            bucket=bucket)), repeats=1)
-        emit(f"sec4.2/{mode}_bucket{bucket}", dt * 1e6,
-             f"err={_factor_err(K, fact):.2e}")
-
-
 def bench_column_buckets():
     """DESIGN section 2: compile vs steady-state time per column bucket.
 
@@ -395,34 +383,35 @@ def bench_column_buckets():
     """
     n, b = scaled(2048), 128
     K, op = _build(n, 3, b)
-    for mode in ("dynamic", "fused"):
-        t0 = time.perf_counter()
-        fact = op.cholesky(CholOptions(eps=1e-6, bs=8, mode=mode))
-        total = time.perf_counter() - t0
-        ev = fact.stats["column_events"]
-        buckets = {}
-        for e in ev:
-            d = buckets.setdefault((e["Tb"], e["Jb"]),
-                                   {"compile_s": 0.0, "steady_s": 0.0,
-                                    "cols": 0, "steady_cols": 0})
-            d["cols"] += 1
-            if e["traced"]:
-                d["compile_s"] += e["seconds"]
-            else:
-                d["steady_s"] += e["seconds"]
-                d["steady_cols"] += 1
-        for (Tb, Jb), d in sorted(buckets.items()):
-            # steady-state mean; compile-inclusive when the bucket's only
-            # columns all traced (e.g. the Tb=1 bucket has one column)
-            per_col = (d["steady_s"] / d["steady_cols"] if d["steady_cols"]
-                       else (d["compile_s"] / d["cols"]))
-            emit(f"pipeline/{mode}_bucket_T{Tb}_J{Jb}", per_col * 1e6,
-                 f"cols={d['cols']};compile_s={d['compile_s']:.2f};"
-                 f"steady_s={d['steady_s']:.2f}")
-        emit(f"pipeline/{mode}_total", total * 1e6,
-             f"column_traces={fact.stats['column_traces']};"
-             f"columns={len(ev)};ladder={fact.stats['bucket_ladder']};"
-             f"err={_factor_err(K, fact):.2e}")
+    t0 = time.perf_counter()
+    fact = op.cholesky(CholOptions(eps=1e-6, bs=8))
+    total = time.perf_counter() - t0
+    ev = fact.stats["column_events"]
+    buckets = {}
+    for e in ev:
+        d = buckets.setdefault((e["Tb"], e["Jb"]),
+                               {"compile_s": 0.0, "steady_s": 0.0,
+                                "cols": 0, "steady_cols": 0})
+        d["cols"] += 1
+        if e["traced"]:
+            d["compile_s"] += e["seconds"]
+        else:
+            d["steady_s"] += e["seconds"]
+            d["steady_cols"] += 1
+    # The record names keep their "dynamic_" prefix: the committed
+    # baselines key on them.
+    for (Tb, Jb), d in sorted(buckets.items()):
+        # steady-state mean; compile-inclusive when the bucket's only
+        # columns all traced (e.g. the Tb=1 bucket has one column)
+        per_col = (d["steady_s"] / d["steady_cols"] if d["steady_cols"]
+                   else (d["compile_s"] / d["cols"]))
+        emit(f"pipeline/dynamic_bucket_T{Tb}_J{Jb}", per_col * 1e6,
+             f"cols={d['cols']};compile_s={d['compile_s']:.2f};"
+             f"steady_s={d['steady_s']:.2f}")
+    emit("pipeline/dynamic_total", total * 1e6,
+         f"column_traces={fact.stats['column_traces']};"
+         f"columns={len(ev)};ladder={fact.stats['bucket_ladder']};"
+         f"err={_factor_err(K, fact):.2e}")
 
 
 def bench_share_omega():
@@ -842,7 +831,7 @@ ALL = [
     bench_compress, bench_factor_time, bench_profile, bench_pcg,
     bench_trsm_old_vs_new, bench_solve_plans, bench_rank_vs_svd,
     bench_pivoting, bench_left_vs_right, bench_lookahead,
-    bench_batching_modes, bench_column_buckets, bench_share_omega,
+    bench_column_buckets, bench_share_omega,
     bench_flop_rate,
     bench_algebra_round_axpy, bench_algebra_gemm, bench_newton_schulz,
     bench_batching, bench_serve, bench_faults,
@@ -853,7 +842,7 @@ SUITES = {
     "build": [bench_compress, bench_memory_growth, bench_rank_distributions],
     "factor": [bench_tile_size, bench_factor_time, bench_profile,
                bench_pivoting, bench_left_vs_right, bench_lookahead,
-               bench_batching_modes, bench_column_buckets,
+               bench_column_buckets,
                bench_share_omega, bench_flop_rate, bench_batching],
     "solve": [bench_trsm_old_vs_new, bench_solve_plans, bench_pcg],
     "algebra": [bench_algebra_round_axpy, bench_algebra_gemm,

@@ -39,8 +39,8 @@ def main():
           f"compression {mem['compression_ratio']:.1f}x, "
           f"avg rank {mem['avg_rank']:.1f})")
 
-    print(f"factoring with ARA Cholesky (eps={args.eps}, dynamic batching)")
-    fact = op.cholesky(CholOptions(eps=args.eps, bs=16, mode="dynamic"))
+    print(f"factoring with ARA Cholesky (eps={args.eps})")
+    fact = op.cholesky(CholOptions(eps=args.eps, bs=16))
     ranks = np.asarray(fact.L.ranks)
     print(f"factor ranks: avg {ranks.mean():.1f}, max {ranks.max()}")
 

@@ -8,23 +8,18 @@ expression* ``A(i,k) - sum_j L(i,j) L(k,j)^T`` without ever forming it:
   samplet_fn(data, Q)    -> B = Op^T @ Q        (T, m, R)
 
 ``data`` is an explicit pytree of operand arrays (tile gathers); it is an
-argument rather than a closure capture so jitted steps are reusable across
-the dynamic-batching refills of Algorithm 5.
+argument rather than a closure capture, so one jitted step serves every
+column of a row bucket.
 
 TPU adaptation (see DESIGN.md section 2): the batch is *uniform* -- every
 tile owns a zero-padded rank-``r_max`` basis buffer ``Q`` and a rank counter.
 Zero padding makes the padded columns numerically inert (projections against
 zero columns are zero), so no masking is needed in the orthogonalization.
-Convergence is tracked per tile; the two execution modes differ in who drives
-the loop:
-
-* dynamic mode ("dynamic") -- Algorithm 5's converged-tile eviction / refill
-  at stable shapes: a python loop + jitted step, convergence pulled to the
-  host each block-iteration, where the column has more tiles than slots;
-  one ``lax.while_loop`` over the same step, read once, where one batch
-  holds the column (``core/cholesky.py``).
-* fused mode ("fused")    -- a single ``lax.while_loop`` that runs until every
-  tile in the batch converges; one jit for the whole column.
+Convergence is tracked per tile, and a converged tile stops appending where
+Algorithm 5 would evict it. A batch runs as one ``lax.while_loop`` over
+:func:`ara_iteration` until every tile converges: :func:`run_ara_fused`
+here, and the TLR factorization's column loop (``core/cholesky.py``), which
+reads its outcome once per column.
 """
 
 from __future__ import annotations
@@ -91,10 +86,9 @@ def rank_overflow(ranks, err, p: ARAParams) -> np.ndarray:
 
     A tile overflows when it sits at the cap with a residual estimate
     still above ``p.eps`` (the ``~room`` forced-convergence path of
-    :func:`ara_iteration`), or when its error estimate is non-finite --
-    the dynamic driver's safety valve records never-processed tiles at
-    rank 0 with ``err = inf``, and those need the same remedy ladder
-    (eps-loosened re-pass, then densify; DESIGN.md section 13).
+    :func:`ara_iteration`), or when its error estimate is non-finite;
+    both take the same remedy ladder (eps-loosened re-pass, then densify;
+    DESIGN.md section 13).
     """
     ranks = np.asarray(ranks)
     err = np.asarray(err)
@@ -209,20 +203,11 @@ def ara_iteration(
 def run_ara_fused(
     sample_fn, samplet_fn, data, key, *, T: int, b: int, m: int,
     p: ARAParams, dtype, share_omega: bool = True, valid=None,
-    project: bool = True,
 ):
     """Single-jit ARA for a whole batch: while_loop until all tiles converge.
 
     ``valid`` marks real slots when the batch is zero-padded up to a bucket
     size (see ``init_state``); padding slots are inert.
-
-    ``project=False`` skips the trailing projection ``B = Op^T Q`` and
-    returns ``B = None``: the rank-bucketed factorization path
-    (``CholOptions.batching="ranked"``) pulls the detected ranks to the
-    host first, then projects against ``Q`` sliced to the rank-ladder
-    width that covers them (columns of ``Q`` past each tile's rank are
-    zero, so the slice is exact) -- the projection chain runs at the
-    bucketed width instead of ``r_max``.
     """
     state0 = init_state(T, b, p, dtype, valid=valid)
 
@@ -235,8 +220,6 @@ def run_ara_fused(
         )
 
     state = jax.lax.while_loop(cond, body, state0)
-    if not project:
-        return state.Q, None, state.rank, state
     B = samplet_fn(data, state.Q)  # (T, m, r_max); cols past rank are zero
     return state.Q, B, state.rank, state
 

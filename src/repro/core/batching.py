@@ -258,9 +258,8 @@ def _round_core_flops(n, b, w, r_out, dtype, impl) -> float:
 def plan_rank_buckets(ranks, cap: int) -> TilePlan:
     """Group tile indices by ``bucket_up(rank)`` on the rank ladder.
 
-    Runs on the host (the per-tile ranks are pulled once per dispatch --
-    the same host orchestration the paper's dynamic batching and the
-    left-looking driver's Algorithm 5 eviction loop already do). Rank-0
+    Runs on the host (the per-tile ranks are pulled once per dispatch, as
+    the paper's dynamic batching orchestrates from the host). Rank-0
     tiles land in ``zero_idx`` and never touch a kernel. Prefer
     :func:`tile_plan`, which memoizes the result on the ranks array.
     """

@@ -18,10 +18,11 @@ LEFT-LOOKING (``algo="left"``, the paper's driver). Per block column ``k``
   4. batched triangular solve  V(i,k) = L(k,k)^{-1} B_i  (+ D^{-1} scaling
      for LDL^T).
 
-Dynamic batching (Algorithm 5): tiles are sorted by their rank in A
-descending; a fixed-size slot buffer processes a subset, evicting converged
-tiles and refilling from the remainder at *stable shapes* (the TPU-friendly
-equivalent of MAGMA pointer-marshaling; see DESIGN.md section 2).
+Column ARA (Algorithm 5's batch, DESIGN.md section 2): the whole column is
+one batch at a *stable shape*; one device loop (``lax.while_loop``) steps
+it until every tile converges, converged tiles masked where the paper
+evicts them, and the host reads the outcome once. The dead slots this
+leaves are counted (``column_events[k]["tile_iters"]``, ``["slots"]``).
 
 Shape-stable column pipeline (DESIGN.md sections 2-3): the row-batch size
 ``T = nb-k-1`` and prior-column count ``J = k`` change every column, which
@@ -69,7 +70,7 @@ import types
 
 from . import ara as ara_mod
 from .algebra import (algebra_trace_count, tlr_round_tiles, tlr_syrk_column)
-from .ara import ARAParams, ara_iteration, init_state, run_ara_fused
+from .ara import ARAParams, ara_iteration, init_state
 from .batching import (batching_trace_count, bucket_width,
                        bucketed_round_tiles, pad_tile_batch, resolve_policy,
                        shard_tile_batch, tile_mesh, tile_plan)
@@ -91,8 +92,6 @@ class CholOptions:
     bs: int = 16
     r_max_out: int = 0            # 0 => A.r_max
     algo: str = "left"            # "left" (ARA sampling) | "right" (eager updates)
-    mode: str = "dynamic"         # "dynamic" | "fused" (left-looking only)
-    bucket: int = 0               # 0 => whole column in one batch
     share_omega: bool = True      # share Omega across the column (beyond-paper)
     schur: Optional[str] = "diag" # None | "diag" | "full"
     modified_chol: bool = True
@@ -611,9 +610,6 @@ _COLUMN_STEP_CONFIGS = 32
 
 class _ColumnSteps(NamedTuple):
     sample: Callable
-    fused_col: Callable
-    fused_sample: Callable
-    dyn_step: Callable
     dyn_loop: Callable
     project: Callable
     diag_update: Callable
@@ -636,41 +632,12 @@ def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
     """
     sample, sample_t = make_column_samplers(ldl, impl)
 
-    def fused_col(data, Lkk, dk_new, key):
-        _STEP_TRACES["column"] += 1
-        Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
-        Q, B, ranks, state = run_ara_fused(
-            sample, sample_t, data, key, T=Tb, b=b, m=b,
-            p=p, dtype=data["Ua"].dtype, share_omega=share,
-            valid=data["valid"],
-        )
-        return Q, _trsm(Lkk, dk_new, B, ldl), ranks, state.it, state.err
-
-    def fused_sample(data, key):
-        # Ranked batching: sampling only -- the projection runs after
-        # the detected ranks reach the host, against Q sliced to the
-        # rank-ladder width that covers them (see run_ara_fused).
-        _STEP_TRACES["column"] += 1
-        Tb, b = data["Ua"].shape[0], data["Ua"].shape[1]
-        Q, _, ranks, state = run_ara_fused(
-            sample, sample_t, data, key, T=Tb, b=b, m=b,
-            p=p, dtype=data["Ua"].dtype, share_omega=share,
-            valid=data["valid"], project=False,
-        )
-        return Q, ranks, state.it, state.err
-
-    def dyn_step(data, state, key):
-        _STEP_TRACES["column"] += 1
-        Tb, b = state.Q.shape[0], state.Q.shape[1]
-        return ara_iteration(sample, data, state, key, p,
-                             share_omega=share, T=Tb, b=b)
-
     def dyn_loop(data, key):
-        # The dynamic column's ARA run as one device loop, for a column
-        # whose rows all hold a slot (nothing to evict or refill): the
-        # same ``ara_iteration`` as ``dyn_step``, stopped where the host
-        # loop stops -- every tile converged, or the column's budget of
-        # ``p.iters`` steps per row tile passed (the safety valve).
+        # The column's ARA as one device loop: ``ara_iteration`` stepped
+        # until every tile converged, or the column's budget of
+        # ``p.iters`` steps per row tile passed (the safety valve). A tile
+        # that fills its buffer is flagged in one more step, which records
+        # its full-rank error for the rank-overflow check.
         # ``tile_iters[t]`` counts the steps slot ``t`` entered
         # unconverged; padding slots start converged and count none.
         _STEP_TRACES["column"] += 1
@@ -690,7 +657,7 @@ def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
                     tile_iters)
 
         # The state takes the dtype of L, A's working dtype (A's U and V
-        # may be stored narrower), as the host loop's state does.
+        # may be stored narrower).
         state0 = init_state(Tb, b, p, data["Uk"].dtype, valid=valid)
         return jax.lax.while_loop(
             cond, body, (state0, jnp.zeros((Tb,), jnp.int32)))
@@ -703,22 +670,19 @@ def _column_steps(ldl: bool, impl: str, share: bool, p: ARAParams,
         _STEP_TRACES["diag"] += 1
         return _diag_update_sum(Uk, Vk, dk)
 
-    return _ColumnSteps(sample, jax.jit(fused_col),
-                        jax.jit(fused_sample), jax.jit(dyn_step),
-                        jax.jit(dyn_loop), jax.jit(project),
+    return _ColumnSteps(sample, jax.jit(dyn_loop), jax.jit(project),
                         jax.jit(diag_update))
 
 
 class _ColumnPipeline:
     """Per-factorization handle on the shape-stable jitted column steps.
 
-    One jitted callable per role (fused column, dynamic ARA step and loop,
-    projection, diagonal update), taken from the process-wide
-    ``_column_steps`` cache; jax's shape-keyed jit cache plus the bucket
-    ladder keeps the number of compiled variants at ~log2(nb), and a later
-    factorization with the same statics traces and lowers none of them
-    again. The handle keeps the
-    options, the ARA parameters and the trace-count baselines, so
+    One jitted callable per role (the ARA loop, projection, diagonal
+    update), taken from the process-wide ``_column_steps`` cache; jax's
+    shape-keyed jit cache plus the bucket ladder keeps the number of
+    compiled variants at ~log2(nb), and a later factorization with the
+    same statics traces and lowers none of them again. The handle keeps
+    the options, the ARA parameters and the trace-count baselines, so
     ``traces`` and ``scatter_traces`` report the fresh traces made since
     it was built (a rank-overflow retry's included): ~log2(nb) in the
     first factorization of a configuration, 0 in a warm one.
@@ -727,8 +691,8 @@ class _ColumnPipeline:
     def __init__(self, opts: CholOptions, p: ARAParams):
         self.opts = opts
         self.p = p
-        (self.sample, self.fused_col, self.fused_sample, self.dyn_step,
-         self.dyn_loop, self.project, self.diag_update) = _column_steps(
+        (self.sample, self.dyn_loop, self.project,
+         self.diag_update) = _column_steps(
             opts.ldl, ops.resolve_impl(opts.impl), opts.share_omega, p,
             tile_mesh(), precision.MATMUL_PRECISION)
         self.scatter = _panel_scatter
@@ -757,229 +721,54 @@ class _ColumnPipeline:
         return _STEP_TRACES["column"] > self._column_t0
 
 
-def _column_ara_fused(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
-                      Lkk, dk_new, key, ladder, widths=(None, None),
-                      pull=_read):
+def _column_ara(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
+                Lkk, dk_new, key, ladder, widths, pull):
+    """Column ``k``'s ARA over ``rows``, projected into the panel.
+
+    The operands are built once at the column's row bucket, the ARA runs
+    as one device loop (``dyn_loop``) and its outcome comes back in one
+    read; the projection then runs at the rank-ladder width covering the
+    detected ranks (ranked batching) or at ``r_max`` (flat).
+
+    Returns the bases, the projected panel and the ranks, zero-padded to
+    the row bucket, and ``info`` for the ``T`` real rows: ``iters``,
+    ``err``, ``safety_valve``, the bucket pair, ``wQ``, and the batch's
+    occupancy: ``tile_iters[t]``, the steps row ``t`` entered
+    unconverged, and ``slots``, the slots dispatched over the column's
+    steps."""
+    opts, p = pipe.opts, pipe.p
     T = len(rows)
     Tb, Jb = _column_buckets(A.nb, k, ladder)
     wA, wL = widths
-    data = _build_column_data(A, Lout, rows, k, perm, dvec, pipe.opts.ldl,
-                              Tb=Tb, Jb=Jb, wA=wA, wL=wL)
-    if pipe.opts.batching == "ranked":
-        # Sample-then-project: the projection chain runs at the rank-ladder
-        # width covering the detected ranks, not at r_max (exact -- columns
-        # of Q past each tile's rank are zero).
-        Q, ranks, it, err = pipe.fused_sample(data, key)
-        wq = bucket_width(pull(ranks[:T]), pipe.p.r_max)
-        with obs.span("chol.project", cat="factor", k=k):
-            Vnew = pipe.project(data, Q[:, :, :wq], Lkk, dk_new)
-            Vnew = _pad_axis(Vnew, pipe.p.r_max, axis=2)
-    else:
-        wq = None
-        Q, Vnew, ranks, it, err = pipe.fused_col(data, Lkk, dk_new, key)
-    info = {"iters": pull(it, int), "err": pull(err[:T]), "T": T,
-            "Tb": Tb, "Jb": Jb, "safety_valve": False, "wQ": wq,
-            "ara_loop": "device"}
-    return Q[:T], Vnew[:T], ranks[:T], info
-
-
-def _column_ara_dynamic(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec,
-                        Lkk, dk_new, key, ladder, widths=(None, None),
-                        pull=_read, *, a_ranks):
-    """Algorithm 5: rank-sorted subset with converged-tile eviction/refill.
-
-    Where one batch holds every row of the column (``bucket=0``, and the
-    short columns under ``bucket > 0``), nothing is ever evicted or
-    refilled: the ARA loop then runs on the device (``_ara_device_loop``)
-    and is read once. Otherwise the host drives the slot queue
-    (``_ara_host_loop``), sorted by ``a_ranks``, the host copy of
-    ``A.ranks``.
-
-    Returns the panel (Q, Vnew, ranks) zero-padded to the column's row
-    bucket, and ``info`` for its ``T`` real rows. ``info`` also records
-    the batch's occupancy: ``tile_iters[t]``, the iterations in which row
-    ``t`` held a slot unconverged, and ``slots``, the slot width each step
-    was dispatched at, summed over the column's steps; ``ara_loop`` says
-    which loop ran, ``"device"`` or ``"host"``."""
-    opts, p = pipe.opts, pipe.p
-    T_col = len(rows)
-    Tb_col, Jb = _column_buckets(A.nb, k, ladder)
-    requested = min(opts.bucket if opts.bucket > 0 else T_col, T_col)
-    Tb = _bucket_up(requested, ladder)
-    # The whole column in row order: the device loop samples it, and
-    # every column projects against it.
-    wA, wL = widths
     data = _build_column_data(A, Lout, rows, k, perm, dvec, opts.ldl,
-                              Tb=Tb_col, Jb=Jb, wA=wA, wL=wL)
-    if Tb >= T_col:
-        Q_all, ranks, ranks_h, info = _ara_device_loop(pipe, data, key, k,
-                                                       T_col, pull)
-    else:
-        Q_all, ranks_h, info = _ara_host_loop(
-            pipe, A, Lout, rows, k, perm, dvec, key, Tb, Tb_col, Jb,
-            widths, pull, a_ranks)
-        ranks = jnp.asarray(np.pad(ranks_h, (0, Tb_col - T_col)))
-
-    # Project once (batched, bucket-padded full column) into the bases.
-    # The panel comes back padded to the column bucket, ready for the
-    # scatter into L.
-    with obs.span("chol.project", cat="factor", k=k):
-        if opts.batching == "ranked":
-            # Project at the rank-ladder width covering the detected ranks.
-            wq = bucket_width(ranks_h, p.r_max)
-            Vnew = pipe.project(data, Q_all[:, :, :wq], Lkk, dk_new)
-            Vnew = _pad_axis(Vnew, p.r_max, axis=2)
-        else:
-            wq = None
-            Vnew = pipe.project(data, Q_all, Lkk, dk_new)
-    info.update(T=T_col, Tb=Tb, Jb=Jb, wQ=wq, slots=Tb * info["iters"])
-    return Q_all, Vnew, ranks, info
-
-
-def _warn_safety_valve(k, iters, n_live, n_queued):
-    warnings.warn(
-        f"TLR column {k}: ARA safety valve tripped after {iters} "
-        f"iterations; {n_live} tile(s) kept their partial bases and "
-        f"{n_queued} queued tile(s) were recorded at rank 0 -- the "
-        f"factorization is degraded (raise max_iters/r_max or loosen eps; "
-        f"see stats['safety_valve'])", RuntimeWarning, stacklevel=6)
-
-
-def _ara_device_loop(pipe: _ColumnPipeline, data, key, k, T_col, pull):
-    """The column's ARA run as one device loop over ``data`` (every row
-    in one batch, in row order), and one host read of what it found.
-
-    The loop stops at the host loop's step and each tile keeps the state
-    it converged with, so ranks, errors, iterations and occupancy are
-    what the host loop records; with a shared Omega every slot draws the
-    same probes, so the slot order does not change them either. Returns
-    the bases and ranks (device, bucket-padded), the ranks on the host
-    and ``info``."""
+                              Tb=Tb, Jb=Jb, wA=wA, wL=wL)
     state, tile_iters = pipe.dyn_loop(data, key)
     rank, err, conv, it, tile_iters = pull(
         (state.rank, state.err, state.converged, state.it, tile_iters),
         jax.device_get)
     iters, valve = int(it), not conv.all()
     if valve:
-        _warn_safety_valve(k, iters, int(np.sum(~conv)), 0)
-    info = {"iters": iters, "err": np.asarray(err[:T_col], float),
-            "tile_iters": tile_iters[:T_col].astype(np.int64),
-            "safety_valve": valve, "ara_loop": "device"}
-    return state.Q, state.rank, rank[:T_col], info
-
-
-def _ara_host_loop(pipe: _ColumnPipeline, A, Lout, rows, k, perm, dvec, key,
-                   Tb, Tb_col, Jb, widths, pull, a_ranks):
-    """Algorithm 5's slot queue, driven from the host: ``Tb`` slots, the
-    convergence flags read after every step, converged tiles evicted and
-    their slots refilled from the queue. Returns the bases in row order
-    (bucket-padded to ``Tb_col``), the ranks on the host and ``info``."""
-    opts, p = pipe.opts, pipe.p
-    wA, wL = widths
-    T_col = len(rows)
-    n_slots = min(Tb, T_col)
-
-    # Sort rows by the rank of the original A tile, descending (section 4.2):
-    # big tiles stay in the batch longest, so they enter first.
-    key_rank = np.array(
-        [a_ranks[tril_index(max(int(perm[i]), int(perm[k])),
-                            min(int(perm[i]), int(perm[k])))]
-         for i in rows]
-    )
-    order = np.argsort(-key_rank, kind="stable")
-    queue = [int(rows[o]) for o in order]
-
-    # Slot state: each slot hosts one tile's ARA run; slots past n_slots are
-    # permanent padding (pre-converged via the validity mask).
-    slot_rows = queue[:n_slots]
-    queue = queue[n_slots:]
-    data = _build_column_data(A, Lout, np.asarray(slot_rows), k, perm, dvec,
-                              opts.ldl, Tb=Tb, Jb=Jb, wA=wA, wL=wL)
-    state = init_state(Tb, A.b, p, A.dtype, valid=data["valid"])
-
-    # Finished bases land in a bucket-padded buffer in row order (padding
-    # rows stay zero), ranks and errors on the host.
-    pos_of = {int(i): t for t, i in enumerate(rows)}
-    Q_all = jnp.zeros((Tb_col, A.b, p.r_max), A.dtype)
-    ranks_h = np.zeros(T_col, np.int32)
-    err_h = np.zeros(T_col)
-    tile_iters = np.zeros(T_col, np.int64)
-    total_iters = 0
-    safety_valve = False
-    slot_live = [True] * len(slot_rows)
-
-    def finish(slots):
-        """Record the bases, ranks and errors of ``slots``; one device
-        gather + scatter and two host pulls for the lot."""
-        nonlocal Q_all
-        pos = np.full(Tb, Tb_col, np.int32)      # out of range: dropped
-        sl = np.zeros(Tb, np.int32)
-        pos[:len(slots)] = [pos_of[slot_rows[s]] for s in slots]
-        sl[:len(slots)] = slots
-        Q_all = _stash_rows(Q_all, state.Q, pos, sl)
-        rk, er = pull(state.rank), pull(state.err)
-        for s in slots:
-            ranks_h[pos_of[slot_rows[s]]] = rk[s]
-            err_h[pos_of[slot_rows[s]]] = er[s]
-
-    while any(slot_live):
-        # Every live slot enters the step unconverged (a refill resets it).
-        tile_iters[[pos_of[slot_rows[s]]
-                    for s, live in enumerate(slot_live) if live]] += 1
-        with obs.span("chol.ara_iter", cat="factor", k=k):
-            state = pipe.dyn_step(data, state, key)
-            total_iters += 1
-            conv = pull(state.converged)
-        # Evict converged tiles; refill their slots from the queue.
-        done = [s for s, live in enumerate(slot_live) if live and conv[s]]
-        if done:
-            finish(done)
-        refills = []
-        for s in done:
-            if queue:
-                slot_rows[s] = queue.pop(0)
-                refills.append(s)
-            else:
-                slot_live[s] = False
-        if refills:
-            sr = np.asarray(refills, np.int32)
-            new_rows = np.asarray([slot_rows[s] for s in refills])
-            nd = _build_column_data(A, Lout, new_rows, k, perm, dvec,
-                                    opts.ldl, Tb=len(refills), Jb=Jb,
-                                    wA=wA, wL=wL)
-            for name in ("Ua", "Va", "ranksA", "Ui", "Vi"):
-                data[name] = data[name].at[sr].set(nd[name])
-            state = state._replace(
-                Q=state.Q.at[sr].set(0.0),
-                rank=state.rank.at[sr].set(0),
-                converged=state.converged.at[sr].set(False),
-                err=state.err.at[sr].set(jnp.inf),
-            )
-        if any(slot_live) and total_iters > p.iters * max(1, T_col):
-            # Safety valve: the iteration budget for the whole column is
-            # exhausted. Flush the still-live slots with their current
-            # partial bases (best basis accumulated so far) instead of
-            # dropping them.
-            safety_valve = True
-            live = [s for s, lv in enumerate(slot_live) if lv]
-            _warn_safety_valve(k, total_iters, len(live), len(queue))
-            finish(live)
-            # Rows still queued never entered a slot: they keep rank 0
-            # (zero basis => zero tile) with an infinite error estimate so
-            # the caller can see they were never processed.
-            for i in queue:
-                err_h[pos_of[i]] = float("inf")
-            break
-
-    info = {"iters": total_iters, "err": err_h, "tile_iters": tile_iters,
-            "safety_valve": safety_valve, "ara_loop": "host"}
-    return Q_all, ranks_h, info
-
-
-@jax.jit
-def _stash_rows(buf, Q, pos, slot):
-    """``buf[pos[i]] = Q[slot[i]]``; out-of-range positions are dropped."""
-    return buf.at[pos].set(jnp.take(Q, slot, axis=0), mode="drop")
+        warnings.warn(
+            f"TLR column {k}: ARA safety valve tripped after {iters} "
+            f"iterations; {int(np.sum(~conv))} tile(s) kept their partial "
+            f"bases -- the factorization is degraded (raise max_iters/r_max "
+            f"or loosen eps; see stats['safety_valve'])", RuntimeWarning,
+            stacklevel=4)
+    with obs.span("chol.project", cat="factor", k=k):
+        if opts.batching == "ranked":
+            # Project at the rank-ladder width covering the detected ranks
+            # (exact: columns of Q past each tile's rank are zero).
+            wq = bucket_width(rank[:T], p.r_max)
+            Vnew = pipe.project(data, state.Q[:, :, :wq], Lkk, dk_new)
+            Vnew = _pad_axis(Vnew, p.r_max, axis=2)
+        else:
+            wq = None
+            Vnew = pipe.project(data, state.Q, Lkk, dk_new)
+    info = {"iters": iters, "err": np.asarray(err[:T], float),
+            "tile_iters": tile_iters[:T].astype(np.int64),
+            "slots": Tb * iters, "safety_valve": valve,
+            "T": T, "Tb": Tb, "Jb": Jb, "wQ": wq}
+    return state.Q, Vnew, state.rank, info
 
 
 # -- main drivers ---------------------------------------------------------------
@@ -1036,8 +825,8 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     p = opts.ara_params(r_out)
     impl = ops.resolve_impl(opts.impl)  # validate the knob up front
     pull = _HostPulls()
-    # A's ranks on the host, read once: the batching plan, the A-tile
-    # gather width and the host ARA loop's queue order.
+    # A's ranks on the host, read once: the batching plan and the A-tile
+    # gather width.
     a_ranks = pull(A.ranks)
     policy = resolve_policy(opts.batching,
                             tile_plan(A.ranks, A.r_max,
@@ -1062,24 +851,24 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     wL = 1 if batching == "ranked" else None
     stats = {
         "column_iters": [], "column_ranks": [], "modified_chol": 0,
-        "pivots": [], "mode": opts.mode, "impl": impl, "algo": "left",
+        "pivots": [], "impl": impl, "algo": "left",
         "bucket_ladder": list(ladder), "column_events": [],
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "batching": batching, "policy": policy,
-        "syncs": 0, "diag_syncs": 0, "ara_device_columns": 0,
+        "syncs": 0, "diag_syncs": 0,
     }
     health = HealthMonitor(opts.retry, "left", nb) if opts.check else None
     # Rank-overflow remedies re-run the failing rows' ARA pass at a
     # loosened eps. ARAParams.eps is static in the traced step, so each
     # escalation level gets its own (cached, rarely built) pipeline; the
-    # re-pass always runs fused over just the overflowing row subset.
+    # re-pass runs the column's ARA (flat) over just the overflowing rows.
     retry_pipes: dict[int, _ColumnPipeline] = {}
 
     def _retry_pipe(attempt: int) -> _ColumnPipeline:
         if attempt not in retry_pipes:
             o2 = dataclasses.replace(
                 opts, eps=opts.retry.eps_at(opts.eps, attempt),
-                mode="fused", batching="flat", check=False)
+                batching="flat", check=False)
             retry_pipes[attempt] = _ColumnPipeline(o2, o2.ara_params(r_out))
         return retry_pipes[attempt]
 
@@ -1231,15 +1020,15 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             health.record("rank_overflow", k, "panel",
                           remedy="eps_loosen", attempt=attempt,
                           rows=[int(rows[i]) for i in pos], eps=eps_a)
-            Qb, Vb, rb, ib = _column_ara_fused(
+            Qb, Vb, rb, ib = _column_ara(
                 _retry_pipe(attempt), A, _Lmat(), rows[pos], k, st.perm,
                 st.dvec, c["Lkk"], c["dk"],
                 jax.random.fold_in(kkey, 7000 + attempt), ladder,
                 widths=(wA, st.wL), pull=pull)
-            posj = jnp.asarray(pos)
-            Q = Q.at[posj].set(Qb)
-            Vnew = Vnew.at[posj].set(Vb)
-            ranks = ranks.at[posj].set(rb)
+            posj, n = jnp.asarray(pos), len(pos)
+            Q = Q.at[posj].set(Qb[:n])
+            Vnew = Vnew.at[posj].set(Vb[:n])
+            ranks = ranks.at[posj].set(rb[:n])
             ranks_h = pull(ranks)[:len(rows)]
             err_h[pos] = np.asarray(ib["err"], float)
             over[:] = False
@@ -1279,11 +1068,8 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             Lkk, dk_new = st.col[k]["Lkk"], st.col[k]["dk"]
             pipe.begin_column()
             with obs.span("chol.panel", cat="factor", k=k) as _psp:
-                L = _Lmat()
-                column = _column_ara_fused if opts.mode == "fused" \
-                    else partial(_column_ara_dynamic, a_ranks=a_ranks)
-                Q, Vnew, ranks, info = column(
-                    pipe, A, L, rows, k, st.perm, st.dvec, Lkk, dk_new,
+                Q, Vnew, ranks, info = _column_ara(
+                    pipe, A, _Lmat(), rows, k, st.perm, st.dvec, Lkk, dk_new,
                     kkey, ladder, widths=(wA, st.wL), pull=pull)
                 if faults.active():
                     Q = faults.corrupt_panel(Q[:T], k)
@@ -1291,7 +1077,7 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
                 ranks_h = pull((Q, Vnew, ranks), _ready_last)[:T]
                 if obs.enabled():
                     _psp.set(T=info["T"], Tb=info["Tb"], Jb=info["Jb"],
-                             iters=info["iters"], ara_loop=info["ara_loop"],
+                             iters=info["iters"],
                              rank_hist=obs.rank_hist(ranks_h, r_out))
             return Q, Vnew, ranks, ranks_h, info
 
@@ -1302,14 +1088,12 @@ def _factorize(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
             stats["column_iters"].append(info["iters"])
             stats["column_ranks"].append(ranks_h)
             stats["safety_valve"] |= info["safety_valve"]
-            stats["ara_device_columns"] += info["ara_loop"] == "device"
             stats["column_events"].append({
                 "k": k, "T": info["T"], "Tb": info["Tb"], "Jb": info["Jb"],
                 "seconds": dt, "traced": pipe.column_traced,
-                "err": np.asarray(info["err"]), "wQ": info.get("wQ"),
+                "err": np.asarray(info["err"]), "wQ": info["wQ"],
                 "syncs": pull.total - n0,
-                "tile_iters": info.get("tile_iters"),
-                "slots": info.get("slots"), "ara_loop": info["ara_loop"],
+                "tile_iters": info["tile_iters"], "slots": info["slots"],
             })
 
             with obs.span("chol.commit", cat="factor", k=k):
@@ -1457,7 +1241,7 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     ``tlr_syrk_column``. Trailing tiles carry growing concatenated factors;
     every ``opts.right_flush`` columns a full rounding pass
     (``tlr_round_tiles``) compacts them. No sampling chain, no ARA --
-    ``opts.mode`` / ``bs`` / ``share_omega`` / ``schur`` are left-looking
+    ``bs`` / ``share_omega`` / ``schur`` are left-looking
     knobs and are ignored here.
     """
     if opts.pivot is not None:
@@ -1512,14 +1296,14 @@ def _factorize_right(A: TLRMatrix, opts: CholOptions) -> TLRFactorization:
     alg0 = algebra_trace_count()
     stats = {
         "column_iters": [], "column_ranks": [], "modified_chol": 0,
-        "pivots": [], "mode": opts.mode, "impl": ops.resolve_impl(impl),
+        "pivots": [], "impl": ops.resolve_impl(impl),
         "algo": "right",
         "bucket_ladder": list(ladder), "column_events": [],
         "column_traces": 0, "project_traces": 0, "diag_traces": 0,
         "safety_valve": False, "flushes": 0, "acc_width": w_acc,
         "batching": batching, "policy": policy, "append_widths": [],
-        # host reads and the ARA loop: left driver only
-        "syncs": None, "diag_syncs": None, "ara_device_columns": None,
+        # host reads: left driver only
+        "syncs": None, "diag_syncs": None,
     }
     eps = jnp.asarray(opts.eps, dtype)
     health = HealthMonitor(opts.retry, "right", nb) if opts.check else None

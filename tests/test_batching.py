@@ -177,12 +177,12 @@ def test_right_driver_ranked_matches_flat(ldl):
     assert all(1 <= w <= op.r_max for w in fr.stats["append_widths"])
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "fused"])
-def test_left_driver_ranked_matches_flat(mode):
+@pytest.mark.parametrize("ldl", [False, True], ids=["cholesky", "ldlt"])
+def test_left_driver_ranked_matches_flat(ldl):
     K, op = _cov_operator(6, 8, 32)
-    ff = op.cholesky(CholOptions(eps=1e-6, bs=8, mode=mode))
-    fr = op.cholesky(CholOptions(eps=1e-6, bs=8, mode=mode,
-                                 batching="ranked"))
+    make = op.ldlt if ldl else op.cholesky
+    ff = make(CholOptions(eps=1e-6, bs=8))
+    fr = make(CholOptions(eps=1e-6, bs=8, batching="ranked"))
     ef, er = _factor_error(K, ff), _factor_error(K, fr)
     assert ef < 1e-4 and er < 1e-4
     # same sampling keys, exact (zero-column) slicing: the ranked run sees

@@ -10,6 +10,7 @@ variants serve all nb columns. These tests pin that contract:
   pure-jnp reference end-to-end through a full factorization.
 """
 
+import dataclasses
 import math
 
 import jax
@@ -66,12 +67,13 @@ def test_column_buckets_cover_and_bound(nb):
 # -- compile-count regression (tentpole acceptance) ----------------------------
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "fused"])
-def test_column_step_compile_count(fresh_column_steps, mode):
+@pytest.mark.parametrize("factor", [tlr_cholesky, tlr_ldlt],
+                         ids=["cholesky", "ldlt"])
+def test_column_step_compile_count(fresh_column_steps, factor):
     """nb=8, b=64: the ARA column step compiles <= log2(nb)+1 variants."""
     _, A = _problem(n=512, b=64)
     assert A.nb == 8
-    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode=mode))
+    fact = factor(A, CholOptions(eps=1e-6, bs=8))
     bound = int(math.log2(A.nb)) + 1
     assert fact.stats["column_traces"] <= bound, fact.stats["column_events"]
     # projection / diagonal executables are ladder-bounded too
@@ -100,16 +102,16 @@ def _traces(fact):
             ("column_traces", "project_traces", "diag_traces")]
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "fused"])
+@pytest.mark.parametrize("batching", ["flat", "ranked"])
 @pytest.mark.parametrize("factor", [tlr_cholesky, tlr_ldlt],
                          ids=["cholesky", "ldlt"])
-def test_warm_factorization_traces_nothing(factor, mode):
+def test_warm_factorization_traces_nothing(factor, batching):
     """The column steps are cached across factorizations: a second one
     with the same options traces, lowers and compiles nothing (its JIT
     work read under telemetry, which the first ran without) and returns
     the first one's factor bit for bit."""
     _, A = _problem(n=512, b=64)
-    opts = CholOptions(eps=1e-6, bs=8, mode=mode)
+    opts = CholOptions(eps=1e-6, bs=8, batching=batching)
     first = factor(A, opts)
     obs.enable()
     try:
@@ -149,15 +151,6 @@ def test_column_steps_keyed_on_statics(fresh_column_steps, other):
     assert _same_factor(fa, fa2)
 
 
-def test_explicit_bucket_still_bounded():
-    """Algorithm 5 slot buffers (bucket>0) stay ladder-bounded as well."""
-    _, A = _problem(n=512, b=64)
-    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode="dynamic",
-                                       bucket=3))
-    # slot batch (one bucketed size) + tail columns: still a handful
-    assert fact.stats["column_traces"] <= 2 * (int(math.log2(A.nb)) + 1)
-
-
 # -- padding is numerically inert ---------------------------------------------
 
 
@@ -176,13 +169,14 @@ def test_bucketed_accuracy_matches_dense():
 # -- kernel dispatch parity (impl knob) ---------------------------------------
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "fused"])
-def test_impl_interpret_matches_ref(mode):
+@pytest.mark.parametrize("batching", ["flat", "ranked"])
+def test_impl_interpret_matches_ref(batching):
     """Pallas interpreter path == pure-jnp path through a full factorization."""
     _, A = _problem(n=256, b=64, r_max=32)
     facts = {}
     for impl in ("ref", "interpret"):
-        f = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode=mode, impl=impl))
+        f = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, batching=batching,
+                                        impl=impl))
         facts[impl] = _dense_L(f)
         assert f.stats["impl"] == impl
     np.testing.assert_allclose(facts["interpret"], facts["ref"],
@@ -218,8 +212,7 @@ def test_dynamic_safety_valve_flushes_live_slots():
     # valve (rank cap would need r_max/bs = 16 iterations, valve trips
     # after T_col+1), so every column exercises the flush path.
     with pytest.warns(RuntimeWarning, match="safety valve"):
-        fact = tlr_cholesky(A, CholOptions(eps=1e-13, bs=4, mode="dynamic",
-                                           max_iters=1))
+        fact = tlr_cholesky(A, CholOptions(eps=1e-13, bs=4, max_iters=1))
     assert fact.stats["safety_valve"] is True
     assert np.isfinite(np.asarray(fact.L.V)).all()
     assert np.isfinite(np.asarray(fact.L.U)).all()
@@ -230,16 +223,16 @@ def test_dynamic_safety_valve_flushes_live_slots():
 
 def test_no_safety_valve_on_converging_problems():
     _, A = _problem(n=256, b=64)
-    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode="dynamic"))
+    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8))
     assert fact.stats["safety_valve"] is False
 
 
-@pytest.mark.parametrize("mode", ["dynamic", "fused"])
-def test_column_events_report_per_tile_err(mode):
-    """Stats-schema parity: dynamic-mode columns report the same per-tile
-    ARA error estimates fused mode always has."""
+@pytest.mark.parametrize("factor", [tlr_cholesky, tlr_ldlt],
+                         ids=["cholesky", "ldlt"])
+def test_column_events_report_per_tile_err(factor):
+    """Every column reports one final ARA error estimate per row tile."""
     _, A = _problem(n=256, b=64)
-    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode=mode))
+    fact = factor(A, CholOptions(eps=1e-6, bs=8))
     assert fact.stats["column_events"], "no columns recorded"
     for ev in fact.stats["column_events"]:
         assert ev["err"].shape == (ev["T"],)
@@ -258,116 +251,160 @@ def test_share_omega_false_through_ops_layer():
     assert np.linalg.norm(K - Ld @ Ld.T, 2) < 1e-4
 
 
-# -- the dynamic ARA loop on the device against the host loop ------------------
+# -- the column's ARA against a plain ARA over its dense tiles -----------------
 
 
-def _column_both_ways(factor, opts, r_max, k):
-    """Column ``k`` of an f32 factorization run through the device ARA loop
-    and through the host loop with one batch for the whole column (the
-    queue empty after the first fill), on the same operands and key."""
+def _column_and_reference(factor, opts, r_max, k):
+    """Column ``k`` of a factorization through the driver's column ARA,
+    and a plain reference on the same operator: ``ara_iteration`` stepped
+    from Python over the column's dense tile expressions (the column's
+    sampler applied to the identity), with the same key and the shared
+    Omega, until every tile converges or the column's budget of
+    ``p.iters`` steps per row tile has passed; then the projection and
+    the triangular solve in plain jnp."""
+    from repro.core.ara import ara_iteration, dense_batch_sampler, init_state
     from repro.core.batching import bucket_width
     from repro.core.cholesky import (_ColumnPipeline, _HostPulls,
-                                     _ara_device_loop, _ara_host_loop,
-                                     _build_column_data)
+                                     _build_column_data, _column_ara)
 
     _, K = covariance_problem(512, 3, 64)
-    A = from_dense(jnp.asarray(K, jnp.float32), 64, r_max, 1e-7)
+    A = from_dense(jnp.asarray(K), 64, r_max, 1e-7)
     fact = factor(A, opts)
     L, d = fact.L, fact.d
+    ldl = factor is tlr_ldlt
+    opts = dataclasses.replace(opts, ldl=ldl)
     p = opts.ara_params(A.r_max)
     pipe = _ColumnPipeline(opts, p)
     ladder = _bucket_ladder(A.nb - 1)
     rows, perm = np.arange(k + 1, A.nb), np.arange(A.nb)
-    Tb, Jb = _column_buckets(A.nb, k, ladder)
+    T, b = len(rows), A.b
     widths = (None, None)
     if opts.batching == "ranked":
         widths = (bucket_width(np.asarray(A.ranks), A.r_max),
                   bucket_width(np.asarray(L.ranks), A.r_max))
-    data = _build_column_data(A, L, rows, k, perm, d, opts.ldl, Tb=Tb,
-                              Jb=Jb, wA=widths[0], wL=widths[1])
     key = jax.random.PRNGKey(11)
-    dk = d[k] if opts.ldl else None
+    Lkk, dk = L.D[k], (d[k] if ldl else None)
     reads = _HostPulls()
-    Qd, ranks_d, ranks_dh, info_d = _ara_device_loop(pipe, data, key, k,
-                                                     len(rows), reads)
+    Q, V, ranks, info = _column_ara(pipe, A, L, rows, k, perm, d, Lkk, dk,
+                                    key, ladder, widths, reads)
     assert reads.total == 1
-    Qh, ranks_hh, info_h = _ara_host_loop(
-        pipe, A, L, rows, k, perm, d, key, Tb, Tb, Jb, widths, _HostPulls(),
-        np.asarray(A.ranks))
-    np.testing.assert_array_equal(np.asarray(ranks_d)[:len(rows)], ranks_dh)
-    return dict(
-        device=(Qd, ranks_dh, info_d, pipe.project(data, Qd, L.D[k], dk)),
-        host=(Qh, ranks_hh, info_h, pipe.project(data, Qh, L.D[k], dk)),
-        p=p, Tb=Tb)
+
+    Tb, Jb = _column_buckets(A.nb, k, ladder)
+    data = _build_column_data(A, L, rows, k, perm, d, ldl, Tb=Tb, Jb=Jb)
+    E = pipe.sample(data, jnp.eye(b, dtype=A.dtype))[:T]
+    sample, sample_t, E = dense_batch_sampler(E)
+    state = init_state(T, b, p, E.dtype)
+    tile_iters = np.zeros(T, np.int64)
+    while not bool(state.converged.all()) and int(state.it) <= p.iters * T:
+        tile_iters += ~np.asarray(state.converged)
+        state = ara_iteration(sample, E, state, key, p, share_omega=True,
+                              T=T, b=b)
+    Vr = jax.vmap(lambda B: jax.scipy.linalg.solve_triangular(
+        Lkk, B, lower=True))(sample_t(E, state.Q))
+    if ldl:
+        Vr = Vr / dk[None, :, None]
+    ref = dict(ranks=np.asarray(state.rank), err=np.asarray(state.err),
+               iters=int(state.it), tile_iters=tile_iters,
+               valve=not bool(state.converged.all()), Q=state.Q, V=Vr)
+    return (np.asarray(Q)[:T], np.asarray(V)[:T], np.asarray(ranks)[:T],
+            info), ref, p
 
 
 _PARITY = {
-    "cholesky-flat": (tlr_cholesky, dict(batching="flat"), 64, 1e-4),
-    "cholesky-ranked": (tlr_cholesky, dict(batching="ranked"), 64, 1e-4),
-    "ldlt-flat": (tlr_ldlt, dict(batching="flat"), 64, 1e-4),
-    "ldlt-ranked": (tlr_ldlt, dict(batching="ranked"), 64, 1e-4),
+    "cholesky-flat": (tlr_cholesky, dict(batching="flat"), 64, 1e-4, 2),
+    "cholesky-ranked": (tlr_cholesky, dict(batching="ranked"), 64, 1e-4, 2),
+    "ldlt-flat": (tlr_ldlt, dict(batching="flat"), 64, 1e-4, 2),
+    "ldlt-ranked": (tlr_ldlt, dict(batching="ranked"), 64, 1e-4, 2),
     # eps out of reach at r_max 16: every tile fills its buffer and takes
     # one more step, the full-rank step that records its error
-    "cholesky-full-rank": (tlr_cholesky, dict(batching="flat"), 16, 1e-9),
+    "cholesky-full-rank": (tlr_cholesky, dict(batching="flat"), 16, 1e-9, 2),
+    # no prior column (J = 0, padded to Jb), and a one-row column
+    "cholesky-first-column": (tlr_cholesky, dict(batching="flat"), 64, 1e-4,
+                              0),
+    "ldlt-last-column": (tlr_ldlt, dict(batching="ranked"), 64, 1e-4, 6),
 }
 
 
 @pytest.mark.parametrize("case", list(_PARITY))
-def test_device_ara_loop_matches_the_host_loop(case):
-    factor, extra, r_max, eps = _PARITY[case]
+def test_column_ara_matches_plain_ara(case):
+    factor, extra, r_max, eps, k = _PARITY[case]
     opts = CholOptions(eps=eps, bs=8, **extra)
-    with jax.enable_x64(False):
-        run = _column_both_ways(factor, opts, r_max, k=2)
-        (Qd, rd, info_d, Vd), (Qh, rh, info_h, Vh) = run["device"], run["host"]
-        np.testing.assert_array_equal(rd, rh)
-        np.testing.assert_array_equal(info_d["err"], info_h["err"])
-        np.testing.assert_array_equal(info_d["tile_iters"], info_h["tile_iters"])
-        assert info_d["iters"] == info_h["iters"]
-        assert info_d["safety_valve"] is info_h["safety_valve"] is False
-        assert (info_d["ara_loop"], info_h["ara_loop"]) == ("device", "host")
-        np.testing.assert_allclose(np.asarray(Qd), np.asarray(Qh),
-                                   rtol=0, atol=1e-6)
-        scale = float(np.abs(np.asarray(Vh)).max())
-        np.testing.assert_allclose(np.asarray(Vd), np.asarray(Vh),
-                                   rtol=0, atol=1e-6 * scale)
+    (Q, V, ranks, info), ref, p = _column_and_reference(factor, opts, r_max,
+                                                        k=k)
+    np.testing.assert_array_equal(ranks, ref["ranks"])
+    np.testing.assert_allclose(info["err"], ref["err"], rtol=1e-9, atol=0)
+    np.testing.assert_array_equal(info["tile_iters"], ref["tile_iters"])
+    assert info["iters"] == ref["iters"]
+    assert info["slots"] == info["Tb"] * info["iters"]
+    assert info["safety_valve"] is ref["valve"] is False
+    np.testing.assert_allclose(Q, np.asarray(ref["Q"]), rtol=0, atol=1e-9)
+    scale = float(np.abs(np.asarray(ref["V"])).max())
+    np.testing.assert_allclose(V, np.asarray(ref["V"]), rtol=0,
+                               atol=1e-9 * scale)
     if case == "cholesky-full-rank":
-        assert (rd == r_max).all()
-        assert info_d["iters"] == run["p"].iters + 1
+        assert (ranks == r_max).all()
+        assert info["iters"] == p.iters + 1
     else:
-        assert rd.max() < r_max
+        assert ranks.max() < r_max
 
 
-def test_device_ara_loop_stops_at_the_host_loops_safety_valve():
-    """The column budget (``p.iters`` steps per row tile) ends both loops
-    at the same step, one past the budget, and both flush the partial
-    bases with the same warning."""
+def test_column_ara_stops_at_the_safety_valve():
+    """The column budget (``p.iters`` steps per row tile, the budget of
+    Algorithm 5's slot queue) ends the column's ARA one step past it, as
+    it ends the plain loop, with the warning and the partial bases."""
     opts = CholOptions(eps=1e-13, bs=4, max_iters=1, batching="flat")
-    with jax.enable_x64(False), pytest.warns(RuntimeWarning,
-                                             match="safety valve"):
-        run = _column_both_ways(tlr_cholesky, opts, 64, k=5)
-    (_, rd, info_d, _), (_, rh, info_h, _) = run["device"], run["host"]
+    with pytest.warns(RuntimeWarning, match="safety valve"):
+        (_, _, ranks, info), ref, _ = _column_and_reference(
+            tlr_cholesky, opts, 64, k=5)
     T = 2
-    assert info_d["iters"] == info_h["iters"] == T + 1
-    assert info_d["safety_valve"] is info_h["safety_valve"] is True
-    np.testing.assert_array_equal(rd, rh)
-    np.testing.assert_array_equal(info_d["tile_iters"], info_h["tile_iters"])
+    assert info["iters"] == ref["iters"] == T + 1
+    assert info["safety_valve"] is ref["valve"] is True
+    np.testing.assert_array_equal(ranks, ref["ranks"])
+    assert (ranks > 0).all()
+    np.testing.assert_array_equal(info["tile_iters"], ref["tile_iters"])
 
 
-def test_refilling_columns_keep_the_host_loop():
-    """Under ``bucket > 0`` a column with more rows than slots evicts and
-    refills on the host; a column that fits its slots runs on the device."""
-    _, A = _problem(n=512, b=64)
-    fact = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, bucket=2))
-    events = fact.stats["column_events"]
-    host = [e for e in events if e["T"] > e["Tb"]]
-    assert len(host) == 5
-    for e in events:
-        assert e["ara_loop"] == ("host" if e in host else "device")
-    for e in host:
-        # every row held a slot, though fewer slots than rows: refilled
-        assert len(e["tile_iters"]) == e["T"] > e["Tb"]
-        assert e["tile_iters"].min() >= 1
-    assert fact.stats["ara_device_columns"] == len(events) - len(host)
-    Ld = _dense_L(fact)
-    K, _ = _problem(n=512, b=64)
-    assert np.linalg.norm(K - Ld @ Ld.T, 2) < 1e-4
+@pytest.mark.parametrize("factor", [tlr_cholesky, tlr_ldlt],
+                         ids=["cholesky", "ldlt"])
+def test_rank_overflow_repass_runs_the_column_ara(monkeypatch, factor):
+    """The eps-loosened re-pass of overflowing rows goes through the same
+    column ARA as the main pass: one read for its loop, and it keeps
+    every retried row within ``r_max_out``."""
+    from repro import faults
+    from repro.core import cholesky as chol
+    from repro.core.tlr import tril_index
+
+    _, K = covariance_problem(256, 1, 32)
+    with pytest.warns(FutureWarning):
+        A = from_dense(jnp.asarray(K), 32, 32, 1e-10)
+    As = faults.spike_rank(A, 4, 1, seed=3, scale=1e-4)
+    opts = CholOptions(eps=1e-6, bs=8, r_max_out=16, check=True)
+    calls = []
+    real = chol._column_ara
+
+    def spy(pipe, A, Lout, rows, k, *args, pull, **kw):
+        n0 = pull.total
+        out = real(pipe, A, Lout, rows, k, *args, pull=pull, **kw)
+        calls.append((pipe.opts, k, list(rows), pull.total - n0, out))
+        return out
+
+    monkeypatch.setattr(chol, "_column_ara", spy)
+    fact = factor(As, opts)
+    loosen = [e for e in fact.stats["health"]["events"]
+              if e["remedy"] == "eps_loosen"]
+    assert loosen
+    repass = [c for c in calls if c[0].eps > opts.eps]
+    assert len(calls) == As.nb - 1 + len(repass)
+    assert [(c[1], c[2], c[0].eps) for c in repass] \
+        == [(e["column"], e["detail"]["rows"], e["detail"]["eps"])
+            for e in loosen]
+    p = opts.ara_params(16)
+    for o, k, rows, n_reads, (_, _, ranks, info) in repass:
+        assert (o.batching, o.check) == ("flat", False)
+        assert n_reads == 1
+        assert (np.asarray(ranks)[:len(rows)] <= 16).all()
+        assert not info["safety_valve"] and info["iters"] <= p.iters + 1
+    for e in loosen:
+        k = e["column"]
+        for i in e["detail"]["rows"]:
+            assert int(fact.L.ranks[tril_index(i, k)]) <= 16

@@ -1,6 +1,6 @@
 """The left driver's own counts: its device-to-host reads (against an
-independent count of every host-read entry point it uses), the dynamic
-ARA loop's slot occupancy, and the spans it opens under telemetry, which
+independent count of every host-read entry point it uses), the column
+ARA's slot occupancy, and the spans it opens under telemetry, which
 leave the factors bit for bit as they are without it."""
 
 import contextlib
@@ -70,9 +70,9 @@ def _host_reads(monkeypatch):
 OPTIONS = {
     "dynamic": CholOptions(eps=1e-6),
     "dynamic-ranked": CholOptions(eps=1e-6, batching="ranked"),
-    "bucketed": CholOptions(eps=1e-6, bucket=2),
-    "fused": CholOptions(eps=1e-6, mode="fused"),
-    "fused-ranked": CholOptions(eps=1e-6, mode="fused", batching="ranked"),
+    "schur-full": CholOptions(eps=1e-6, schur="full"),
+    "per-tile-omega": CholOptions(eps=1e-6, share_omega=False),
+    "r-max-out": CholOptions(eps=1e-6, r_max_out=16),
     "checked": CholOptions(eps=1e-6, check=True),
     "pivoted": CholOptions(eps=1e-6, pivot="frobenius"),
 }
@@ -92,14 +92,14 @@ def test_pull_count_equals_an_independent_count(monkeypatch, case):
     assert sum(per_col) + stats["diag_syncs"] <= stats["syncs"]
     # one pull per diagonal: the modified-Cholesky flag (and the pivot)
     assert stats["diag_syncs"] == op.nb * (1 + (opts.pivot is not None))
-    if opts.mode == "dynamic" and opts.bucket == 0 and not opts.check:
+    if not opts.check:
         # per column: the diagonal's reads, the ARA loop's one read and
         # the panel's end; once per factorization: A's ranks
         assert stats["syncs"] <= op.nb * (3 + (opts.pivot is not None))
         assert per_col == [2] * (op.nb - 1)
 
 
-@pytest.mark.parametrize("case", ["dynamic", "dynamic-ranked", "bucketed"])
+@pytest.mark.parametrize("case", list(OPTIONS))
 def test_slot_occupancy_bounds(case):
     fact = _problem(seed=1).cholesky(OPTIONS[case])
     events = fact.stats["column_events"]
@@ -113,20 +113,7 @@ def test_slot_occupancy_bounds(case):
     assert 0 < occ <= 1
 
 
-def test_default_options_run_every_ara_loop_on_the_device():
-    fact = _problem(seed=1).cholesky(OPTIONS["dynamic"])
-    events = fact.stats["column_events"]
-    assert fact.stats["ara_device_columns"] == len(events) == 7
-    assert {e["ara_loop"] for e in events} == {"device"}
-
-
-def test_fused_mode_records_no_occupancy():
-    fact = _problem(seed=1).cholesky(OPTIONS["fused"])
-    assert all(e["tile_iters"] is None and e["slots"] is None
-               for e in fact.stats["column_events"])
-
-
-@pytest.mark.parametrize("case", ["dynamic", "fused-ranked", "bucketed"])
+@pytest.mark.parametrize("case", ["dynamic", "dynamic-ranked", "pivoted"])
 def test_telemetry_leaves_the_factor_bit_identical(case):
     op = _problem(seed=2)
     opts = OPTIONS[case]
@@ -144,17 +131,11 @@ def test_telemetry_leaves_the_factor_bit_identical(case):
     assert names.count("chol.pull") == on.stats["syncs"]
     assert names.count("chol.commit") == op.nb - 1
     assert names.count("chol.project") == op.nb - 1
-    # one per step of the host ARA loop; the device loop opens none
-    host_iters = sum(it for it, e in zip(on.stats["column_iters"],
-                                         on.stats["column_events"])
-                     if e["ara_loop"] == "host")
-    assert names.count("chol.ara_iter") == host_iters
     ph = on.stats["telemetry"]["phases"]
     assert ph["chol.pull"]["count"] == on.stats["syncs"]
-    # a pull is inside the span it serves: the ARA step's, the panel's,
-    # the diagonal's or the factorization's
+    # a pull is inside the span it serves: the panel's, the diagonal's
+    # or the factorization's
     by_id = {s.id: s for s in tel.spans}
     parents = {by_id[s.parent].name for s in tel.spans
                if s.name == "chol.pull"}
-    assert parents <= {"chol.ara_iter", "chol.panel", "chol.diag",
-                       "chol.factorize"}
+    assert parents <= {"chol.panel", "chol.diag", "chol.factorize"}

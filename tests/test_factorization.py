@@ -35,29 +35,14 @@ def _factor_error(K, fact):
     return np.linalg.norm(Ap - R, 2)
 
 
-@pytest.mark.parametrize("mode", ["fused", "dynamic"])
-def test_cholesky_accuracy(mode):
+@pytest.mark.parametrize("batching", ["flat", "ranked"])
+def test_cholesky_accuracy(batching):
     K, A = _cov_tlr()
-    opts = CholOptions(eps=1e-6, bs=8, mode=mode, r_max_out=64)
+    opts = CholOptions(eps=1e-6, bs=8, batching=batching, r_max_out=64)
     fact = tlr_cholesky(A, opts)
     err = _factor_error(K, fact)
-    assert err < 1e-4, f"mode={mode}: ||A-LL^T|| = {err}"
+    assert err < 1e-4, f"batching={batching}: ||A-LL^T|| = {err}"
     assert fact.stats["modified_chol"] == 0
-
-
-@pytest.mark.slow
-def test_cholesky_modes_agree():
-    """Dynamic batching must not change the math, only the orchestration."""
-    K, A = _cov_tlr(n=384, b=64)
-    f1 = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode="fused"))
-    f2 = tlr_cholesky(A, CholOptions(eps=1e-6, bs=8, mode="dynamic", bucket=3))
-    e1, e2 = _factor_error(K, f1), _factor_error(K, f2)
-    assert abs(e1 - e2) < 5e-5
-    # Ranks agree to within one sample block: the math is identical, but a
-    # refilled slot sees a different (equally fresh) Omega stream, which can
-    # move a borderline tile by +-bs.
-    r1, r2 = np.asarray(f1.L.ranks), np.asarray(f2.L.ranks)
-    assert np.max(np.abs(r1 - r2)) <= 8
 
 
 @pytest.mark.parametrize("share_omega", [True, False])
